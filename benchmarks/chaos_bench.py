@@ -37,10 +37,6 @@ import time
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
 ROWS = int(os.environ.get("CHAOS_ROWS", "40000"))
 SCANS = int(os.environ.get("CHAOS_SCANS", "150"))
 BUCKETS = 4

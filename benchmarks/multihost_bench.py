@@ -78,7 +78,6 @@ import os, sys, time
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax
-jax.config.update("jax_platforms", "cpu")
 
 pid = int(sys.argv[1]); port = sys.argv[2]; table_path = sys.argv[3]
 sys.path.insert(0, sys.argv[4]); rows = int(sys.argv[5])
@@ -196,7 +195,5 @@ def measure(rows: int = 400_000, timeout: float = 300.0) -> dict:
 
 
 if __name__ == "__main__":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
     n = int(sys.argv[1]) if len(sys.argv) > 1 else 400_000
     print(json.dumps(measure(n)), flush=True)

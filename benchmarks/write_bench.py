@@ -27,10 +27,6 @@ import tempfile
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np  # noqa: E402
 import pyarrow as pa  # noqa: E402
 

@@ -244,9 +244,9 @@ def check_distributed_init(model: ProgramModel) -> List[Finding]:
                     "distributed-init", mod.rel, node.lineno,
                     "direct jax.distributed.initialize( outside "
                     "parallel/multihost.py — use "
-                    "multihost.initialize(), which opts the CPU "
-                    "backend into Gloo collectives before the "
-                    "backend comes up"))
+                    "multihost.initialize(), the one reviewed call "
+                    "site (env defaults, peer-death heartbeat "
+                    "budget)"))
     return out
 
 

@@ -313,14 +313,15 @@ class MergeTreeCompactManager:
                     # streamed plane's ~runs x chunk memory bound;
                     # an unsupported file drops to the pyarrow
                     # read_batches path below
-                    from paimon_tpu.format.rawpage import \
-                        _FALLBACK_ERRORS, iter_batches_device
+                    from paimon_tpu.format.rawpage import (
+                        DeviceDecodeUnsupported, iter_batches_device,
+                    )
                     batches = None
                     try:
                         batches = iter_batches_device(
                             self.file_io, path, chunk_rows,
                             self.options)
-                    except _FALLBACK_ERRORS:
+                    except DeviceDecodeUnsupported:
                         from paimon_tpu.metrics import (
                             SCAN_DEVICE_DECODE_FALLBACKS,
                             global_registry,

@@ -19,10 +19,19 @@ definition levels, UNCOMPRESSED/SNAPPY/GZIP/ZSTD codecs.  Anything
 else raises ``DeviceDecodeUnsupported`` and the caller falls back to
 the pyarrow path (core/read.py gates on ``read.device-decode``);
 results are byte-identical to pyarrow by the oracle test suite.
+
+Two kinds of step alternate below and are kept apart: HOST PARSE of
+file bytes (page/run headers, padding) runs inside ``_parsing()``, which
+turns whatever the hand-rolled parsers raise on byte shapes they never
+anticipated into ``DeviceDecodeUnsupported``; DEVICE TRANSFORMS
+(ops/decode.py) run outside it, so an error JAX raises for a lowering
+or a compile it cannot do fails the read instead of passing for an
+uncovered file.
 """
 
 from __future__ import annotations
 
+import contextlib
 import io
 import struct
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -58,6 +67,26 @@ class DeviceDecodeUnsupported(Exception):
     """This file/column needs an encoding, codec or shape outside the
     device decode plane's coverage; the caller takes the pyarrow host
     path (never an error surfaced to users)."""
+
+
+# what the hand-rolled thrift/page/run-header parsers raise on byte
+# shapes they never anticipated (truncated varints, absent header
+# fields) — the host reader is the arbiter of whether such a file is
+# readable or genuinely corrupt
+_PARSE_ERRORS = (IndexError, KeyError, TypeError, ValueError,
+                 struct.error)
+
+
+@contextlib.contextmanager
+def _parsing():
+    """Scope of one host-side parse step over file bytes.  No device
+    transform may run inside it: JAX raises the same TypeError /
+    ValueError classes for a lowering it cannot do."""
+    try:
+        yield
+    except _PARSE_ERRORS as e:
+        raise DeviceDecodeUnsupported(
+            f"unparseable page bytes: {e!r}") from e
 
 
 # ---------------------------------------------------------------------------
@@ -269,8 +298,9 @@ def _decode_rle_values(buf: bytes, bit_width: int,
     import jax.numpy as jnp
 
     from paimon_tpu.ops.decode import expand_rle_hybrid, pad_pow2
-    runs = _pad_runs(parse_rle_runs(buf, bit_width, count))
-    words = _pad_bytes_u32(buf)
+    with _parsing():
+        runs = _pad_runs(parse_rle_runs(buf, bit_width, count))
+        words = _pad_bytes_u32(buf)
     padded_count = pad_pow2(count)
     out = expand_rle_hybrid(jnp.asarray(words),
                             jnp.asarray(runs[0]), jnp.asarray(runs[1]),
@@ -288,13 +318,16 @@ def _decode_plain_values(data: bytes, phys: str,
     from paimon_tpu.ops.decode import (pad_pow2, plain_to_u32,
                                        plain_to_u64)
     width = _PHYS_WIDTH[phys]
-    if len(data) < width * count:
-        raise DeviceDecodeUnsupported("PLAIN page shorter than values")
     padded_count = pad_pow2(count)
-    buf = _pad_u8(data, floor=padded_count * width)
-    if len(buf) < padded_count * width:
-        buf = np.concatenate(
-            [buf, np.zeros(padded_count * width - len(buf), np.uint8)])
+    with _parsing():
+        if len(data) < width * count:
+            raise DeviceDecodeUnsupported(
+                "PLAIN page shorter than values")
+        buf = _pad_u8(data, floor=padded_count * width)
+        if len(buf) < padded_count * width:
+            buf = np.concatenate(
+                [buf,
+                 np.zeros(padded_count * width - len(buf), np.uint8)])
     fn = plain_to_u64 if width == 8 else plain_to_u32
     out = fn(jnp.asarray(buf), padded_count)
     return np.asarray(out)[:count]
@@ -387,6 +420,26 @@ def _decompress(data: bytes, codec: str, uncompressed: int) -> bytes:
         data, decompressed_size=uncompressed).to_pybytes()
 
 
+def _next_page(data: bytes, pos: int, codec: str
+               ) -> Tuple[Dict, bytes, int]:
+    """HOST: parse the page header at `pos` and decompress its payload.
+    Returns (header, page bytes, position past the page)."""
+    with _parsing():
+        if pos >= len(data):
+            raise DeviceDecodeUnsupported("column chunk truncated")
+        hdr, body = parse_page_header(data, pos)
+        end = body + hdr["compressed_size"]
+        payload = data[body:end]
+        if hdr["type"] == _PAGE_DATA_V2:
+            raise DeviceDecodeUnsupported("v2 data page")
+        if hdr["type"] == _PAGE_DATA and hdr["data"] is None:
+            raise DeviceDecodeUnsupported("data page without header")
+        if hdr["type"] not in (_PAGE_DICT, _PAGE_DATA):
+            return hdr, b"", end              # index pages etc.: skipped
+        return hdr, _decompress(payload, codec,
+                                hdr["uncompressed_size"]), end
+
+
 def _decode_chunk(data: bytes, col_meta, max_def: int,
                   ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
     """One column chunk's pages -> (raw-bits values with zeros at null
@@ -405,61 +458,54 @@ def _decode_chunk(data: bytes, col_meta, max_def: int,
     mask_parts: List[np.ndarray] = []
     seen = 0
     while seen < total:
-        if pos >= len(data):
-            raise DeviceDecodeUnsupported("column chunk truncated")
-        hdr, body = parse_page_header(data, pos)
-        comp = hdr["compressed_size"]
-        payload = data[body:body + comp]
-        pos = body + comp
+        hdr, page, pos = _next_page(data, pos, codec)
         ptype = hdr["type"]
         if ptype == _PAGE_DICT:
-            page = _decompress(payload, codec,
-                               hdr["uncompressed_size"])
-            dhdr = hdr["dict"] or {}
-            if dhdr.get(2, _ENC_PLAIN) not in (_ENC_PLAIN,
-                                               _ENC_PLAIN_DICT):
-                raise DeviceDecodeUnsupported("non-PLAIN dictionary")
-            dict_vals = _decode_plain_values(page, phys, dhdr.get(1, 0))
+            with _parsing():
+                dhdr = hdr["dict"] or {}
+                if dhdr.get(2, _ENC_PLAIN) not in (_ENC_PLAIN,
+                                                   _ENC_PLAIN_DICT):
+                    raise DeviceDecodeUnsupported("non-PLAIN dictionary")
+                dict_count = dhdr.get(1, 0)
+            dict_vals = _decode_plain_values(page, phys, dict_count)
             continue
-        if ptype == _PAGE_DATA_V2:
-            raise DeviceDecodeUnsupported("v2 data page")
         if ptype != _PAGE_DATA:
-            continue                          # index pages etc.
-        dh = hdr["data"]
-        if dh is None:
-            raise DeviceDecodeUnsupported("data page without header")
-        nvals = dh.get(1, 0)
-        enc = dh.get(2, _ENC_PLAIN)
-        page = _decompress(payload, codec, hdr["uncompressed_size"])
-        off = 0
+            continue
+        with _parsing():
+            dh = hdr["data"]
+            nvals = dh.get(1, 0)
+            enc = dh.get(2, _ENC_PLAIN)
+            if enc not in (_ENC_PLAIN, _ENC_PLAIN_DICT, _ENC_RLE_DICT):
+                raise DeviceDecodeUnsupported(f"value encoding {enc}")
+            off = 0
+            level_bytes = None
+            if max_def > 0:
+                if dh.get(3, _ENC_RLE) != _ENC_RLE:
+                    raise DeviceDecodeUnsupported("non-RLE def levels")
+                dlen = struct.unpack("<I", page[off:off + 4])[0]
+                off += 4
+                level_bytes = page[off:off + dlen]
+                off += dlen
         present = None
         n_present = nvals
-        if max_def > 0:
-            if dh.get(3, _ENC_RLE) != _ENC_RLE:
-                raise DeviceDecodeUnsupported("non-RLE def levels")
-            dlen = struct.unpack("<I", page[off:off + 4])[0]
-            off += 4
-            bw = max_def.bit_length()
-            levels = _decode_rle_values(page[off:off + dlen], bw,
-                                        nvals)
-            off += dlen
+        if level_bytes is not None:
+            levels = _decode_rle_values(level_bytes,
+                                        max_def.bit_length(), nvals)
             present = levels == max_def
             n_present = int(present.sum())
         if enc == _ENC_PLAIN:
             vals = _decode_plain_values(page[off:], phys, n_present)
-        elif enc in (_ENC_PLAIN_DICT, _ENC_RLE_DICT):
+        else:
             if dict_vals is None:
                 raise DeviceDecodeUnsupported("dict page missing")
             if n_present:
-                bw = page[off]
+                with _parsing():
+                    bw = page[off]
                 idx = _decode_rle_values(page[off + 1:], bw, n_present)
+                vals = np.asarray(dict_gather(
+                    jnp.asarray(dict_vals), jnp.asarray(idx)))
             else:
-                idx = np.zeros(0, np.uint32)
-            vals = np.asarray(dict_gather(
-                jnp.asarray(dict_vals), jnp.asarray(idx))) \
-                if n_present else dict_vals[:0]
-        else:
-            raise DeviceDecodeUnsupported(f"value encoding {enc}")
+                vals = dict_vals[:0]
         if present is not None and n_present != nvals:
             padded = pad_pow2(nvals)
             vp = np.zeros(padded, vals.dtype)
@@ -550,23 +596,16 @@ def _check_supported(md, columns: Sequence[str]) -> Dict[str, int]:
     return out
 
 
-# errors that route a file back to the pyarrow host path: the typed
-# coverage signal, plus anything the hand-rolled thrift/page parsers
-# raise on byte shapes they never anticipated (truncated varints,
-# absent header fields) — the host reader is the arbiter of whether
-# such a file is readable or genuinely corrupt
-_FALLBACK_ERRORS = (DeviceDecodeUnsupported, IndexError, KeyError,
-                    TypeError, ValueError, struct.error)
-
-
 def maybe_read_device(file_io: FileIO, path: str,
                       projection: Optional[List[str]] = None,
                       options=None) -> Optional[pa.Table]:
     """read_parquet_device, or None when the file needs the pyarrow
-    host path (fallback counted in the scan metric group)."""
+    host path (fallback counted in the scan metric group).  Only the
+    typed coverage signal falls back; a device transform's own error
+    propagates."""
     try:
         return read_parquet_device(file_io, path, projection, options)
-    except _FALLBACK_ERRORS:
+    except DeviceDecodeUnsupported:
         from paimon_tpu.metrics import SCAN_DEVICE_DECODE_FALLBACKS, \
             global_registry
         global_registry().group("scan").counter(
@@ -584,25 +623,28 @@ def read_parquet_device(file_io: FileIO, path: str,
     DeviceDecodeUnsupported otherwise (caller falls back).
     `row_groups` restricts the read (the streamed-compaction batch
     iterator reads one group at a time to keep its memory bound)."""
-    md = _footer_metadata(file_io, path, options)
-    arrow_schema = md.schema.to_arrow_schema()
-    names = list(projection) if projection else list(arrow_schema.names)
-    col_idx = _check_supported(md, names)
-    groups = list(row_groups) if row_groups is not None \
-        else list(range(md.num_row_groups))
+    with _parsing():
+        md = _footer_metadata(file_io, path, options)
+        arrow_schema = md.schema.to_arrow_schema()
+        names = list(projection) if projection \
+            else list(arrow_schema.names)
+        col_idx = _check_supported(md, names)
+        groups = list(row_groups) if row_groups is not None \
+            else list(range(md.num_row_groups))
 
-    # one ranged read per (row group, column) chunk, all batched into a
-    # single read_ranges call (block-range cache / SSD tier / hedging)
-    ranges: List[Tuple[int, int]] = []
-    keys: List[Tuple[int, str]] = []
-    for rg in groups:
-        for name in names:
-            cm = md.row_group(rg).column(col_idx[name])
-            start = cm.data_page_offset
-            if cm.dictionary_page_offset is not None:
-                start = min(start, cm.dictionary_page_offset)
-            ranges.append((start, cm.total_compressed_size))
-            keys.append((rg, name))
+        # one ranged read per (row group, column) chunk, all batched
+        # into a single read_ranges call (block-range cache / SSD tier
+        # / hedging)
+        ranges: List[Tuple[int, int]] = []
+        keys: List[Tuple[int, str]] = []
+        for rg in groups:
+            for name in names:
+                cm = md.row_group(rg).column(col_idx[name])
+                start = cm.data_page_offset
+                if cm.dictionary_page_offset is not None:
+                    start = min(start, cm.dictionary_page_offset)
+                ranges.append((start, cm.total_compressed_size))
+                keys.append((rg, name))
     blobs = file_io.read_ranges(path, ranges) if ranges else []
     chunks = dict(zip(keys, blobs))
 
@@ -616,8 +658,9 @@ def read_parquet_device(file_io: FileIO, path: str,
             values, mask = _decode_chunk(
                 chunks[(rg, name)], cm,
                 schema_col.max_definition_level)
-            field_type = arrow_schema.field(name).type
-            arrays[name].append(_arrow_array(values, mask, field_type))
+            with _parsing():
+                arrays[name].append(_arrow_array(
+                    values, mask, arrow_schema.field(name).type))
     cols = {n: pa.chunked_array(arrays[n],
                                 type=arrow_schema.field(n).type)
             for n in names}
@@ -639,9 +682,10 @@ def iter_batches_device(file_io: FileIO, path: str,
     device decode exactly as it does on the pyarrow iter_batches path.
     Raises DeviceDecodeUnsupported before yielding anything when the
     file is outside coverage (checked from the footer alone)."""
-    md = _footer_metadata(file_io, path, options)
-    names = list(md.schema.to_arrow_schema().names)
-    _check_supported(md, names)            # EAGER: before any yield
+    with _parsing():
+        md = _footer_metadata(file_io, path, options)
+        names = list(md.schema.to_arrow_schema().names)
+        _check_supported(md, names)        # EAGER: before any yield
     return _iter_batches_device(file_io, path, batch_rows, options, md)
 
 
@@ -654,7 +698,7 @@ def _iter_batches_device(file_io, path, batch_rows, options, md):
         try:
             t = read_parquet_device(file_io, path, options=options,
                                     row_groups=[rg])
-        except _FALLBACK_ERRORS:
+        except DeviceDecodeUnsupported:
             # a page shape the footer cannot reveal (v2 data pages,
             # odd in-page encodings): the REMAINING row groups decode
             # through pyarrow — earlier groups already yielded the

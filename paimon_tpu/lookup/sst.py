@@ -29,8 +29,8 @@ File layout:
 Probes take the native path by default (native/probe.c
 `sst_probe_batch`: bloom + binary search over the flat key buffer, one
 C call per batch with the GIL released); when the shared object is
-unavailable or predates the probe symbols, the probe silently degrades
-to the vectorized numpy walk and counts a `lookup.native_fallbacks`.
+unavailable, the probe silently degrades to the vectorized numpy walk
+and counts a `lookup.native_fallbacks`.
 
 Both caches are bounded: the in-RAM block cache globally by bytes
 (lookup.cache-max-memory-size), the on-disk store per table by
@@ -364,9 +364,9 @@ class SstReader:
         Native by default: one `sst_probe_batch` C call resolves the
         whole batch (bloom + flat-key binary search, GIL released);
         only the few hit rows are then gathered from cached blocks.
-        Unavailable native (no compiler, PAIMON_DISABLE_NATIVE, or a
-        stale `.so` without the probe symbols) silently degrades to
-        the numpy path and counts a `lookup.native_fallbacks`.
+        Unavailable native (no compiler, PAIMON_DISABLE_NATIVE)
+        silently degrades to the numpy path and counts a
+        `lookup.native_fallbacks`.
 
         When `packed` is supplied, `lanes` may be None — both probe
         flavors work off the packed big-endian keys alone."""

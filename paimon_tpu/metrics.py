@@ -64,7 +64,7 @@ __all__ = ["Counter", "Gauge", "Histogram", "MetricGroup",
            "RESILIENCE_BROWNOUT_LEVEL", "RESILIENCE_HEDGE_WAIT_MS",
            "MULTIHOST_COMMIT_CONFLICTS", "MULTIHOST_COMMIT_RETRIES",
            "MULTIHOST_OWNERSHIP_HANDOFFS", "MULTIHOST_BARRIER_WAIT_MS",
-           "MULTIHOST_FOREIGN_ROWS", "MULTIHOST_CONFIG_WARNINGS",
+           "MULTIHOST_FOREIGN_ROWS",
            "MULTIHOST_OWNED_BUCKETS", "MULTIHOST_MAINTENANCE_TAKEOVERS",
            "MULTIHOST_LEASE_RENEWALS", "MULTIHOST_LEASE_EXPIRED",
            "PLAN_PLANS", "PLAN_MS", "PLAN_DELTA_APPLIES",
@@ -256,7 +256,6 @@ MULTIHOST_COMMIT_RETRIES = "commit_retries"
 MULTIHOST_OWNERSHIP_HANDOFFS = "ownership_handoffs"
 MULTIHOST_BARRIER_WAIT_MS = "barrier_wait_ms"
 MULTIHOST_FOREIGN_ROWS = "foreign_rows_routed"  # rows exchanged to owners
-MULTIHOST_CONFIG_WARNINGS = "config_warnings"   # collective-config fallbacks
 
 # multi-host MAINTENANCE-plane names (same multihost group; producer is
 # parallel/maintenance_plane.py, consumers the multi-host soak tests +

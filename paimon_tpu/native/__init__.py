@@ -5,6 +5,11 @@ The hot host-side sort of the merge plane compiles from
 next to this file; everything degrades gracefully to the numpy path
 when no compiler is available or PAIMON_DISABLE_NATIVE=1.
 
+The shared object's NAME carries a hash of the sources and the compile
+flags, so the only library this package ever loads is one built from
+the sources it sits next to: a copied tree (file times mean nothing
+there) or an edited source simply misses the cache and rebuilds.
+
 This is the framework's native-runtime layer in the sense of the
 reference's C/JVM-intrinsic sort machinery (paimon-core
 sort/BinaryInMemorySortBuffer, codegen'd comparators): Python stays
@@ -12,6 +17,7 @@ the control plane, the per-row inner loops live in C.
 """
 
 import ctypes
+import hashlib
 import os
 import shutil
 import subprocess
@@ -27,21 +33,34 @@ _DIR = os.path.dirname(os.path.abspath(__file__))
 # silently miss a new source file
 SOURCES = ("radix_sort.c", "probe.c")
 _SRCS = tuple(os.path.join(_DIR, s) for s in SOURCES)
-_SRC = _SRCS[0]                      # kept for older call sites
-_LIB_NAME = "_paimon_native.so"
+_CFLAGS = ("-O3", "-shared", "-fPIC")
 
-# symbols the ctypes wrappers bind, grouped by generation: REQUIRED
-# ones fail the whole load when absent, OPTIONAL ones (added after the
-# first shipped .so) degrade per-call to the Python path with a
-# lookup.native_fallbacks counter
+# symbols the ctypes wrappers bind; a library lacking any of them fails
+# the whole load (it cannot have been built from these sources)
 REQUIRED_SYMBOLS = ("radix_argsort_u64", "merge_winners_u64",
                     "ovc_codes_u64", "ovc_codes_lanes",
-                    "ovc_merge_u64", "ovc_merge_lanes")
-OPTIONAL_SYMBOLS = ("sst_probe_batch",)
-EXPORTED_SYMBOLS = REQUIRED_SYMBOLS + OPTIONAL_SYMBOLS
+                    "ovc_merge_u64", "ovc_merge_lanes",
+                    "sst_probe_batch")
 
 _lib: Optional[ctypes.CDLL] = None
+_lib_path: Optional[str] = None
 _tried = False
+
+
+def lib_name() -> str:
+    """`_paimon_native-<hash>.so`: the hash covers every source file's
+    bytes and the compile flags."""
+    h = hashlib.sha256(" ".join(_CFLAGS).encode())
+    for src in _SRCS:
+        with open(src, "rb") as f:
+            h.update(b"\0" + os.path.basename(src).encode() + b"\0")
+            h.update(f.read())
+    return f"_paimon_native-{h.hexdigest()[:16]}.so"
+
+
+def loaded_path() -> Optional[str]:
+    """File the loaded library came from; None when none is loaded."""
+    return _lib_path
 
 
 def _compiler():
@@ -51,45 +70,51 @@ def _compiler():
     return None
 
 
+def _compile(cc: str, out: str) -> Optional[str]:
+    """Compile every source into `out` (atomically, via a sibling temp
+    name); returns None on success, else the failure text."""
+    tmp = out + f".build-{os.getpid()}"
+    try:
+        proc = subprocess.run([cc, *_CFLAGS, "-o", tmp, *_SRCS],
+                              capture_output=True, text=True,
+                              timeout=120)
+        if proc.returncode != 0:
+            return proc.stderr[-1000:]
+        os.replace(tmp, out)         # atomic vs concurrent builders
+        return None
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return str(e)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+
+
 def _build(cc: str, use_cache: bool = True) -> Optional[str]:
-    """Compile the shared object; prefer caching it next to the source,
-    fall back to a temp dir when the package dir is not writable.  The
-    last failure's stderr is reported only if every location fails."""
+    """Path of the hash-named shared object, compiling it unless a file
+    of that name is already cached; prefer caching it next to the
+    source, fall back to a temp dir when the package dir is not
+    writable.  The last failure's stderr is reported only if every
+    location fails."""
+    name = lib_name()
     errors = []
     for make_dir in (lambda: _DIR,
                      lambda: tempfile.mkdtemp(prefix="paimon_native_")):
-        out_dir = make_dir()
-        out = os.path.join(out_dir, _LIB_NAME)
-        if use_cache and os.path.exists(out) and \
-                os.path.getmtime(out) >= max(os.path.getmtime(s)
-                                             for s in _SRCS):
+        out = os.path.join(make_dir(), name)
+        if use_cache and os.path.exists(out):
             return out
-        tmp = out + f".build-{os.getpid()}"
-        cmd = [cc, "-O3", "-shared", "-fPIC", "-o", tmp, *_SRCS]
-        try:
-            proc = subprocess.run(cmd, capture_output=True, text=True,
-                                  timeout=120)
-            if proc.returncode != 0:
-                errors.append(proc.stderr[-1000:])
-                continue             # e.g. read-only dir: try the next
-            os.replace(tmp, out)     # atomic vs concurrent builders
+        error = _compile(cc, out)
+        if error is None:
             return out
-        except (OSError, subprocess.TimeoutExpired) as e:
-            errors.append(str(e))
-            continue
-        finally:
-            if os.path.exists(tmp):
-                os.unlink(tmp)
-    if errors:
-        sys.stderr.write(f"paimon_tpu.native: build failed:\n"
-                         f"{errors[-1]}\n")
+        errors.append(error)         # e.g. read-only dir: try the next
+    sys.stderr.write(f"paimon_tpu.native: build failed:\n"
+                     f"{errors[-1]}\n")
     return None
 
 
 def load() -> Optional[ctypes.CDLL]:
     """The native library, building it on first use; None when
     unavailable (no compiler / disabled / build failure)."""
-    global _lib, _tried
+    global _lib, _lib_path, _tried
     if _lib is not None or _tried:
         return _lib
     _tried = True
@@ -136,17 +161,10 @@ def load() -> Optional[ctypes.CDLL]:
     lib.ovc_merge_lanes.argtypes = [p_u32, p_i64, p_u64, p_i64, i64,
                                     i64, i64, p_i32, p_u64]
     lib.ovc_merge_lanes.restype = ctypes.c_int
-    # OPTIONAL generation: a .so that predates probe.c still loads —
-    # the probe path degrades per-call to Python (the caller counts a
-    # lookup.native_fallbacks for it)
-    try:
-        lib.sst_probe_batch.argtypes = [p_u8, i64, i64, p_u64, i64,
-                                        i64, p_u8, p_u64, i64, p_i64,
-                                        p_i64]
-        lib.sst_probe_batch.restype = ctypes.c_int
-    except AttributeError:
-        pass
-    _lib = lib
+    lib.sst_probe_batch.argtypes = [p_u8, i64, i64, p_u64, i64, i64,
+                                    p_u8, p_u64, i64, p_i64, p_i64]
+    lib.sst_probe_batch.restype = ctypes.c_int
+    _lib, _lib_path = lib, path
     return _lib
 
 
@@ -279,10 +297,10 @@ def sst_probe(flat_keys: np.ndarray, n_rows: int, key_width: int,
     key buffer, one C call for the whole query batch.  Returns the per
     query row ranges (lo int64[m], hi int64[m]; lo==hi is a miss,
     -1/-1 a bloom rejection), or None when the native library is
-    unavailable or the loaded `.so` predates the probe symbols (the
-    caller falls back to the Python path and counts it)."""
+    unavailable (the caller falls back to the Python path and counts
+    it)."""
     lib = load()
-    if lib is None or not hasattr(lib, "sst_probe_batch"):
+    if lib is None:
         return None
     if bloom_bits is None:
         bloom_bits = np.zeros(0, dtype=np.uint64)
@@ -314,7 +332,7 @@ def _raw_probe():
     global _RAW_PROBE
     if _RAW_PROBE is None:
         lib = load()
-        if lib is None or not hasattr(lib, "sst_probe_batch"):
+        if lib is None:
             _RAW_PROBE = False
         else:
             addr = ctypes.cast(lib.sst_probe_batch,
@@ -379,14 +397,8 @@ def build_fresh(out_dir: str) -> Optional[str]:
     cc = _compiler()
     if cc is None:
         return None
-    out = os.path.join(out_dir, _LIB_NAME)
-    cmd = [cc, "-O3", "-shared", "-fPIC", "-o", out, *_SRCS]
-    try:
-        proc = subprocess.run(cmd, capture_output=True, text=True,
-                              timeout=120)
-    except (OSError, subprocess.TimeoutExpired):
-        return None
-    return out if proc.returncode == 0 else None
+    out = os.path.join(out_dir, lib_name())
+    return out if _compile(cc, out) is None else None
 
 
 def merge_winners(keys: np.ndarray, seq: np.ndarray, keep_last: bool

@@ -15,7 +15,13 @@ data-parallel kernels:
 Design notes: all kernels use static shapes (inputs padded to bucketized
 sizes), uint32 lanes (TPU-native; 64-bit values split hi/lo), and
 jnp-only control flow so XLA can fuse and tile freely.
+
+Every entry point imports this package before it runs a kernel, so the
+two process-wide JAX settings live here and nowhere else: 64-bit types,
+and where the persistent compile cache is kept.
 """
+
+import os as _os
 
 import jax as _jax
 
@@ -24,5 +30,21 @@ import jax as _jax
 # acceptable: the hot sort path uses uint32 lanes regardless.
 _jax.config.update("jax_enable_x64", True)
 
-from paimon_tpu.ops.normkey import NormalizedKeyEncoder  # noqa: F401
-from paimon_tpu.ops.merge import merge_runs, MergeResult  # noqa: F401
+
+def default_compile_cache_dir() -> str:
+    """`<checkout>/.jax_cache`, derived from this package's own path.
+    The directory is part of the cache key, so it must not move between
+    runs: never a temp name, a pid or a time."""
+    checkout = _os.path.dirname(_os.path.dirname(
+        _os.path.dirname(_os.path.abspath(__file__))))
+    return _os.path.join(checkout, ".jax_cache")
+
+
+# JAX reads JAX_COMPILATION_CACHE_DIR itself; only when the operator has
+# not placed the cache do we place it, at the one fixed path above.
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _jax.config.update("jax_compilation_cache_dir",
+                       default_compile_cache_dir())
+
+from paimon_tpu.ops.normkey import NormalizedKeyEncoder  # noqa: F401,E402
+from paimon_tpu.ops.merge import merge_runs, MergeResult  # noqa: F401,E402

@@ -6,7 +6,9 @@ AggregateMergeFunction + 24 FieldAggregators (mergetree/compact/aggregate/).
 The record-at-a-time accumulate loop becomes: device sort by (key, seq)
 (shared kernel in ops/merge.py) -> per-key segment ids -> per-column
 segmented reduction. Numeric sum/max/min/count/product run on device via
-jax.ops.segment_*; order-based aggregates (last/first[-non-null] value,
+jax.ops.segment_* (float64 columns on the host: the chip has no 64-bit
+float, see _host_segment_reduce); order-based aggregates
+(last/first[-non-null] value,
 listagg, strings) reduce to a per-segment index selection computed on
 device and a host-side Arrow take, so variable-length data never crosses
 to HBM.
@@ -156,17 +158,36 @@ def _seg_prod_jit(vals, seg_ids, num_seg):
     return jax.ops.segment_prod(vals, seg_ids, num_segments=num_seg)
 
 
-def _padded_seg(fn_jit):
+def _host_segment_reduce(ufunc, vals: np.ndarray, seg_ids: np.ndarray,
+                         num_seg: int) -> np.ndarray:
+    """Segmented reduce on the host, for the one value type the chip
+    cannot hold: XLA's TPU backend keeps a float64 as a pair of
+    float32, so 1e300 arrives as inf, 1e-300 as 0 and pi without its
+    low bits — a reduce there cannot be bit-identical.  Relies on this
+    module's sorted-segment contract (`seg_ids` ascending and dense)."""
+    starts = np.flatnonzero(np.concatenate(
+        [[True], seg_ids[1:] != seg_ids[:-1]]))
+    if len(starts) != num_seg:
+        raise ValueError(f"segment ids not ascending and dense: "
+                         f"{len(starts)} runs for {num_seg} segments")
+    return ufunc.reduceat(vals, starts)
+
+
+def _padded_seg(fn_jit, ufunc):
     """BOTH the row count and num_segments pad to powers of two, so XLA
     compiles O(log^2) distinct shapes across a whole compaction instead
     of one per window (a streamed merge emits hundreds of distinct
     (rows, segments) pairs; each used to recompile).  Padding rows
     point at a dedicated dummy segment past num_seg, which the final
-    slice drops — their values never touch a real segment."""
+    slice drops — their values never touch a real segment.
+
+    float64 values reduce on the host (`_host_segment_reduce`)."""
     def call(vals, seg_ids, num_seg):
         vals = np.asarray(vals)
         seg_ids = np.asarray(seg_ids)
         n = len(vals)
+        if vals.dtype == np.float64 and n:
+            return _host_segment_reduce(ufunc, vals, seg_ids, num_seg)
         # strictly greater than num_seg so the dummy segment exists
         padded_seg = 1 << max(4, int(num_seg).bit_length())
         m = 1 << max(10, int(n - 1).bit_length()) if n > 1 else 1024
@@ -181,10 +202,10 @@ def _padded_seg(fn_jit):
     return call
 
 
-_seg_sum = _padded_seg(_seg_sum_jit)
-_seg_max = _padded_seg(_seg_max_jit)
-_seg_min = _padded_seg(_seg_min_jit)
-_seg_prod = _padded_seg(_seg_prod_jit)
+_seg_sum = _padded_seg(_seg_sum_jit, np.add)
+_seg_max = _padded_seg(_seg_max_jit, np.maximum)
+_seg_min = _padded_seg(_seg_min_jit, np.minimum)
+_seg_prod = _padded_seg(_seg_prod_jit, np.multiply)
 
 
 def _last_index_where(mask: np.ndarray, seg_id: np.ndarray,

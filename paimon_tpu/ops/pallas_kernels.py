@@ -34,30 +34,12 @@ _BLOCK_ROWS = 8
 _LANE = 128
 PALLAS_TILE = _BLOCK_ROWS * _LANE     # N must be a multiple of this
 
-# flipped by disable_pallas_runtime() when a real-hardware Mosaic
-# compile fails mid-run: callers retry on the pure-XLA path and every
-# later merge skips the kernel for the life of the process
-_RUNTIME_DISABLED = False
-
-
-def disable_pallas_runtime(reason: str = "") -> None:
-    """Permanently (for this process) turn the Pallas path off — called
-    when Mosaic rejects the kernel on the actual backend so the merge
-    plane can recompile without it instead of failing the job."""
-    global _RUNTIME_DISABLED
-    if not _RUNTIME_DISABLED:
-        import sys
-        sys.stderr.write(
-            f"paimon_tpu: disabling Pallas kernels for this process"
-            f"{': ' + reason if reason else ''}\n")
-    _RUNTIME_DISABLED = True
-
 
 def pallas_enabled() -> bool:
     """Kernel on for TPU (compiled) and cpu (interpret mode, so tests
     run the identical program); other accelerators keep the fused XLA
     path — interpret-emulating a grid there would be a regression."""
-    if _RUNTIME_DISABLED or os.environ.get("PAIMON_DISABLE_PALLAS") == "1":
+    if os.environ.get("PAIMON_DISABLE_PALLAS") == "1":
         return False
     return jax.default_backend() in ("tpu", "cpu")
 
@@ -105,7 +87,12 @@ def _eq_next_fn(num_lanes: int, n: int, interpret: bool,
             consec = perm_nxt[...] == perm_cur[...] + 1
             known = off_nxt[...] != jnp.uint32(_OVC_SENTINEL)
             eq_code = off_nxt[...] >= jnp.uint32(num_key_lanes)
-            eq = jnp.where(jnp.logical_and(consec, known), eq_code, eq)
+            # the select runs on 32-bit words: Mosaic (libtpu 0.0.34)
+            # refuses a select whose OPERANDS are i1 vectors
+            # ("Unsupported target bitwidth for truncation", i8 -> i1)
+            eq = jnp.where(jnp.logical_and(consec, known),
+                           eq_code.astype(jnp.uint32),
+                           eq.astype(jnp.uint32)) != jnp.uint32(0)
         eq = jnp.logical_and(eq, inv_cur[...] == inv_nxt[...])
         out[...] = eq.astype(jnp.uint32)
 

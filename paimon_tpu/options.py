@@ -950,9 +950,8 @@ class CoreOptions:
         "flat sorted key buffer laid out at SST build time, one call "
         "per (bucket, sorted-run) file with the GIL released.  "
         "Degrades silently to the vectorized numpy walk — counting "
-        "lookup.native_fallbacks — when no compiler is available, "
-        "PAIMON_DISABLE_NATIVE=1, or the cached .so predates the "
-        "probe symbols; false forces the numpy walk")
+        "lookup.native_fallbacks — when no compiler is available or "
+        "PAIMON_DISABLE_NATIVE=1; false forces the numpy walk")
     SERVICE_WARMBOOT_ENABLED = ConfigOption(
         "service.warmboot.enabled", _parse_bool, False,
         "Boot serving replicas WARM from state persisted through the "

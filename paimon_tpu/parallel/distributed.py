@@ -631,8 +631,8 @@ class DistributedWritePlane:
                              f"{self.arbitration!r}")
         from paimon_tpu.metrics import (
             MULTIHOST_BARRIER_WAIT_MS, MULTIHOST_COMMIT_CONFLICTS,
-            MULTIHOST_COMMIT_RETRIES, MULTIHOST_CONFIG_WARNINGS,
-            MULTIHOST_FOREIGN_ROWS, MULTIHOST_OWNERSHIP_HANDOFFS,
+            MULTIHOST_COMMIT_RETRIES, MULTIHOST_FOREIGN_ROWS,
+            MULTIHOST_OWNERSHIP_HANDOFFS,
             global_registry,
         )
         self._metrics = global_registry().multihost_metrics()
@@ -640,8 +640,7 @@ class DistributedWritePlane:
         # Prometheus endpoint always expose them (a conflict-free run
         # must render commit_conflicts 0, not omit the series)
         for c in (MULTIHOST_COMMIT_CONFLICTS, MULTIHOST_COMMIT_RETRIES,
-                  MULTIHOST_OWNERSHIP_HANDOFFS, MULTIHOST_FOREIGN_ROWS,
-                  MULTIHOST_CONFIG_WARNINGS):
+                  MULTIHOST_OWNERSHIP_HANDOFFS, MULTIHOST_FOREIGN_ROWS):
             self._metrics.counter(c)
         self._metrics.histogram(MULTIHOST_BARRIER_WAIT_MS)
         # dynamic (load-time) options are NOT in the on-disk schema;

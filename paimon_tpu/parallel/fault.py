@@ -14,8 +14,9 @@ failing the whole job.  The degradation ladder is:
                         ->  raise (bucket unrecoverable; job fails)
 
 Only *transient* errors ride the ladder.  Programming errors
-(ValueError, KeyError, schema bugs) propagate immediately — retrying
-them would loop deterministically and degrade silently.
+(ValueError, KeyError, schema bugs) and compile/lowering refusals of a
+device program propagate immediately — retrying them would loop
+deterministically and degrade silently.
 """
 
 from __future__ import annotations
@@ -29,16 +30,23 @@ from paimon_tpu.options import CoreOptions
 __all__ = ["is_transient_error", "BucketRetryPolicy"]
 
 # error class NAMES treated as device/lane loss: jax surfaces device
-# failures as jaxlib XlaRuntimeError (a RuntimeError subclass we must
-# not import at module scope — jax loads lazily everywhere else)
-_DEVICE_ERROR_NAMES = frozenset({"XlaRuntimeError"})
+# failures as jax.errors.JaxRuntimeError (a RuntimeError subclass we
+# must not import at module scope — jax loads lazily everywhere else)
+_DEVICE_ERROR_NAMES = frozenset({"JaxRuntimeError"})
+
+# the same class also carries XLA/Mosaic compile and lowering refusals;
+# its message leads with the status code.  These are what a program the
+# compiler cannot build (or that cannot fit) reports — deterministic
+# for a given program, so never transient
+_COMPILE_STATUSES = ("INTERNAL", "UNIMPLEMENTED", "INVALID_ARGUMENT",
+                     "RESOURCE_EXHAUSTED")
 
 
 def is_transient_error(exc: BaseException) -> bool:
     """True when `exc` is worth retrying: a store-side 503
     (TransientStoreError), an IO fault (OSError covers InjectedIOError
     and FileNotFoundError from racing maintenance), or a device/lane
-    loss (XlaRuntimeError).
+    loss (JaxRuntimeError whose status is not a compile refusal).
 
     DECODE errors are excluded even though they reach us as OSError
     (modern pyarrow raises plain OSError for torn footers / corrupt
@@ -62,8 +70,10 @@ def is_transient_error(exc: BaseException) -> bool:
         return False
     if isinstance(exc, (TransientStoreError, OSError)):
         return True
-    return any(t.__name__ in _DEVICE_ERROR_NAMES
-               for t in type(exc).__mro__)
+    if any(t.__name__ in _DEVICE_ERROR_NAMES
+           for t in type(exc).__mro__):
+        return not str(exc).lstrip().startswith(_COMPILE_STATUSES)
+    return False
 
 
 @dataclass

@@ -117,7 +117,6 @@ class _MeshWindowKernel:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from paimon_tpu.ops.merge import segmented_merge_body
-        from paimon_tpu.parallel._compat import shard_map
 
         self.sharding = NamedSharding(mesh, P(axis))
         self._n_dev = mesh.shape[axis]
@@ -129,7 +128,7 @@ class _MeshWindowKernel:
                 num_key_lanes=num_key_lanes, ovc_off=ovc_off)
             return perm, winner
 
-        @partial(shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(P(axis), P(axis), P(axis), P(axis),
                            P(axis)),
                  out_specs=(P(axis), P(axis), P()))
@@ -361,14 +360,14 @@ class _BucketJob:
                 # the pyarrow batch path); unsupported files drop to
                 # the format reader below
                 from paimon_tpu.format.rawpage import (
-                    _FALLBACK_ERRORS, iter_batches_device,
+                    DeviceDecodeUnsupported, iter_batches_device,
                 )
                 batches = None
                 try:
                     batches = iter_batches_device(
                         ctx.table.file_io, path, ctx.chunk_rows,
                         options)
-                except _FALLBACK_ERRORS:
+                except DeviceDecodeUnsupported:
                     from paimon_tpu.metrics import (
                         SCAN_DEVICE_DECODE_FALLBACKS, global_registry,
                     )
@@ -771,8 +770,13 @@ def compact_table_mesh(table, mesh=None, axis: str = "buckets",
                 perm, winner, _ = kernel(lanes_arr, seq_hi, seq_lo,
                                          invalid, ovc_arr)
         except Exception as e:              # noqa: BLE001
-            # a kernel failure is a lane/device failure for every
-            # bucket in flight this step: each rides its own ladder
+            if not is_transient_error(e):
+                # a program the compiler refuses (or a bug) is not a
+                # lane failure: no bucket degrades to the single-chip
+                # manager on its account
+                raise
+            # a device loss is a lane failure for every bucket in
+            # flight this step: each rides its own ladder
             for li, entry in enumerate(device_rows):
                 if entry is not None:
                     _handle_bucket_failure(li, entry[0], e)

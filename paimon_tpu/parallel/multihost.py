@@ -38,14 +38,19 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 
+# the coordination service counts a peer's silence in heartbeats of this
+# many seconds; its own knob is the total timeout
+_HEARTBEAT_INTERVAL_S = 10
+
+
 def peer_death_tolerance(max_missing_heartbeats: Optional[int] = None
                          ) -> dict:
-    """Heartbeat-tolerance kwargs for the distributed runtime client
-    AND the coordination service, from the explicit argument or the
+    """Heartbeat-tolerance kwargs for `jax.distributed.initialize`,
+    from the explicit argument or the
     `PAIMON_MULTIHOST_PEER_MISSED_HEARTBEATS` env var.  Empty dict
-    when neither is set (jax defaults apply: ~10 missed heartbeats at
-    10s intervals, after which the coordination service declares the
-    quiet task crashed and FATALLY tears down every other task).
+    when neither is set (jax's default applies: after ~100s of silence
+    the coordination service declares the quiet task crashed and
+    FATALLY tears down every other task).
 
     That default contradicts this repo's fleet design: host death is
     an EXPECTED event the lease detector (parallel/maintenance_plane)
@@ -59,8 +64,8 @@ def peer_death_tolerance(max_missing_heartbeats: Optional[int] = None
             max_missing_heartbeats = int(env)
     if max_missing_heartbeats is None:
         return {}
-    return {"service_max_missing_heartbeats": max_missing_heartbeats,
-            "client_max_missing_heartbeats": max_missing_heartbeats}
+    return {"heartbeat_timeout_seconds":
+            max_missing_heartbeats * _HEARTBEAT_INTERVAL_S}
 
 
 def initialize(coordinator_address: Optional[str] = None,
@@ -76,7 +81,10 @@ def initialize(coordinator_address: Optional[str] = None,
     `PAIMON_MULTIHOST_PEER_MISSED_HEARTBEATS` env var) widens how many
     10s heartbeats a peer may miss before the coordination service
     declares it crashed and aborts the WHOLE mesh — see
-    `peer_death_tolerance` for why lease-governed fleets want this."""
+    `peer_death_tolerance` for why lease-governed fleets want this.
+
+    CPU meshes ride jax's Gloo cross-process collectives, which the
+    installed jax enables by default."""
     import jax
 
     coordinator_address = coordinator_address or \
@@ -86,79 +94,11 @@ def initialize(coordinator_address: Optional[str] = None,
     if process_id is None:
         process_id = int(os.environ.get("PROCESS_ID", "0"))
     if num_processes > 1:
-        # jax 0.4.x ships the CPU backend with cross-process
-        # collectives DISABLED by default — without opting into the
-        # Gloo implementation, the first multiprocess computation
-        # fails with "Multiprocess computations aren't implemented on
-        # the CPU backend" (the long-standing test_multihost_real
-        # red).  Harmless on TPU (the setting only affects the CPU
-        # backend); must run before the backend initializes.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-        except (AttributeError, ValueError, KeyError) as e:
-            # other jax versions: the flag may not exist (newer
-            # releases enable cross-process CPU collectives through
-            # the distributed runtime itself).  NOT silent: a pod that
-            # falls back to broken CPU collectives fails much later
-            # with an inscrutable "Multiprocess computations aren't
-            # implemented" — surface the config miss now so that
-            # failure is diagnosable from the warning + metric.
-            import warnings
-
-            from paimon_tpu.metrics import (
-                MULTIHOST_CONFIG_WARNINGS, global_registry,
-            )
-            warnings.warn(
-                "multihost.initialize: could not opt the CPU backend "
-                f"into Gloo cross-process collectives ({e!r}); if "
-                "this jax build lacks them, the first cross-process "
-                "computation will fail with 'Multiprocess "
-                "computations aren't implemented on the CPU backend'",
-                RuntimeWarning, stacklevel=2)
-            global_registry().multihost_metrics().counter(
-                MULTIHOST_CONFIG_WARNINGS).inc()
-        tolerance = peer_death_tolerance(max_missing_heartbeats)
-        if tolerance:
-            # the public wrapper does not forward heartbeat knobs
-            # (jax 0.4.x); mirror its one precondition and call the
-            # runtime state directly.  A jax build whose internals
-            # moved falls back to the default (intolerant) bring-up —
-            # NOT silent, same warning+metric contract as the gloo
-            # opt-in above: the mesh still comes up, but survivors
-            # will be aborted ~100s after a peer dies
-            try:
-                from jax._src import distributed as _dist
-                from jax._src import xla_bridge as _bridge
-                if _bridge.backends_are_initialized():
-                    raise RuntimeError(
-                        "multihost.initialize must run before any JAX "
-                        "computation")
-                _dist.global_state.initialize(
-                    coordinator_address=coordinator_address,
-                    num_processes=num_processes,
-                    process_id=process_id,
-                    **tolerance)
-                return jax.process_index(), jax.process_count()
-            except (ImportError, AttributeError, TypeError) as e:
-                import warnings
-
-                from paimon_tpu.metrics import (
-                    MULTIHOST_CONFIG_WARNINGS, global_registry,
-                )
-                warnings.warn(
-                    "multihost.initialize: this jax build does not "
-                    f"expose coordination heartbeat tolerance ({e!r});"
-                    " peers that outlive a dead host past the default "
-                    "~100s window will be aborted by the coordination "
-                    "service despite holding valid leases",
-                    RuntimeWarning, stacklevel=2)
-                global_registry().multihost_metrics().counter(
-                    MULTIHOST_CONFIG_WARNINGS).inc()
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes,
-            process_id=process_id)
+            process_id=process_id,
+            **peer_death_tolerance(max_missing_heartbeats))
     return jax.process_index(), jax.process_count()
 
 

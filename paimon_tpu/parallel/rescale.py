@@ -36,11 +36,9 @@ def _dispatch_kernel(mesh, axis: str, n_per_dev: int, cap: int,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from paimon_tpu.parallel._compat import shard_map
-
     n_dev = mesh.shape[axis]
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(axis), P(axis), P(axis)),
              out_specs=(P(axis), P(axis), P(axis)))
     def step(hashes, valid, row_gid):

@@ -43,7 +43,6 @@ class _ShardedCompactKernel:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from paimon_tpu.ops.merge import segmented_merge_body
-        from paimon_tpu.parallel._compat import shard_map
 
         self.mesh = mesh
         self.axis = axis
@@ -61,7 +60,7 @@ class _ShardedCompactKernel:
             live = winner & ((s_kinds == 0) | (s_kinds == 2))
             return perm, winner, live
 
-        @partial(shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis)),
                  out_specs=(P(axis), P(axis), P(axis), P(axis), P(), P()))
         def step(lanes, seq_hi, seq_lo, invalid, kinds):

@@ -80,7 +80,6 @@ class ShardedBucketMerge:
         n_dev = mesh.shape[axis]
 
         from paimon_tpu.ops.merge import segmented_merge_body
-        from paimon_tpu.parallel._compat import shard_map
 
         def per_bucket(lanes, seq_hi, seq_lo, invalid):
             perm, winner, _ = segmented_merge_body(
@@ -88,7 +87,7 @@ class ShardedBucketMerge:
                 seq_hi, seq_lo, invalid, keep)
             return perm, winner
 
-        @partial(shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(P(axis), P(axis), P(axis), P(axis)),
                  out_specs=(P(axis), P(axis), P()))
         def step(lanes, seq_hi, seq_lo, invalid):

@@ -189,6 +189,53 @@ def test_maybe_read_device_counts_fallback(tmp_path, fio):
     assert after == before + 1
 
 
+@pytest.mark.parametrize("exc", [TypeError, ValueError,
+                                 NotImplementedError])
+@pytest.mark.parametrize("transform", ["plain_to_u64",
+                                       "expand_rle_hybrid"])
+def test_device_transform_error_propagates(tmp_path, fio, monkeypatch,
+                                           transform, exc):
+    """What JAX raises for a lowering it cannot do (TypeError,
+    ValueError, NotImplementedError) comes from the DEVICE step: it
+    fails the read.  Only the parse step's errors mean "uncovered
+    file" — a truncated page of the same file still falls back."""
+    import paimon_tpu.ops.decode as decode
+    from paimon_tpu.format.rawpage import maybe_read_device
+    from paimon_tpu.metrics import (
+        SCAN_DEVICE_DECODE_FALLBACKS, global_registry,
+    )
+    rng = np.random.default_rng(3)
+    n = 5_000
+    t = pa.table({"x": pa.array(rng.integers(0, 1 << 40, n), pa.int64(),
+                                mask=rng.random(n) < 0.2)})
+    p = str(tmp_path / "dev.parquet")
+    pq.write_table(t, p, use_dictionary=False)
+    assert maybe_read_device(fio, p).equals(pq.read_table(p))
+
+    def refuse(*a, **k):
+        raise exc("no lowering for this on the backend")
+
+    counter = global_registry().group("scan").counter(
+        SCAN_DEVICE_DECODE_FALLBACKS)
+    before = counter.count
+    with monkeypatch.context() as m:
+        m.setattr(decode, transform, refuse)
+        with pytest.raises(exc, match="no lowering"):
+            maybe_read_device(fio, p)
+    assert counter.count == before          # not counted as a fallback
+
+    # the parse step keeps its fallback: cut the file's pages short
+    raw = open(p, "rb").read()
+    md = pq.read_metadata(p)
+    start = md.row_group(0).column(0).data_page_offset
+    torn = bytearray(raw)
+    torn[start:start + 6] = b"\xff" * 6      # garbage page header
+    p2 = str(tmp_path / "torn.parquet")
+    open(p2, "wb").write(bytes(torn))
+    assert maybe_read_device(fio, p2) is None
+    assert counter.count == before + 1
+
+
 # ---------------------------------------------------------------------------
 # 2. end-to-end table reads
 # ---------------------------------------------------------------------------

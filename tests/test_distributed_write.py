@@ -457,7 +457,6 @@ import os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(dev)d"
 import jax
-jax.config.update("jax_platforms", "cpu")
 
 pid = int(sys.argv[1]); port = sys.argv[2]; table_path = sys.argv[3]
 sys.path.insert(0, sys.argv[4]); n_procs = int(sys.argv[5])

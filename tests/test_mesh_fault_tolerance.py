@@ -26,9 +26,10 @@ from tests.failing_fileio import FailingFileIO, InjectedIOError
 from tests.store_oracle import make_random_engine_table
 from tests.test_mesh_engine import _bucket_kv, _rows
 
-# jax surfaces device loss as jaxlib's XlaRuntimeError; tests model it
-# with a same-named class so is_transient_error's name check fires
-XlaRuntimeError = type("XlaRuntimeError", (RuntimeError,), {})
+# the class the installed jax raises for device loss AND for compile
+# refusals (jax.errors.JaxRuntimeError -> RuntimeError); the status
+# that leads its message tells the two apart
+from jax.errors import JaxRuntimeError  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -123,7 +124,7 @@ def test_device_loss_degrades_every_bucket(tmp_path, mesh, monkeypatch):
     monkeypatch.setattr(
         me._MeshWindowKernel, "__call__",
         lambda self, *a: (_ for _ in ()).throw(
-            XlaRuntimeError("device lost")))
+            JaxRuntimeError("UNAVAILABLE: device lost")))
     stats = compact_table_mesh(faulty, mesh,
                                retry_policy=_policy(max_attempts=2))
     assert stats.snapshot_id is not None
@@ -131,6 +132,40 @@ def test_device_loss_degrades_every_bucket(tmp_path, mesh, monkeypatch):
     reread = FileStoreTable.load(faulty.path)
     assert _bucket_kv(reread) == _bucket_kv(clean)
     assert _rows(reread) == _rows(clean)
+
+
+@pytest.mark.parametrize("message", [
+    "INTERNAL: Mosaic failed to compile TPU kernel: unsupported op",
+    "UNIMPLEMENTED: While rewriting computation to not contain X64 "
+    "element types, XLA encountered an HLO for which this rewriting "
+    "is not implemented",
+    "INVALID_ARGUMENT: layout mismatch",
+    "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+    "memory in memory space hbm",
+])
+def test_compile_failure_of_the_window_kernel_propagates(
+        tmp_path, mesh, monkeypatch, message):
+    """A program the compiler refuses is not a lane failure: the job
+    fails at the call that needed the kernel — no retry, no bucket
+    quietly degraded to the single-chip manager, nothing committed."""
+    table = make_random_engine_table(str(tmp_path / "t"), 21,
+                                     "deduplicate", buckets=3)
+    calls = {"n": 0}
+
+    def refuse(self, *a):
+        calls["n"] += 1
+        raise JaxRuntimeError(message)
+
+    monkeypatch.setattr(me._MeshWindowKernel, "__call__", refuse)
+    retries0 = _counter(COMPACTION_BUCKET_RETRIES)
+    fallbacks0 = _counter(COMPACTION_BUCKET_FALLBACKS)
+    with pytest.raises(JaxRuntimeError, match=message.split(":")[0]):
+        compact_table_mesh(table, mesh, retry_policy=_policy())
+    assert calls["n"] == 1
+    assert _counter(COMPACTION_BUCKET_RETRIES) == retries0
+    assert _counter(COMPACTION_BUCKET_FALLBACKS) == fallbacks0
+    assert FileStoreTable.load(table.path).latest_snapshot() \
+        .commit_kind != "COMPACT"
 
 
 def test_fallback_disabled_raises_after_retries(tmp_path, mesh):
@@ -180,7 +215,15 @@ def test_is_transient_error_taxonomy():
     assert is_transient_error(InjectedIOError("killed"))
     assert is_transient_error(OSError("io"))
     assert is_transient_error(FileNotFoundError("raced"))
-    assert is_transient_error(XlaRuntimeError("device lost"))
+    assert is_transient_error(JaxRuntimeError("UNAVAILABLE: device lost"))
+    assert is_transient_error(JaxRuntimeError("device lost"))
+    # the real class is what the name check keys on
+    assert "JaxRuntimeError" in {c.__name__
+                                 for c in JaxRuntimeError.__mro__}
+    assert not is_transient_error(
+        JaxRuntimeError("INTERNAL: Mosaic failed to compile"))
+    assert not is_transient_error(
+        JaxRuntimeError("RESOURCE_EXHAUSTED: out of HBM at compile"))
     assert not is_transient_error(ValueError("bug"))
     assert not is_transient_error(KeyError("bug"))
     assert not is_transient_error(RuntimeError("generic"))

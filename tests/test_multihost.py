@@ -25,19 +25,34 @@ class TestBootstrap:
         assert MH.peer_death_tolerance() == {}
 
     def test_peer_death_tolerance_explicit_and_env(self, monkeypatch):
+        # N missed 10s heartbeats -> the runtime's total timeout
         assert MH.peer_death_tolerance(360) == {
-            "service_max_missing_heartbeats": 360,
-            "client_max_missing_heartbeats": 360,
-        }
+            "heartbeat_timeout_seconds": 3600}
         monkeypatch.setenv("PAIMON_MULTIHOST_PEER_MISSED_HEARTBEATS",
                            "25")
         assert MH.peer_death_tolerance() == {
-            "service_max_missing_heartbeats": 25,
-            "client_max_missing_heartbeats": 25,
-        }
+            "heartbeat_timeout_seconds": 250}
         # explicit argument wins over the env var
-        assert MH.peer_death_tolerance(7)[
-            "client_max_missing_heartbeats"] == 7
+        assert MH.peer_death_tolerance(7) == {
+            "heartbeat_timeout_seconds": 70}
+
+    def test_initialize_forwards_tolerance_to_the_runtime(
+            self, monkeypatch):
+        """The one bring-up path: the public jax call, which on the
+        installed jax takes the heartbeat budget itself."""
+        import inspect
+
+        import jax
+
+        assert "heartbeat_timeout_seconds" in inspect.signature(
+            jax.distributed.initialize).parameters
+        inits = []
+        monkeypatch.setattr(jax.distributed, "initialize",
+                            lambda **kw: inits.append(kw))
+        MH.initialize("127.0.0.1:1", 2, 0, max_missing_heartbeats=360)
+        assert inits == [{"coordinator_address": "127.0.0.1:1",
+                          "num_processes": 2, "process_id": 0,
+                          "heartbeat_timeout_seconds": 3600}]
 
 
 class TestGlobalMesh:
@@ -153,36 +168,3 @@ class TestSplitAssignment:
 
     def test_commit_user(self):
         assert MH.distributed_write_commit_user("w") == "w-p0"
-
-
-class TestInitializeConfigWarning:
-    def test_gloo_config_failure_warns_not_silent(self, monkeypatch):
-        """A jax build where the Gloo opt-in flag is missing must warn
-        through the obs plane (+ multihost config_warnings counter),
-        not silently proceed into broken CPU collectives."""
-        import warnings
-
-        import jax
-
-        from paimon_tpu.metrics import (
-            MULTIHOST_CONFIG_WARNINGS, global_registry,
-        )
-
-        def boom(key, value):
-            raise ValueError(f"no such config {key}")
-
-        inits = []
-        monkeypatch.setattr(jax.config, "update", boom)
-        monkeypatch.setattr(jax.distributed, "initialize",
-                            lambda **kw: inits.append(kw))
-        counter = global_registry().multihost_metrics().counter(
-            MULTIHOST_CONFIG_WARNINGS)
-        before = counter.count
-        with warnings.catch_warnings(record=True) as caught:
-            warnings.simplefilter("always")
-            idx, count = MH.initialize("127.0.0.1:1", 2, 0)
-        assert len(inits) == 1              # runtime still brought up
-        msgs = [str(w.message) for w in caught]
-        assert any("Gloo" in m and "cross-process" in m for m in msgs), \
-            msgs
-        assert counter.count == before + 1

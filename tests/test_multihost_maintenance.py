@@ -93,7 +93,6 @@ import os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax
-jax.config.update("jax_platforms", "cpu")
 
 pid = int(sys.argv[1]); port = sys.argv[2]; table_path = sys.argv[3]
 REPO = sys.argv[4]

@@ -43,7 +43,6 @@ import os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax
-jax.config.update("jax_platforms", "cpu")
 
 pid = int(sys.argv[1]); port = sys.argv[2]; table_path = sys.argv[3]
 sys.path.insert(0, sys.argv[4])

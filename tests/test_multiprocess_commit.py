@@ -15,7 +15,6 @@ import pytest
 _WORKER = r"""
 import os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
-import jax; jax.config.update("jax_platforms", "cpu")
 from paimon_tpu.table import FileStoreTable
 
 path, worker_id, n_commits = sys.argv[1], int(sys.argv[2]), int(sys.argv[3])

@@ -1,14 +1,14 @@
 """Native serving hot path (PR 18).
 
 * BUILD SMOKE: `native/*.c` compiles fresh in a temp dir and the
-  resulting `.so` exports EVERY symbol python binds — the guard
-  against a probe symbol silently missing (a stale cached lib would
-  serve the slow path forever).
+  resulting `.so` exports EVERY symbol python binds; the library the
+  process loads is named after a hash of those sources, so a binary
+  built from other sources can never be picked up.
 * PROBE PARITY: the batched C probe (`sst_probe_batch`) against the
   python bloom+searchsorted oracle — identical hits and rows across
   tombstones, empty SSTs, equal-key runs spanning blocks, partitioned
   batches, and misses.
-* FALLBACK: a lib without the probe symbols degrades per-call to the
+* FALLBACK: with no native library the probe degrades per-call to the
   python path, counted by `lookup.native_fallbacks`, answers
   unchanged.
 * CONCURRENT SERVING: /lookup batches through the native probe under
@@ -44,10 +44,9 @@ from paimon_tpu.table import FileStoreTable
 from paimon_tpu.types import BigIntType, IntType, VarCharType
 
 _HAS_NATIVE = native.load() is not None
-_HAS_PROBE = _HAS_NATIVE and hasattr(native.load(), "sst_probe_batch")
 
 needs_probe = pytest.mark.skipif(
-    not _HAS_PROBE, reason="native sst_probe_batch unavailable")
+    not _HAS_NATIVE, reason="native sst_probe_batch unavailable")
 
 
 def _counter(name):
@@ -82,24 +81,46 @@ def _commit(table, rows, kinds=None):
 class TestNativeBuildSmoke:
     def test_fresh_build_exports_every_bound_symbol(self, tmp_path):
         """Compile native/*.c from scratch; the .so must export every
-        symbol the python side binds (REQUIRED + OPTIONAL) — the
-        build-level guard that a new symbol generation actually made
-        it into the artifact."""
+        symbol the python side binds — the build-level guard that a
+        new symbol actually made it into the artifact."""
         so = native.build_fresh(str(tmp_path))
         lib = ctypes.CDLL(so)
-        for sym in native.EXPORTED_SYMBOLS:
+        for sym in native.REQUIRED_SYMBOLS:
             assert hasattr(lib, sym), f"fresh .so missing {sym}"
 
-    def test_loaded_lib_exports_every_bound_symbol(self):
-        """The CACHED lib the process actually serves with has the full
-        symbol set too — a stale .so from before a new symbol was
-        added loads fine but would silently pin the fallback path."""
-        lib = native.load()
-        missing = [s for s in native.EXPORTED_SYMBOLS
-                   if not hasattr(lib, s)]
-        assert not missing, \
-            f"cached .so is stale, missing {missing} — " \
-            f"remove it and rebuild"
+    def test_loaded_lib_is_named_after_its_sources(self):
+        """The library the process serves with is the one whose name
+        carries the hash of the tracked sources + flags."""
+        native.load()
+        assert os.path.basename(native.loaded_path()) == \
+            native.lib_name()
+
+    def test_name_follows_the_sources_not_file_times(
+            self, tmp_path, monkeypatch):
+        """A binary built from other sources is never loaded, whatever
+        its mtime says (a tree copy resets file times): edit a source
+        and the expected name moves off the planted file; a build of
+        the edited sources lands under the new name."""
+        srcs = []
+        for src in native._SRCS:
+            dst = tmp_path / os.path.basename(src)
+            dst.write_bytes(open(src, "rb").read())
+            srcs.append(str(dst))
+        monkeypatch.setattr(native, "_DIR", str(tmp_path))
+        monkeypatch.setattr(native, "_SRCS", tuple(srcs))
+        assert native.lib_name() == os.path.basename(
+            native.loaded_path())            # same bytes, same name
+        stale = tmp_path / native.lib_name()
+        stale.write_bytes(b"not a library")
+        with open(srcs[0], "a") as f:
+            f.write("\n/* edited */\n")
+        future = time.time() + 3600
+        os.utime(stale, (future, future))    # newer than every source
+        assert native.lib_name() != stale.name
+        built = native._build(native._compiler())
+        assert os.path.basename(built) == native.lib_name()
+        assert built != str(stale)
+        ctypes.CDLL(built)                   # a real library
 
 
 # -- probe parity ------------------------------------------------------------
@@ -226,14 +247,14 @@ class TestProbeParity:
 
 @needs_probe
 class TestNativeFallback:
-    def test_missing_symbol_degrades_per_call(self, tmp_path,
-                                              monkeypatch):
-        """native.sst_probe returning None (no lib / stale .so without
-        the symbol) must fall back to python per call, count
-        `lookup.native_fallbacks`, and answer identically.  The raw
-        pointer prepared path is disabled up front (a stale .so never
-        resolves a prep context), so every probe routes through
-        sst_probe — the per-call degradation gate under test."""
+    def test_missing_library_degrades_per_call(self, tmp_path,
+                                               monkeypatch):
+        """native.sst_probe returning None (no native library) must
+        fall back to python per call, count `lookup.native_fallbacks`,
+        and answer identically.  The raw pointer prepared path is
+        disabled up front (without a library no prep context
+        resolves), so every probe routes through sst_probe — the
+        per-call degradation gate under test."""
         from paimon_tpu.lookup import LocalTableQuery
         monkeypatch.setattr(native, "sst_probe_prepare",
                             lambda *a, **k: None)
