@@ -1,0 +1,5 @@
+"""Process start to the start of the window."""
+
+
+def read(run, params):
+    return run.setup_s
