@@ -27,7 +27,9 @@ from paimon_tpu.core.read import assemble_runs
 from paimon_tpu.fs import FileIO
 from paimon_tpu.manifest import DataFileMeta, FileSource
 from paimon_tpu.options import CoreOptions, MergeEngine
-from paimon_tpu.ops.merge import merge_runs
+from paimon_tpu.metrics import COMPACTION_DURATION_MS, global_registry
+from paimon_tpu.obs.trace import carry, span
+from paimon_tpu.ops.merge import merge_runs, prep_span
 from paimon_tpu.utils.deadline import check_deadline, wait_future
 from paimon_tpu.ops.normkey import NormalizedKeyEncoder
 from paimon_tpu.schema.table_schema import TableSchema
@@ -78,17 +80,21 @@ def _prefetch(it, depth: int = 2):
                 q.put(("__prefetch_error__", e))
 
     from paimon_tpu.parallel.executors import spawn_thread
-    spawn_thread(pump, name="paimon-prefetch-pump")
+    spawn_thread(carry(pump), name="paimon-prefetch-pump")
     try:
         while True:
             # bounded poll so a request whose deadline is spent stops
             # waiting on a stalled pump (the cancel flag in `finally`
-            # then releases the pump thread and its pinned chunks)
-            try:
-                item = q.get(timeout=0.2)
-            except _queue.Empty:
-                check_deadline("compaction prefetch")
-                continue
+            # then releases the pump thread and its pinned chunks); the
+            # `wait` span says who waited for the pump, and closes
+            # before the yield
+            with span("wait", cat="wait", what="compaction prefetch"):
+                while True:
+                    try:
+                        item = q.get(timeout=0.2)
+                        break
+                    except _queue.Empty:
+                        check_deadline("compaction prefetch")
             if item is _SENTINEL:
                 return
             if isinstance(item, tuple) and len(item) == 2 and \
@@ -183,9 +189,6 @@ class MergeTreeCompactManager:
 
     def do_compact(self, unit: CompactUnit) -> CompactResult:
         """reference MergeTreeCompactTask.doCompact:83."""
-        from paimon_tpu.metrics import global_registry
-        import time as _time
-
         group = global_registry().group("compaction")
         # managers are constructed per compaction task, so the busy
         # window lives at module scope — a per-instance timer would
@@ -193,13 +196,16 @@ class MergeTreeCompactManager:
         timer = _BUSY_TIMER
         group.gauge("busy_ratio_1m", timer.busy_ratio)
         timer.start()
-        t0 = _time.perf_counter()
         try:
-            result = self._do_compact(unit)
+            # the task's root span: every stage below — prefetch
+            # thread, merge pool, write pool — walks back to it
+            with span("compact.task", cat="compaction",
+                      group="compaction", metric=COMPACTION_DURATION_MS,
+                      bucket=self.bucket, files=len(unit.files),
+                      rows=sum(f.row_count for f in unit.files)):
+                result = self._do_compact(unit)
         finally:
             timer.stop()
-            group.histogram("duration_ms").update(
-                (_time.perf_counter() - t0) * 1000)
             group.counter("tasks").inc()
         group.counter("input_files").inc(len(unit.files))
         group.counter("output_files").inc(len(result.after))
@@ -283,6 +289,11 @@ class MergeTreeCompactManager:
         from paimon_tpu.format.blob import blob_column_names
         has_blobs = bool(blob_column_names(self.schema))
 
+        def encode(t: pa.Table):
+            with prep_span(t.num_rows):
+                return (t, *self.key_encoder.encode_table_ex(
+                    t, self.key_cols))
+
         def run_iter(run_files):
             # yields (table, lanes, truncated): the lane encode runs
             # HERE, inside the prefetch thread, overlapping the merge
@@ -300,8 +311,7 @@ class MergeTreeCompactManager:
                                      self.schema_manager,
                                      self._schema_cache,
                                      keep_sys_cols=True)
-                    yield (t, *self.key_encoder.encode_table_ex(
-                        t, self.key_cols))
+                    yield encode(t)
                     continue
                 ext = f.file_name.rsplit(".", 1)[-1]
                 fmt = get_format(ext)
@@ -335,8 +345,7 @@ class MergeTreeCompactManager:
                                 self.schema_manager,
                                 self._schema_cache,
                                 keep_sys_cols=True)
-                            yield (t, *self.key_encoder.encode_table_ex(
-                                t, self.key_cols))
+                            yield encode(t)
                         continue
                 from paimon_tpu.fs.caching import scoped_batches
                 # scoped_batches holds the footer-cache gate only
@@ -352,8 +361,7 @@ class MergeTreeCompactManager:
                                      self.schema_manager,
                                      self._schema_cache,
                                      keep_sys_cols=True)
-                    yield (t, *self.key_encoder.encode_table_ex(
-                        t, self.key_cols))
+                    yield encode(t)
 
         # three-stage pipeline: prefetch threads decode+lane-encode,
         # ONE merge worker sorts/dedups windows (so device upload/sort/
@@ -371,6 +379,13 @@ class MergeTreeCompactManager:
                 self.partition, self.bucket, merged, level=output_level,
                 file_source=FileSource.COMPACT)
 
+        def _merge_one(tables, encoded) -> pa.Table:
+            with span("compact.window", cat="compaction",
+                      rows=sum(t.num_rows for t in tables)):
+                return self._merge_tables(tables, drop_delete,
+                                          encoded=encoded,
+                                          overlapped=True)
+
         # two merge workers: the OVC/native merges and the numpy
         # epilogues release the GIL, so adjacent windows genuinely
         # overlap; futures are still consumed in submission order so
@@ -381,9 +396,8 @@ class MergeTreeCompactManager:
             def merge_window(items):
                 tables = [item[0] for item in items]
                 encoded = [item[1:] for item in items]
-                return merge_pool.submit(
-                    self._merge_tables, tables, drop_delete,
-                    encoded=encoded, overlapped=True)
+                return merge_pool.submit(carry(_merge_one), tables,
+                                         encoded)
 
             def flush():
                 nonlocal acc, acc_bytes
@@ -403,7 +417,7 @@ class MergeTreeCompactManager:
                 if len(pending) >= 3:
                     wait_future(pending[0], "compaction write backpressure")
                 merged = pa.concat_tables(acc, promote_options="none")
-                futures.append(pool.submit(_write_one, merged))
+                futures.append(pool.submit(carry(_write_one), merged))
                 acc, acc_bytes = [], 0
 
             merge_futs: List = []
@@ -541,7 +555,7 @@ class MergeTreeCompactManager:
             from concurrent.futures import ThreadPoolExecutor
             with ThreadPoolExecutor(
                     max_workers=min(8, len(uncached))) as pool:
-                list(pool.map(self._read_file, uncached))
+                list(pool.map(carry(self._read_file), uncached))
         runs = []
         for run_files in runs_meta:
             tables = [self._read_file(f) for f in run_files]

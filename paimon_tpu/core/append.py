@@ -517,7 +517,8 @@ class AppendSplitRead:
             if streaming:
                 out = out.append_column(RK, pa.array([], pa.int8()))
             return out
-        return pa.concat_tables(tables, promote_options="default")
+        from paimon_tpu.core.read import assemble_tables
+        return assemble_tables(tables)
 
     def _empty(self) -> pa.Table:
         return pa.table({f.name: pa.array([], data_type_to_arrow(f.type))

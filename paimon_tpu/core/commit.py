@@ -228,16 +228,46 @@ class FileStoreCommit:
                     watermark: Optional[int] = None,
                     force_full_manifest_merge: bool = False,
                     skip_missing_manifests: bool = False) -> int:
-        from paimon_tpu.metrics import global_registry
-        import time as _time
-
+        """One commit, from its entries to the published snapshot (or
+        the error that gave up), under the `commit` span: its duration
+        is `commit.duration_ms`, one sample a commit — a commit that
+        gives up leaves a sample too."""
+        from paimon_tpu.metrics import COMMIT_DURATION_MS
         from paimon_tpu.obs.trace import span as _span, sync_from_options
+
+        sync_from_options(self.options)
+        with _span("commit", cat="commit", group="commit",
+                   metric=COMMIT_DURATION_MS, kind=kind,
+                   table=self.table_path):
+            return self._commit_attempts(
+                entries, changelog_entries, commit_identifier, kind,
+                check_deleted_files=check_deleted_files,
+                index_entries=index_entries, properties=properties,
+                entries_fn=entries_fn,
+                expected_latest_id=expected_latest_id,
+                statistics=statistics, watermark=watermark,
+                force_full_manifest_merge=force_full_manifest_merge,
+                skip_missing_manifests=skip_missing_manifests)
+
+    def _commit_attempts(self, entries: List[ManifestEntry],
+                         changelog_entries: List[ManifestEntry],
+                         commit_identifier: int, kind: str, *,
+                         check_deleted_files: bool,
+                         index_entries: Optional[list],
+                         properties: Optional[Dict[str, str]],
+                         entries_fn,
+                         expected_latest_id: Optional[int],
+                         statistics: Optional[str],
+                         watermark: Optional[int],
+                         force_full_manifest_merge: bool,
+                         skip_missing_manifests: bool) -> int:
+        from paimon_tpu.metrics import global_registry
+
+        from paimon_tpu.obs.trace import span as _span
         from paimon_tpu.utils.backoff import Backoff
         from paimon_tpu.utils.deadline import DeadlineExceededError
 
-        sync_from_options(self.options)
         _metrics = global_registry().group("commit")
-        _t0 = _time.perf_counter()
         _attempts = 0
         _max_retries = self.options.get(CoreOptions.COMMIT_MAX_RETRIES)
         _min_wait = self.options.get(CoreOptions.COMMIT_MIN_RETRY_WAIT)
@@ -358,7 +388,9 @@ class FileStoreCommit:
                     from paimon_tpu.parallel.executors import new_thread_pool
                     pool = new_thread_pool(1, "paimon-commit")
                     try:
-                        fut = pool.submit(_write_manifest, entries, "delta")
+                        from paimon_tpu.obs.trace import carry
+                        fut = pool.submit(carry(_write_manifest), entries,
+                                          "delta")
                         changelog_manifest = _write_manifest(
                             changelog_entries, "changelog")
                         from paimon_tpu.utils.deadline import wait_future
@@ -476,8 +508,6 @@ class FileStoreCommit:
                     _metrics.counter("commits").inc()
                     if _attempts > 1:
                         _metrics.counter("retries").inc(_attempts - 1)
-                    _metrics.histogram("duration_ms").update(
-                        (_time.perf_counter() - _t0) * 1000)
                     return new_id
                 # lost the race: clean up everything written for this attempt
                 # and retry against the new latest (the delta manifest is

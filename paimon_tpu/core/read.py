@@ -135,6 +135,14 @@ def evolve_table(table: pa.Table, file_schema_id: int, schema: TableSchema,
     return pa.table(cols)
 
 
+def assemble_tables(tables: Sequence[pa.Table]) -> pa.Table:
+    """The scan's last stage: the split tables as one (zero-copy, the
+    result keeps the splits' chunks)."""
+    from paimon_tpu.obs.trace import span
+    with span("scan.assemble", cat="scan", tables=len(tables)):
+        return pa.concat_tables(tables, promote_options="default")
+
+
 def assemble_runs(files: Sequence[DataFileMeta]) -> List[List[DataFileMeta]]:
     """Order a bucket's files into sorted runs, oldest first.
 
@@ -228,7 +236,7 @@ class MergeFileSplitRead:
             if streaming is None:
                 streaming = any(s.for_streaming for s in splits)
             return self._empty_table(streaming)
-        return pa.concat_tables(tables, promote_options="default")
+        return assemble_tables(tables)
 
     def _empty_table(self, streaming: bool) -> pa.Table:
         """Typed empty result with a schema identical to non-empty reads

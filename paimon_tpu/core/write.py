@@ -26,7 +26,9 @@ from paimon_tpu.core.kv_file import KEY_PREFIX, KeyValueFileWriter
 from paimon_tpu.fs import FileIO
 from paimon_tpu.manifest import DataFileMeta, SimpleStats
 from paimon_tpu.options import CoreOptions, MergeEngine
-from paimon_tpu.ops.merge import KIND_COL, SEQ_COL, merge_runs, sort_table
+from paimon_tpu.ops.merge import (
+    KIND_COL, SEQ_COL, gather, merge_runs, sort_table,
+)
 from paimon_tpu.schema.table_schema import TableSchema
 from paimon_tpu.types import RowKind
 from paimon_tpu.utils.deadline import wait_future
@@ -252,7 +254,7 @@ class _BucketWriter:
             else:
                 order = sort_table(kv, key_cols,
                                    key_encoder=self.parent.key_encoder)
-                sorted_kv = kv.take(pa.array(order))
+                sorted_kv = gather(kv, order)
 
         changelog: List[DataFileMeta] = []
         if self.parent.changelog_input:
@@ -797,21 +799,26 @@ class KeyValueFileStoreWrite:
         # sequence reservation) stays on this thread, in batch order.
         def prep(table=table, kinds=row_kinds,
                  pre=precomputed_buckets):
-            buckets = pre if pre is not None \
-                else self.bucket_assigner.assign(table)
-            out = []
-            for (part, bucket), idx in lpt_order(
-                    group_by_partition_bucket(
-                        table, buckets, self.partition_keys)):
-                out.append(((part, bucket), table.take(pa.array(idx)),
-                            kinds[idx]))
-            return out
+            from paimon_tpu.metrics import WRITE_ROUTE_MS
+            from paimon_tpu.obs.trace import span
+            with span("write.route", cat="write", group="write",
+                      metric=WRITE_ROUTE_MS, rows=table.num_rows):
+                buckets = pre if pre is not None \
+                    else self.bucket_assigner.assign(table)
+                out = []
+                for (part, bucket), idx in lpt_order(
+                        group_by_partition_bucket(
+                            table, buckets, self.partition_keys)):
+                    out.append(((part, bucket),
+                                table.take(pa.array(idx)), kinds[idx]))
+                return out
 
         pool = self._prep_executor()
         if pool is None:
             self._route(prep())
             return
-        self._prep.append(pool.submit(prep))
+        from paimon_tpu.obs.trace import carry
+        self._prep.append(pool.submit(carry(prep)))
         # bounded lookahead: at most 4 batches prepped ahead (each holds
         # a batch-sized copy), routed strictly in submission order
         while len(self._prep) > 4:

@@ -111,12 +111,24 @@ class _ParquetReader(FormatReader):
 
     def read_batches(self, file_io, path, projection=None,
                      batch_rows: int = 1 << 20):
-        # compressed bytes stay resident; decode is incremental per batch
+        # compressed bytes stay resident; decode is incremental per
+        # batch.  The `decode` span (the one `read` has) is around each
+        # advance of the iterator, never around the yield: a suspended
+        # generator must not hold a span open on the consumer's thread.
+        from paimon_tpu.metrics import IO_DECODE_MS
+        from paimon_tpu.obs.trace import span
         pf = self._open(file_io, path)
-        with _decode_errors(path):
-            for rb in pf.iter_batches(batch_size=batch_rows,
-                                      columns=projection):
-                yield pa.Table.from_batches([rb])
+        batches = pf.iter_batches(batch_size=batch_rows,
+                                  columns=projection)
+        while True:
+            with _decode_errors(path), \
+                    span("decode", cat="io", group="io",
+                         metric=IO_DECODE_MS, path=path):
+                rb = next(batches, None)
+                if rb is None:
+                    return
+                table = pa.Table.from_batches([rb])
+            yield table
 
 
 def split_compression(spec: str):

@@ -31,6 +31,9 @@ __all__ = ["Counter", "Gauge", "Histogram", "MetricGroup",
            "IO_READ_MS", "IO_DECODE_MS", "IO_ENCODE_MS", "IO_UPLOAD_MS",
            "COMPACTION_WINDOW_MS", "COMPACTION_FALLBACK_MS",
            "COMMIT_CAS_MS", "COMMIT_MANIFEST_ENCODE_MS",
+           "COMMIT_DURATION_MS", "COMPACTION_DURATION_MS",
+           "WRITE_ROUTE_MS",
+           "MERGE_PREP_MS", "MERGE_DEVICE_MS", "MERGE_AGG_MS",
            "STREAM_EVENTS_INGESTED", "STREAM_CHECKPOINTS",
            "STREAM_CHECKPOINT_MS", "STREAM_LOOP_RESTARTS",
            "STREAM_FRESHNESS_MS", "STREAM_CHANGELOG_ROWS",
@@ -130,6 +133,16 @@ COMPACTION_WINDOW_MS = "window_ms"          # compaction: device window
 COMPACTION_FALLBACK_MS = "fallback_ms"      # compaction: 1-chip rescue
 COMMIT_CAS_MS = "cas_ms"                    # commit: one CAS publish
 COMMIT_MANIFEST_ENCODE_MS = "manifest_encode_ms"
+COMMIT_DURATION_MS = "duration_ms"          # commit: one whole commit,
+                                            # published or given up
+COMPACTION_DURATION_MS = "duration_ms"      # compaction: one whole task
+WRITE_ROUTE_MS = "route_ms"                 # write: hash/group-by/take
+# merge metric group: the stages of one sorted-run merge, whoever
+# called it (scan split, flush sort, compaction window) — producers
+# in ops/merge.py, ops/agg.py and compact/manager.py
+MERGE_PREP_MS = "prep_ms"                   # concat, lane encode, pad
+MERGE_DEVICE_MS = "device_ms"               # first upload -> result on host
+MERGE_AGG_MS = "agg_ms"                     # aggregation epilogue, whole
 
 # streaming-daemon counter/gauge/histogram names (stream metric group;
 # producer is service/stream_daemon.py, consumers tests/soak_harness.py

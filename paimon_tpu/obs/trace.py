@@ -3,26 +3,43 @@
 Design constraints, in priority order:
 
 1. **No-op when disabled.**  ``span(...)`` is called on every pipeline
-   stage of every hot path; with tracing off it must cost one module
-   flag check.  The disabled call returns a shared singleton context
-   manager (no allocation beyond the caller's kwargs dict, which is
-   built per *stage* — per split / per flush / per window — never per
-   row).  `benchmarks/micro.py` ``obs`` measures the disabled path at
-   <2% of scan wall time vs an uninstrumented baseline, asserted by a
-   tier-1 test (tests/test_obs.py).
+   stage of every hot path; with no listener it must cost two flag
+   checks (the module's own, and the profiler's static
+   ``TraceAnnotation.is_enabled()``, ~80 ns).  The disabled call
+   returns a shared singleton context manager (no allocation beyond
+   the caller's kwargs dict, which is built per *stage* — per split /
+   per flush / per window — never per row).  `benchmarks/micro.py`
+   ``obs`` measures the disabled path at <2% of scan wall time vs an
+   uninstrumented baseline, asserted by a tier-1 test
+   (tests/test_obs.py).
 2. **Thread-safe bounded collection.**  Spans land in a ring
    (`collections.deque(maxlen=trace.buffer.spans)`) under one lock;
    an unbounded trace can never OOM a long-running service.
-3. **Nestable.**  A `contextvars.ContextVar` tracks the current span,
-   so children record their parent id without any caller plumbing.
-   Worker threads start fresh contexts, which is exactly right: each
-   pool thread is its own track in the Chrome trace.
+3. **Nestable, across pools too.**  A `contextvars.ContextVar` tracks
+   the current span, so children record their parent id without any
+   caller plumbing.  Work handed to a pool or a thread goes through
+   `carry(fn)`, which re-installs the submitter's span (and trace id)
+   in the worker: a split, a window or a flush task records the span
+   that caused it, and every span of one operation walks back to its
+   root (`scan.to_arrow`, `compact.task`, `write.batch` /
+   `write.prepare` / `write.commit`).  Each pool thread is still its
+   own track in the Chrome trace.
 4. **One timing, two sinks.**  A span that names a ``group``/``metric``
    also lands its duration in that metric group's latency histogram
    (`metrics.py`), so the registry snapshot and the trace timeline can
    never disagree about what was measured.
+5. **Two listeners, one call site.**  Besides the ring
+   (`enable_tracing()`), an open JAX profiler session is a listener:
+   while one is open — whether or not tracing was enabled — every span
+   also enters ``jax.profiler.TraceAnnotation("paimon." + name)`` with
+   its scalar attrs, so the program's stages land on their thread's
+   line of the trace's ``/host:CPU`` plane, on the same clock as the
+   device planes (`paimon.scan.split`, `paimon.merge.device`,
+   `paimon.compact.window`, `paimon.write.route`, `paimon.wait` ...;
+   `chipbench/span_reduce.py` reads them back).  No option switches
+   this on: start a profiler session around a table operation.
 
-Enabling is process-global (the planes share thread pools, so
+Enabling the ring is process-global (the planes share thread pools, so
 per-table tracing would tear one timeline into halves): call
 `enable_tracing()` / `disable_tracing()` directly (CLI `--trace`,
 tests), or set the `trace.enabled` / `metrics.enabled` table options —
@@ -39,6 +56,7 @@ import itertools
 import json
 import os
 import platform
+import sys
 import threading
 import time
 from collections import deque
@@ -50,6 +68,7 @@ __all__ = ["Span", "TraceCollector", "span", "enable_tracing",
            "sync_from_options", "export_path", "export_dir",
            "set_export_dir", "process_tag", "set_replica_id",
            "new_trace_id", "current_trace_id", "current_context_token",
+           "carry", "profiler_listening", "ANNOTATION_PREFIX",
            "inject_headers", "server_span", "spool_flush",
            "reset_spool"]
 
@@ -64,6 +83,10 @@ STAGE_SERVE_REQUEST = "serve.request"
 STAGE_CLIENT_REQUEST = "client.request"
 STAGE_PLAN_LINK = "plan.link"
 STAGE_LEASE_FOLD = "lease.fold"
+
+# Every span of this module is named ``ANNOTATION_PREFIX + name`` on the
+# profiler's host plane (chipbench/span_reduce.py matches on it).
+ANNOTATION_PREFIX = "paimon."
 
 # Header names of the W3C-style context carried on every serving hop.
 HDR_TRACE_ID = "X-Trace-Id"
@@ -164,6 +187,32 @@ _spooled_through = 0
 _spool_header_done = False
 
 
+_TraceAnnotation = None       # jax.profiler's, bound on first use
+
+
+def profiler_listening() -> bool:
+    """True exactly while a JAX profiler session is open.  Costs one
+    static call (~80 ns); never imports jax itself — a process that
+    has not imported it has no session to listen to."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        if "jax" not in sys.modules:
+            return False
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation.is_enabled()
+
+
+def _annotation(name: str, attrs: Dict):
+    """The span as the profiler sees it: `paimon.<name>` with the
+    scalar attrs (rows, bytes, bucket, route...); anything else — a
+    partition tuple, a path list — stays in the ring only."""
+    return _TraceAnnotation(
+        ANNOTATION_PREFIX + name,
+        **{k: v for k, v in attrs.items()
+           if isinstance(v, (bool, int, float, str))})
+
+
 class _NoopSpan:
     """Shared do-nothing context manager for the disabled fast path."""
 
@@ -206,11 +255,36 @@ class _MetricSpan:
         return False
 
 
+class _ProfiledSpan(_MetricSpan):
+    """A profiler session is open and the ring is off: the span is an
+    annotation on the profiler's clock, plus its histogram."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str, group: Optional[str],
+                 metric: Optional[str], attrs: Dict):
+        self.group = group if _metrics_on else None
+        self.metric = metric
+        self._ann = _annotation(name, attrs)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        if self.group is not None:
+            _MetricSpan.__exit__(self)
+        self._ann.__exit__(*exc)
+        return False
+
+
 class _LiveSpan:
-    """Tracing enabled: full span with nesting + ring + histogram."""
+    """Tracing enabled: full span with nesting + ring + histogram (and
+    the profiler's annotation while a session is open)."""
 
     __slots__ = ("name", "cat", "group", "metric", "attrs", "t0",
-                 "span_id", "_token")
+                 "span_id", "_token", "_ann")
 
     def __init__(self, name: str, cat: str, group: Optional[str],
                  metric: Optional[str], attrs: Dict):
@@ -228,11 +302,17 @@ class _LiveSpan:
     def __enter__(self):
         self.span_id = next(_ids)
         self._token = _current.set(self.span_id)
+        self._ann = None
+        if profiler_listening():
+            self._ann = _annotation(self.name, self.attrs)
+            self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         _current.reset(self._token)
         parent = _current.get()
         if exc_type is not None:
@@ -259,12 +339,40 @@ def span(name: str, *, cat: str = "", group: Optional[str] = None,
     name-drift test sees the producer).  Extra kwargs become span
     attributes (table/partition/bucket/snapshot/attempt...) — pass raw
     values, stringification happens at export time.
+
+    Listeners: the ring (`enable_tracing()`) and an open JAX profiler
+    session, which sees the span as the annotation `paimon.<name>`
+    with the scalar attrs.  With neither, a grouped span only times
+    its histogram and an ungrouped one is the shared no-op.
     """
+    if _enabled:
+        return _LiveSpan(name, cat, group, metric or name, attrs)
+    if profiler_listening():
+        return _ProfiledSpan(name, group, metric or name, attrs)
+    if group is not None and _metrics_on:
+        return _MetricSpan(group, metric or name)
+    return _NOOP
+
+
+def carry(fn):
+    """`fn`, bound to the submitting thread's current span and trace
+    id: called on a pool worker (or a spawned thread) it re-installs
+    both, so the spans it opens there record the span that caused the
+    work as their parent.  Returns `fn` itself when the ring is off —
+    the profiler's trace nests by time on each thread's own line and
+    has no parent ids to carry."""
     if not _enabled:
-        if group is not None and _metrics_on:
-            return _MetricSpan(group, metric or name)
-        return _NOOP
-    return _LiveSpan(name, cat, group, metric or name, attrs)
+        return fn
+    sid, tid = _current.get(), _trace_id.get()
+
+    def carried(*args, **kwargs):
+        t_span, t_trace = _current.set(sid), _trace_id.set(tid)
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            _trace_id.reset(t_trace)
+            _current.reset(t_span)
+    return carried
 
 
 # -- cross-process trace context --------------------------------------------

@@ -25,9 +25,11 @@ import numpy as np
 import pyarrow as pa
 import pyarrow.compute as pc
 
+from paimon_tpu.metrics import MERGE_AGG_MS
+from paimon_tpu.obs.trace import span
 from paimon_tpu.options import CoreOptions, MergeEngine
 from paimon_tpu.ops.merge import (
-    KIND_COL, SEQ_COL, device_sorted_winners,
+    KIND_COL, SEQ_COL, device_sorted_winners, gather, prep_span,
 )
 from paimon_tpu.ops.normkey import NormalizedKeyEncoder
 from paimon_tpu.schema.table_schema import TableSchema
@@ -165,12 +167,13 @@ def _host_segment_reduce(ufunc, vals: np.ndarray, seg_ids: np.ndarray,
     float32, so 1e300 arrives as inf, 1e-300 as 0 and pi without its
     low bits — a reduce there cannot be bit-identical.  Relies on this
     module's sorted-segment contract (`seg_ids` ascending and dense)."""
-    starts = np.flatnonzero(np.concatenate(
-        [[True], seg_ids[1:] != seg_ids[:-1]]))
-    if len(starts) != num_seg:
-        raise ValueError(f"segment ids not ascending and dense: "
-                         f"{len(starts)} runs for {num_seg} segments")
-    return ufunc.reduceat(vals, starts)
+    with span("agg.host", cat="merge", rows=len(vals)):
+        starts = np.flatnonzero(np.concatenate(
+            [[True], seg_ids[1:] != seg_ids[:-1]]))
+        if len(starts) != num_seg:
+            raise ValueError(f"segment ids not ascending and dense: "
+                             f"{len(starts)} runs for {num_seg} segments")
+        return ufunc.reduceat(vals, starts)
 
 
 def _padded_seg(fn_jit, ufunc):
@@ -181,24 +184,29 @@ def _padded_seg(fn_jit, ufunc):
     point at a dedicated dummy segment past num_seg, which the final
     slice drops — their values never touch a real segment.
 
-    float64 values reduce on the host (`_host_segment_reduce`)."""
+    float64 values reduce on the host (`_host_segment_reduce`).
+    Returns a numpy array: every caller fetched the result at once, so
+    the fetch sits here, inside the `agg.device` span (pad, upload,
+    program, slice, download)."""
     def call(vals, seg_ids, num_seg):
         vals = np.asarray(vals)
         seg_ids = np.asarray(seg_ids)
         n = len(vals)
         if vals.dtype == np.float64 and n:
             return _host_segment_reduce(ufunc, vals, seg_ids, num_seg)
-        # strictly greater than num_seg so the dummy segment exists
-        padded_seg = 1 << max(4, int(num_seg).bit_length())
-        m = 1 << max(10, int(n - 1).bit_length()) if n > 1 else 1024
-        if m > n:
-            vals = np.concatenate(
-                [vals, np.zeros(m - n, dtype=vals.dtype)])
-            seg_ids = np.concatenate(
-                [seg_ids, np.full(m - n, padded_seg - 1,
-                                  dtype=seg_ids.dtype)])
-        out = fn_jit(jnp.asarray(vals), jnp.asarray(seg_ids), padded_seg)
-        return jnp.asarray(out)[:num_seg]
+        with span("agg.device", cat="merge", rows=n, segments=num_seg):
+            # strictly greater than num_seg so the dummy segment exists
+            padded_seg = 1 << max(4, int(num_seg).bit_length())
+            m = 1 << max(10, int(n - 1).bit_length()) if n > 1 else 1024
+            if m > n:
+                vals = np.concatenate(
+                    [vals, np.zeros(m - n, dtype=vals.dtype)])
+                seg_ids = np.concatenate(
+                    [seg_ids, np.full(m - n, padded_seg - 1,
+                                      dtype=seg_ids.dtype)])
+            out = fn_jit(jnp.asarray(vals), jnp.asarray(seg_ids),
+                         padded_seg)
+            return np.asarray(jnp.asarray(out)[:num_seg])
     return call
 
 
@@ -252,30 +260,33 @@ def merge_runs_agg(runs: Sequence[pa.Table], key_cols: Sequence[str],
     """Merge runs under aggregation / partial-update semantics.
     Returns a KV-shaped table (keys + sys cols + aggregated values),
     sorted by key."""
-    table = pa.concat_tables(runs, promote_options="none")
-    n = table.num_rows
-    if n == 0:
-        return table
-    if key_encoder is None:
-        key_encoder = NormalizedKeyEncoder(
-            [table.schema.field(k).type for k in key_cols],
-            nullable=[table.schema.field(k).nullable for k in key_cols])
-    lanes, truncated, packed = key_encoder.encode_table_ex(table,
-                                                           key_cols)
-    seq = np.asarray(table.column(SEQ_COL).combine_chunks().cast(pa.int64()))
-    full_key = None
-    if truncated.any():
-        kcols = [table.column(k) for k in key_cols]
+    with prep_span(sum(r.num_rows for r in runs)):
+        table = pa.concat_tables(runs, promote_options="none")
+        n = table.num_rows
+        if n == 0:
+            return table
+        if key_encoder is None:
+            key_encoder = NormalizedKeyEncoder(
+                [table.schema.field(k).type for k in key_cols],
+                nullable=[table.schema.field(k).nullable
+                          for k in key_cols])
+        lanes, truncated, packed = key_encoder.encode_table_ex(table,
+                                                               key_cols)
+        seq = np.asarray(
+            table.column(SEQ_COL).combine_chunks().cast(pa.int64()))
+        full_key = None
+        if truncated.any():
+            kcols = [table.column(k) for k in key_cols]
 
-        def full_key(i: int):
-            return tuple(c[int(i)].as_py() for c in kcols)
+            def full_key(i: int):
+                return tuple(c[int(i)].as_py() for c in kcols)
 
-    from paimon_tpu.ops.merge import user_seq_order_lanes
-    order_lanes = user_seq_order_lanes(
-        table, seq_fields, options.sequence_field_descending) \
-        if seq_fields else None
-    run_starts = np.concatenate(
-        [[0], np.cumsum([r.num_rows for r in runs])]).astype(np.int64)
+        from paimon_tpu.ops.merge import user_seq_order_lanes
+        order_lanes = user_seq_order_lanes(
+            table, seq_fields, options.sequence_field_descending) \
+            if seq_fields else None
+        run_starts = np.concatenate(
+            [[0], np.cumsum([r.num_rows for r in runs])]).astype(np.int64)
     order, seg_id, win_sorted = _segment_ids_from_sort(
         lanes, seq, truncated, full_key, order_lanes, packed=packed,
         run_starts=run_starts)
@@ -298,10 +309,19 @@ def aggregate_sorted_segments(table: pa.Table, order: np.ndarray,
     `win_sorted`: True at the last row of each segment.  Folds every
     segment per the table's merge engine and returns the KV-shaped
     merged rows in key order."""
+    with span("agg.reduce", cat="merge", group="merge",
+              metric=MERGE_AGG_MS, rows=len(order)):
+        return _aggregate_sorted_segments(table, order, seg_id,
+                                          win_sorted, key_cols, schema,
+                                          options)
+
+
+def _aggregate_sorted_segments(table, order, seg_id, win_sorted, key_cols,
+                               schema, options) -> pa.Table:
     num_seg = int(seg_id[-1]) + 1 if len(seg_id) else 0
     win_pos = np.flatnonzero(win_sorted)           # last row of each segment
 
-    sorted_tbl = table.take(pa.array(order))
+    sorted_tbl = gather(table, order)
     kinds_sorted = np.asarray(sorted_tbl.column(KIND_COL).combine_chunks()
                               .cast(pa.int8()))
     retract = (kinds_sorted == RowKind.DELETE) | \

@@ -32,6 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 
+from paimon_tpu.metrics import MERGE_DEVICE_MS, MERGE_PREP_MS
+from paimon_tpu.obs.trace import span
 from paimon_tpu.ops.normkey import NormalizedKeyEncoder
 from paimon_tpu.types import RowKind
 
@@ -52,13 +54,68 @@ class MergeResult:
 
     def take(self, columns: Optional[List[str]] = None) -> pa.Table:
         t = self.table.select(columns) if columns else self.table
-        return t.take(pa.array(self.indices))
+        return gather(t, self.indices)
 
 
 def _pad_size(n: int) -> int:
     if n <= 1024:
         return 1024
     return 1 << (n - 1).bit_length()
+
+
+def gather(table: pa.Table, indices: np.ndarray) -> pa.Table:
+    """`merge.gather`: the rows a merge chose, taken out of the Arrow
+    table in the merge's order."""
+    with span("merge.gather", cat="merge", rows=len(indices)):
+        return table.take(pa.array(indices))
+
+
+def prep_span(rows: int):
+    """`merge.prep`: host work that readies a merge's operands —
+    concat, key-lane encode, sequence split, pad to the program's
+    size.  Shared by every caller of `device_sorted_winners`."""
+    return span("merge.prep", cat="merge", group="merge",
+                metric=MERGE_PREP_MS, rows=rows)
+
+
+def _padded_operands(lanes, order_lanes: Optional[np.ndarray],
+                     seq: np.ndarray):
+    """The device programs' operands, padded to the program's size
+    (under `merge.prep`): the lane matrix (key lanes, then any user
+    order lanes) as given and padded, the sequence split in two
+    words, and the validity word that sorts padding last."""
+    with prep_span(len(seq)):
+        lanes = np.asarray(lanes)    # materialize if lazily concatenated
+        if order_lanes is not None and order_lanes.shape[1] > 0:
+            lanes = np.concatenate([lanes, order_lanes], axis=1)
+        n, m = len(seq), _pad_size(len(seq))
+        lanes_p = np.zeros((m, lanes.shape[1]), dtype=np.uint32)
+        lanes_p[:n] = lanes
+        useq = seq.astype(np.int64, copy=False).view(np.uint64)
+        seq_hi = np.zeros(m, dtype=np.uint32)
+        seq_lo = np.zeros(m, dtype=np.uint32)
+        seq_hi[:n] = (useq >> np.uint64(32)).astype(np.uint32)
+        seq_lo[:n] = (useq & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+        invalid = np.ones(m, dtype=np.uint32)
+        invalid[:n] = 0
+    return lanes, lanes_p, seq_hi, seq_lo, invalid
+
+
+def _device_span(route: str, rows: int, padded_rows: int,
+                 h2d_bytes: int, d2h_bytes: int):
+    """`merge.device`: one round trip to the chip, from the first
+    operand's upload to the host holding the result.  It times the
+    calls as they are — dispatch is asynchronous, the download blocks —
+    and adds none.  Bytes are the operands' and the results' sizes."""
+    return span("merge.device", cat="merge", group="merge",
+                metric=MERGE_DEVICE_MS, rows=rows,
+                padded_rows=padded_rows, h2d_bytes=h2d_bytes,
+                d2h_bytes=d2h_bytes, route=route)
+
+
+def _host_span(route: str, rows: int):
+    """`merge.host`: a merge (or its epilogue) sorted on the host."""
+    return span("merge.host", cat="merge", rows=rows, route=route)
 
 
 def segmented_merge_body(lane_list, seq_hi, seq_lo, invalid, keep: str,
@@ -440,38 +497,32 @@ def _bitmask_sorted_winners(lanes, seq: np.ndarray, keep: str,
     read intra-segment order or prev)."""
     PATH_COUNTS["device"] += 1
     n = packed.shape[0]
-    lanes = np.asarray(lanes)
-    if order_lanes is not None and order_lanes.shape[1] > 0:
-        lanes = np.concatenate([lanes, order_lanes], axis=1)
-    num_lanes = lanes.shape[1]
+    lanes, lanes_p, seq_hi, seq_lo, invalid = _padded_operands(
+        lanes, order_lanes, seq)
+    m, num_lanes = lanes_p.shape
     num_key_lanes = 2                     # bitmask requires packed u64
-    m = _pad_size(n)
-    lanes_p = np.zeros((m, num_lanes), dtype=np.uint32)
-    lanes_p[:n] = lanes
-    useq = seq.astype(np.int64, copy=False).view(np.uint64)
-    seq_hi = np.zeros(m, dtype=np.uint32)
-    seq_lo = np.zeros(m, dtype=np.uint32)
-    seq_hi[:n] = (useq >> np.uint64(32)).astype(np.uint32)
-    seq_lo[:n] = (useq & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-    invalid = np.ones(m, dtype=np.uint32)
-    invalid[:n] = 0
 
     from paimon_tpu.ops.pallas_kernels import pallas_enabled
-    lane_list = tuple(jnp.asarray(lanes_p[:, i]) for i in range(num_lanes))
-    fn = _merge_fn_bitmask(num_lanes, keep, num_key_lanes, pallas_enabled())
-    words = fn(lane_list, jnp.asarray(seq_hi),
-               jnp.asarray(seq_lo), jnp.asarray(invalid))
-    mask = np.unpackbits(np.asarray(words).view(np.uint8),
-                         bitorder="little")[:n].astype(bool)
-    widx = np.flatnonzero(mask)           # winners, original row order
-    _WINNER_FRAC["num"] += float(len(widx))
-    _WINNER_FRAC["den"] += float(n)
-    wkeys = np.ascontiguousarray(packed[widx])
-    from paimon_tpu import native
-    perm_w = native.radix_argsort(wkeys)
-    if perm_w is None:
-        perm_w = np.argsort(wkeys, kind="stable")
-    indices = widx[perm_w].astype(np.int32)
+    with _device_span("bitmask", n, m, 4 * m * (num_lanes + 3), m // 8):
+        lane_list = tuple(jnp.asarray(lanes_p[:, i])
+                          for i in range(num_lanes))
+        fn = _merge_fn_bitmask(num_lanes, keep, num_key_lanes,
+                               pallas_enabled())
+        words = fn(lane_list, jnp.asarray(seq_hi),
+                   jnp.asarray(seq_lo), jnp.asarray(invalid))
+        words = np.asarray(words)
+    with _host_span("bitmask_epilogue", n):
+        mask = np.unpackbits(words.view(np.uint8),
+                             bitorder="little")[:n].astype(bool)
+        widx = np.flatnonzero(mask)       # winners, original row order
+        _WINNER_FRAC["num"] += float(len(widx))
+        _WINNER_FRAC["den"] += float(n)
+        wkeys = np.ascontiguousarray(packed[widx])
+        from paimon_tpu import native
+        perm_w = native.radix_argsort(wkeys)
+        if perm_w is None:
+            perm_w = np.argsort(wkeys, kind="stable")
+        indices = widx[perm_w].astype(np.int32)
     return (indices, np.ones(len(indices), dtype=bool),
             np.broadcast_to(np.int64(-1), len(indices)))
 
@@ -552,64 +603,65 @@ def device_sorted_winners(lanes: np.ndarray, seq: np.ndarray,
             # sorted-run inputs: offset-value coded merge replaces the
             # sort (single-int compares, segment boundaries for free)
             from paimon_tpu.ops.ovc import ovc_sorted_winners
-            res = ovc_sorted_winners(lanes, seq, keep, run_starts,
-                                     num_key_lanes, packed=packed)
+            with _host_span("ovc", n):
+                res = ovc_sorted_winners(lanes, seq, keep, run_starts,
+                                         num_key_lanes, packed=packed)
             if res is not None:
                 PATH_COUNTS["ovc"] += 1
                 return res
         PATH_COUNTS["host"] += 1
-        full = lanes if no_user_order \
-            else np.concatenate([lanes, order_lanes], axis=1)
-        return _host_sorted_winners(full, seq, keep, num_key_lanes,
-                                    need_prev=not winners_only,
-                                    packed=packed if no_user_order
-                                    else None)
+        with _host_span("host", n):
+            full = lanes if no_user_order \
+                else np.concatenate([lanes, order_lanes], axis=1)
+            return _host_sorted_winners(full, seq, keep, num_key_lanes,
+                                        need_prev=not winners_only,
+                                        packed=packed if no_user_order
+                                        else None)
     PATH_COUNTS["device"] += 1
-    lanes = np.asarray(lanes)        # materialize if lazily concatenated
-    if order_lanes is not None and order_lanes.shape[1] > 0:
-        lanes = np.concatenate([lanes, order_lanes], axis=1)
-    num_lanes = lanes.shape[1]
-    m = _pad_size(n)
-    lanes_p = np.full((m, num_lanes), 0, dtype=np.uint32)
-    lanes_p[:n] = lanes
-    useq = seq.astype(np.int64, copy=False).view(np.uint64)
-    seq_hi = np.zeros(m, dtype=np.uint32)
-    seq_lo = np.zeros(m, dtype=np.uint32)
-    seq_hi[:n] = (useq >> np.uint64(32)).astype(np.uint32)
-    seq_lo[:n] = (useq & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-    invalid = np.ones(m, dtype=np.uint32)
-    invalid[:n] = 0
+    lanes, lanes_p, seq_hi, seq_lo, invalid = _padded_operands(
+        lanes, order_lanes, seq)
+    m, num_lanes = lanes_p.shape
 
     from paimon_tpu.ops.pallas_kernels import pallas_enabled
-    lane_list = tuple(jnp.asarray(lanes_p[:, i]) for i in range(num_lanes))
-    # sorted-run inputs ship their offset-value codes to the device:
-    # the winner-select consumes the single-int offsets first and only
-    # lane-compares pairs the codes cannot decide (full variant only —
-    # the packed/bitmask returns already collapse keys to one u64)
-    ovc_args = ()
     with_ovc = run_starts is not None and not winners_only
-    if with_ovc:
-        from paimon_tpu.ops.ovc import OVC_OFF_SENTINEL, run_ovc_offsets
-        off = np.full(m, OVC_OFF_SENTINEL, dtype=np.uint32)
-        off[:n] = run_ovc_offsets(lanes, run_starts)
-        ovc_args = (jnp.asarray(off),)
-    # a kernel the compiler refuses raises here: there is no second,
-    # quieter program to fall back to
-    fn = _merge_fn_packed(num_lanes, keep, num_key_lanes,
-                          pallas_enabled()) if winners_only \
-        else _merge_fn(num_lanes, keep, num_key_lanes, pallas_enabled(),
-                       with_ovc)
-    out = fn(lane_list, jnp.asarray(seq_hi),
-             jnp.asarray(seq_lo), jnp.asarray(invalid), *ovc_args)
+    with _device_span("packed" if winners_only
+                      else "full_ovc" if with_ovc else "full", n, m,
+                      4 * m * (num_lanes + 3 + with_ovc),
+                      4 * m if winners_only else 9 * m):
+        lane_list = tuple(jnp.asarray(lanes_p[:, i])
+                          for i in range(num_lanes))
+        # sorted-run inputs ship their offset-value codes to the device:
+        # the winner-select consumes the single-int offsets first and
+        # only lane-compares pairs the codes cannot decide (full variant
+        # only — the packed/bitmask returns already collapse keys to one
+        # u64).  The codes are computed here, after the lanes' upload
+        # was started, as before the span was put around it.
+        ovc_args = ()
+        if with_ovc:
+            from paimon_tpu.ops.ovc import (
+                OVC_OFF_SENTINEL, run_ovc_offsets,
+            )
+            off = np.full(m, OVC_OFF_SENTINEL, dtype=np.uint32)
+            off[:n] = run_ovc_offsets(lanes, run_starts)
+            ovc_args = (jnp.asarray(off),)
+        # a kernel the compiler refuses raises here: there is no second,
+        # quieter program to fall back to
+        fn = _merge_fn_packed(num_lanes, keep, num_key_lanes,
+                              pallas_enabled()) if winners_only \
+            else _merge_fn(num_lanes, keep, num_key_lanes,
+                           pallas_enabled(), with_ovc)
+        out = fn(lane_list, jnp.asarray(seq_hi),
+                 jnp.asarray(seq_lo), jnp.asarray(invalid), *ovc_args)
+        if winners_only:
+            # one 4-byte word/row off the device: perm | (winner << 31)
+            packed = np.asarray(out)
+        else:
+            perm, winner, prev = (np.asarray(a) for a in out)
     if winners_only:
-        # one 4-byte word/row off the device: perm | (winner << 31)
-        packed = np.asarray(out)
         perm = (packed & np.uint32(0x7FFFFFFF)).astype(np.int32)
         winner = (packed >> np.uint32(31)).astype(bool)
         prev = np.broadcast_to(np.int64(-1), m)
-        return perm, winner, prev
-    perm, winner, prev = out
-    return (np.asarray(perm), np.asarray(winner), np.asarray(prev))
+    return perm, winner, prev
 
 
 def user_seq_order_lanes(table: pa.Table,
@@ -657,8 +709,10 @@ def sort_table(table: pa.Table, key_names: Sequence[str],
         key_encoder = NormalizedKeyEncoder(
             [table.schema.field(k).type for k in key_names],
             nullable=[table.schema.field(k).nullable for k in key_names])
-    lanes, truncated = key_encoder.encode_table(table, key_names)
-    seq = np.asarray(table.column(SEQ_COL).combine_chunks().cast(pa.int64()))
+    with prep_span(n):
+        lanes, truncated = key_encoder.encode_table(table, key_names)
+        seq = np.asarray(
+            table.column(SEQ_COL).combine_chunks().cast(pa.int64()))
     perm, _, _ = device_sorted_winners(lanes, seq, "last")
     order = perm[perm < n].astype(np.int64)
     if truncated.any():
@@ -716,60 +770,66 @@ def merge_runs(runs: Sequence[pa.Table], key_names: Sequence[str],
     """
     if not runs:
         raise ValueError("No runs to merge")
-    table = pa.concat_tables(runs, promote_options="none")
-    n = table.num_rows
-    if n == 0:
-        return MergeResult(table, np.zeros(0, dtype=np.int64))
+    with prep_span(sum(r.num_rows for r in runs)):
+        table = pa.concat_tables(runs, promote_options="none")
+        n = table.num_rows
+        if n == 0:
+            return MergeResult(table, np.zeros(0, dtype=np.int64))
 
-    if key_encoder is None:
-        key_encoder = NormalizedKeyEncoder(
-            [table.schema.field(k).type for k in key_names],
-            nullable=[table.schema.field(k).nullable for k in key_names])
-    packed = None
-    if encoded is not None:
-        # caller already lane-encoded each run (streamed windows encode
-        # once for the window cut — don't pay the encode twice); items
-        # are (lanes, truncated[, packed-u64])
-        truncated = (np.concatenate([e[1] for e in encoded])
-                     if len(encoded) > 1 else np.asarray(encoded[0][1]))
-        packs = [e[2] if len(e) > 2 else None for e in encoded]
-        if all(p is not None for p in packs):
-            packed = (np.concatenate(packs) if len(packs) > 1
-                      else np.asarray(packs[0]))
-        if packed is not None:
-            # the packed-key host fast path never reads the lane matrix:
-            # concatenating it up front would copy 8N bytes per window
-            # for nothing, so defer until a path actually wants it
-            lane_parts = [e[0] for e in encoded]
-            lanes = _LazyLanes(lane_parts)
+        if key_encoder is None:
+            key_encoder = NormalizedKeyEncoder(
+                [table.schema.field(k).type for k in key_names],
+                nullable=[table.schema.field(k).nullable
+                          for k in key_names])
+        packed = None
+        if encoded is not None:
+            # caller already lane-encoded each run (streamed windows
+            # encode once for the window cut — don't pay the encode
+            # twice); items are (lanes, truncated[, packed-u64])
+            truncated = (np.concatenate([e[1] for e in encoded])
+                         if len(encoded) > 1
+                         else np.asarray(encoded[0][1]))
+            packs = [e[2] if len(e) > 2 else None for e in encoded]
+            if all(p is not None for p in packs):
+                packed = (np.concatenate(packs) if len(packs) > 1
+                          else np.asarray(packs[0]))
+            if packed is not None:
+                # the packed-key host fast path never reads the lane
+                # matrix: concatenating it up front would copy 8N bytes
+                # per window for nothing, so defer until a path
+                # actually wants it
+                lane_parts = [e[0] for e in encoded]
+                lanes = _LazyLanes(lane_parts)
+            else:
+                lanes = (np.concatenate([e[0] for e in encoded])
+                         if len(encoded) > 1
+                         else np.asarray(encoded[0][0]))
         else:
-            lanes = (np.concatenate([e[0] for e in encoded])
-                     if len(encoded) > 1 else np.asarray(encoded[0][0]))
-    else:
-        lanes, truncated, packed = key_encoder.encode_table_ex(
-            table, key_names)
-    seq = np.asarray(table.column(SEQ_COL).combine_chunks().cast(pa.int64()))
+            lanes, truncated, packed = key_encoder.encode_table_ex(
+                table, key_names)
+        seq = np.asarray(
+            table.column(SEQ_COL).combine_chunks().cast(pa.int64()))
 
-    # sorted-run boundaries for the OVC merge path: every input run
-    # (or pre-cut window chunk — chunks of one run arrive in run order,
-    # so treating each as its own run preserves arrival order) is
-    # individually (key, seq)-sorted by the write/compact invariants;
-    # the OVC path re-verifies and falls back if a caller violates that
-    if encoded is not None:
-        run_lens = [e[0].shape[0] for e in encoded]
-    else:
-        run_lens = [r.num_rows for r in runs]
-    run_starts = np.concatenate(
-        [[0], np.cumsum(run_lens)]).astype(np.int64)
-
-    keep = "first" if merge_engine == "first-row" else "last"
-    if seq_fields and keep == "first":
-        # reference forbids the combo: "first by user sequence" would
-        # let later commits replace the retained first row
-        raise ValueError(
-            "sequence.field cannot be used with merge-engine first-row")
-    order_lanes = user_seq_order_lanes(table, seq_fields, seq_desc) \
-        if seq_fields else None
+        # sorted-run boundaries for the OVC merge path: every input run
+        # (or pre-cut window chunk — chunks of one run arrive in run
+        # order, so treating each as its own run preserves arrival
+        # order) is individually (key, seq)-sorted by the write/compact
+        # invariants; the OVC path re-verifies and falls back if a
+        # caller violates that
+        if encoded is not None:
+            run_lens = [e[0].shape[0] for e in encoded]
+        else:
+            run_lens = [r.num_rows for r in runs]
+        run_starts = np.concatenate(
+            [[0], np.cumsum(run_lens)]).astype(np.int64)
+        keep = "first" if merge_engine == "first-row" else "last"
+        if seq_fields and keep == "first":
+            # reference forbids the combo: "first by user sequence" would
+            # let later commits replace the retained first row
+            raise ValueError(
+                "sequence.field cannot be used with merge-engine first-row")
+        order_lanes = user_seq_order_lanes(table, seq_fields, seq_desc) \
+            if seq_fields else None
     # without changelog derivation the caller consumes only winner
     # rows, so the packed-key fast path is admissible — unless any key
     # was prefix-truncated: _refine_truncated needs the full path's

@@ -29,6 +29,7 @@ from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 import numpy as np
 import pyarrow as pa
 
+from paimon_tpu.obs.trace import span
 from paimon_tpu.ops.merge import merge_runs
 from paimon_tpu.ops.normkey import NormalizedKeyEncoder
 
@@ -214,23 +215,26 @@ def iter_merge_windows(
             if tail:
                 yield tail
             return
-        bound = min(r.last_key() for r in non_exhausted)
-        heads: List = []
-        if window_rows:
-            caps = [c for c in (r.key_at(window_rows) for r in runs)
-                    if c is not None]
-            if caps:
-                cap = min(caps)
-                if cap < bound:
-                    for r in runs:          # run order = merge stability
-                        heads.extend(r.cut_lt(cap))
-                    if heads:
-                        yield heads
-                        continue
-                    # a single key group wider than the cap: fall back
-                    # to the natural bound below so the stream advances
-        for r in runs:                      # run order = merge stability
-            heads.extend(r.cut_lt(bound))
+        # the cut alone, not the fill above (the chunk source's time);
+        # the span closes before the yield: a suspended generator holds
+        # none
+        with span("merge.cut", cat="merge"):
+            bound = min(r.last_key() for r in non_exhausted)
+            heads: List = []
+            if window_rows:
+                caps = [c for c in (r.key_at(window_rows) for r in runs)
+                        if c is not None]
+                if caps:
+                    cap = min(caps)
+                    if cap < bound:
+                        for r in runs:      # run order = merge stability
+                            heads.extend(r.cut_lt(cap))
+                        # no rows below the cap: a single key group
+                        # wider than it — fall back to the natural
+                        # bound below so the stream advances
+            if not heads:
+                for r in runs:              # run order = merge stability
+                    heads.extend(r.cut_lt(bound))
         if heads:
             yield heads
         else:

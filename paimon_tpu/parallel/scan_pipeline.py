@@ -279,8 +279,8 @@ def _iter_pipelined(read, splits, options, par, *, ordered, stats):
                            est_bytes=b):
                     inflight.append(
                         [next_i, s, b,
-                         pool.submit(_read_split_traced, read, s,
-                                     table_path)])
+                         pool.submit(_trace.carry(_read_split_traced),
+                                     read, s, table_path)])
                 inflight_bytes += b
                 next_i += 1
                 c_splits.inc()

@@ -273,7 +273,11 @@ class FlushPool:
             self.max_inflight_tasks = max(self.max_inflight_tasks,
                                           self._inflight_tasks)
             self._g_inflight.set(self._inflight_bytes)
-            self._queues.setdefault(key, deque()).append((est_bytes, fn))
+            # each task carries its own submitter's span: the actor
+            # that drains this key was started by an earlier one
+            from paimon_tpu.obs.trace import carry
+            self._queues.setdefault(key, deque()).append(
+                (est_bytes, fn, carry(self._run_task)))
             if key not in self._active:
                 self._active.add(key)
                 self._ensure_pool().submit(self._drain_key, key)
@@ -289,7 +293,9 @@ class FlushPool:
         and start a fresh one."""
         if self.serial:
             return
-        with self._cond:
+        from paimon_tpu.obs.trace import span as _span
+        with _span("wait", cat="wait", what="write drain barrier"), \
+                self._cond:
             self._check_poisoned()
             while self._inflight_tasks > 0 and self._error is None:
                 from paimon_tpu.utils.deadline import (
@@ -304,7 +310,7 @@ class FlushPool:
                     # — the deadline must not wait on a hung upload)
                     for q in self._queues.values():
                         while q:
-                            est, _ = q.popleft()
+                            est = q.popleft()[0]
                             self._inflight_bytes -= est
                             self._inflight_tasks -= 1
                     self._g_inflight.set(self._inflight_bytes)
@@ -316,7 +322,7 @@ class FlushPool:
                 # running tasks to finish so state stops mutating
                 for q in self._queues.values():
                     while q:
-                        est, _ = q.popleft()
+                        est = q.popleft()[0]
                         self._inflight_bytes -= est
                         self._inflight_tasks -= 1
                 while self._inflight_tasks > 0:
@@ -380,16 +386,16 @@ class FlushPool:
                     if q:
                         # pipeline failed: cancel this key's backlog
                         while q:
-                            est, _ = q.popleft()
+                            est = q.popleft()[0]
                             self._inflight_bytes -= est
                             self._inflight_tasks -= 1
                         self._g_inflight.set(self._inflight_bytes)
                     self._active.discard(key)
                     self._cond.notify_all()
                     return
-                est, fn = q.popleft()
+                est, fn, run_task = q.popleft()
             try:
-                self._run_task(key, fn)
+                run_task(key, fn)
             except BaseException as e:      # noqa: BLE001 — latched
                 with self._cond:
                     if self._error is None:

@@ -183,9 +183,13 @@ class FileStoreTable:
         # manifest walk is store IO and must ride the same deadline
         # as the read (TableRead.to_arrow's own entry scope only
         # guards reads over pre-built plans)
+        from paimon_tpu.obs.trace import span
         from paimon_tpu.utils.deadline import deadline_scope
+        # the scan's root span: plan, every split (on its pool worker)
+        # and the assembly walk back to it
         with deadline_scope(self.options.get(
-                CoreOptions.REQUEST_TIMEOUT), entry=True):
+                CoreOptions.REQUEST_TIMEOUT), entry=True), \
+                span("scan.to_arrow", cat="scan", table=self.path):
             rb = self.new_read_builder()
             if projection:
                 rb = rb.with_projection(projection)
@@ -197,8 +201,9 @@ class FileStoreTable:
                 # pushed LIMIT: the pipelined read stops admitting
                 # splits once enough rows are buffered
                 rb = rb.with_limit(limit)
-            scan = rb.new_scan()
-            return rb.new_read().to_arrow(scan.plan().splits)
+            with span("scan.plan", cat="scan"):
+                splits = rb.new_scan().plan().splits
+            return rb.new_read().to_arrow(splits)
 
     def compact(self, full: bool = False,
                 partition_filter: Optional[dict] = None,
@@ -530,11 +535,15 @@ class TableWrite:
     def write_arrow(self, data: pa.Table,
                     row_kinds: Optional[np.ndarray] = None,
                     buckets=None):
-        data = self._apply_field_defaults(data)
-        if buckets is not None:
-            self._write.write_arrow(data, row_kinds, buckets=buckets)
-        else:
-            self._write.write_arrow(data, row_kinds)
+        from paimon_tpu.obs.trace import span
+        # one batch write has three calls and so three root spans:
+        # write.batch, write.prepare, write.commit
+        with span("write.batch", cat="write", rows=data.num_rows):
+            data = self._apply_field_defaults(data)
+            if buckets is not None:
+                self._write.write_arrow(data, row_kinds, buckets=buckets)
+            else:
+                self._write.write_arrow(data, row_kinds)
 
     def _apply_field_defaults(self, data: pa.Table) -> pa.Table:
         """NULL incoming values become the column's configured default
@@ -589,7 +598,9 @@ class TableWrite:
         (parallel/write_pipeline.py): drains every in-flight bucket
         flush, re-raising the first worker error, then returns the
         accumulated commit messages."""
-        return self._write.prepare_commit()
+        from paimon_tpu.obs.trace import span
+        with span("write.prepare", cat="write"):
+            return self._write.prepare_commit()
 
     def close(self):
         """Shuts down the flush pool (joining its workers) and drops
@@ -633,9 +644,12 @@ class TableCommit:
         (entry point): retry/CAS backoffs stop sleeping once it is
         spent and the snapshot CAS is never attempted past it — a
         timed-out commit raises instead of orphan-committing."""
+        from paimon_tpu.obs.trace import span
         from paimon_tpu.utils.deadline import deadline_scope
         with deadline_scope(self.table.options.get(
-                CoreOptions.REQUEST_TIMEOUT), entry=True):
+                CoreOptions.REQUEST_TIMEOUT), entry=True), \
+                span("write.commit", cat="write",
+                     messages=len(messages)):
             return self._commit_with_deadline(
                 messages, commit_identifier, watermark, properties)
 
@@ -989,8 +1003,8 @@ class TableRead:
                 if n >= limit:
                     break
             if tables:
-                out = pa.concat_tables(tables,
-                                       promote_options="default")
+                from paimon_tpu.core.read import assemble_tables
+                out = assemble_tables(tables)
             else:
                 if streaming is None:
                     streaming = any(s.for_streaming for s in split_list)

@@ -177,24 +177,30 @@ def wait_future(fut, what: str = "future", poll_s: float = 0.5):
     the moment the budget is spent — a hung worker can no longer hold
     a timed-out request (the worker itself keeps running and its
     result is discarded, same abandonment contract as the scan
-    pipeline's hung-split path)."""
-    dl = _CURRENT.get()
-    if dl is None:
-        return fut.result()
-    import concurrent.futures as _cf
-    while True:
-        dl.check(what)
-        try:
-            return fut.result(timeout=min(poll_s, dl.remaining_s()))
-        except _cf.TimeoutError:
-            if fut.done():
-                # the future completed in the window between the wait
-                # timing out and this check (or the worker itself
-                # raised) — a done future answers instantly with the
-                # WORKER's outcome; re-raising the poll's TimeoutError
-                # here would turn a successful result into a crash
-                return fut.result()
-            continue
+    pipeline's hung-split path).
+
+    Every wait is one `wait` span naming `what`: it says who waited,
+    never what ran."""
+    from paimon_tpu.obs.trace import span
+    with span("wait", cat="wait", what=what):
+        dl = _CURRENT.get()
+        if dl is None:
+            return fut.result()
+        import concurrent.futures as _cf
+        while True:
+            dl.check(what)
+            try:
+                return fut.result(timeout=min(poll_s, dl.remaining_s()))
+            except _cf.TimeoutError:
+                if fut.done():
+                    # the future completed in the window between the
+                    # wait timing out and this check (or the worker
+                    # itself raised) — a done future answers instantly
+                    # with the WORKER's outcome; re-raising the poll's
+                    # TimeoutError here would turn a successful result
+                    # into a crash
+                    return fut.result()
+                continue
 
 
 def run_with_deadline(dl: Optional[Deadline], fn: Callable, /,

@@ -34,10 +34,12 @@ def _clean_tracer():
     the ring around every test so no spans leak across tests."""
     was_tracing = obs.tracing_enabled()
     was_metrics = obs.metrics_enabled()
+    ring = obs.collector().max_spans
     obs.collector().clear()
     yield
     (obs.enable_tracing if was_tracing else obs.disable_tracing)()
     obs.set_metrics_enabled(was_metrics)
+    obs.collector().resize(ring)     # a test's small ring stays its own
     obs.collector().clear()
 
 
@@ -535,3 +537,276 @@ def test_disabled_tracing_overhead_bounded(entry):
         f"fleet-observability overhead {fleet}% >= 25% "
         f"(noinstr={by_name['obs_scan_noinstr']['best_seconds']}s, "
         f"fleet={by_name['obs_scan_fleet']['best_seconds']}s)")
+
+
+# -- profiler listener, cross-pool parents, stage spans (ISSUE 26) ----------
+
+def _registry_totals():
+    """{(group, metric): (sum or count, samples)} over every table."""
+    from paimon_tpu.metrics import global_registry
+    out = {}
+    for r in global_registry().snapshot_rows():
+        if r["kind"] == "histogram":
+            value, n = r["total_sum"], r["total_count"]
+        elif r["kind"] == "counter":
+            value, n = r["value"], r["value"]
+        else:
+            continue
+        key = (r["group"], r["metric"])
+        old = out.get(key, (0, 0))
+        out[key] = (old[0] + value, old[1] + n)
+    return out
+
+
+def _delta(before, after, group, metric):
+    a, b = before.get((group, metric), (0, 0)), after.get((group, metric),
+                                                          (0, 0))
+    return b[0] - a[0], b[1] - a[1]
+
+
+def _small_agg_table(path, rows=6_000, commits=3, streamed=True):
+    """An aggregation table of `commits` overlapping runs in one bucket;
+    `streamed` makes its full compaction take the streamed-window path
+    (prefetch thread, merge pool, write pool)."""
+    opts = {"bucket": "1", "write-only": "true",
+            "merge-engine": "aggregation",
+            "fields.v.aggregate-function": "sum"}
+    if streamed:
+        opts.update({"tpu.merge.stream-threshold-rows": "1000",
+                     "tpu.merge.chunk-rows": "2000",
+                     "tpu.merge.window-rows": "1500"})
+    schema = (Schema.builder().column("id", BigIntType(False))
+              .column("v", BigIntType()).primary_key("id")
+              .options(opts).build())
+    table = FileStoreTable.create(path, schema)
+    rng = np.random.default_rng(7)
+    for _ in range(commits):
+        wb = table.new_batch_write_builder()
+        with wb.new_write() as w:
+            w.write_arrow(pa.table({
+                "id": pa.array(rng.permutation(rows), pa.int64()),
+                "v": pa.array(rng.integers(0, 9, rows), pa.int64())}))
+            wb.new_commit().commit(w.prepare_commit())
+    return FileStoreTable.load(path)
+
+
+def _host_annotations(trace_dir):
+    """{annotation name: count} over every host line of the recorded
+    `.xplane.pb`."""
+    from jax.profiler import ProfileData
+    found = [os.path.join(d, f) for d, _, fs in os.walk(trace_dir)
+             for f in fs if f.endswith(".xplane.pb")]
+    assert len(found) == 1, found
+    names = {}
+    for plane in ProfileData.from_file(found[0]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    names[e.name] = names.get(e.name, 0) + 1
+    return names
+
+
+def test_profiler_session_alone_is_a_listener(tmp_path):
+    """No enable_tracing(): an open jax.profiler session puts the
+    program's spans on the host plane as `paimon.<name>` and their
+    histograms fill as ever; the ring stays empty."""
+    import jax
+    table = _build_traced_table(str(tmp_path / "t"), rows=5_000)
+    assert not obs.tracing_enabled()
+    before = _registry_totals()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "prof"),
+                             profiler_options=options)
+    try:
+        assert obs.profiler_listening()
+        table.to_arrow()
+    finally:
+        jax.profiler.stop_trace()
+    assert not obs.profiler_listening()
+    names = _host_annotations(str(tmp_path / "prof"))
+    for name in ("paimon.scan.to_arrow", "paimon.scan.split",
+                 "paimon.merge.prep", "paimon.decode"):
+        assert names.get(name), f"{name} not on any host line: {names}"
+    after = _registry_totals()
+    for group, metric in (("scan", "split_ms"), ("merge", "prep_ms"),
+                          ("io", "decode_ms")):
+        assert _delta(before, after, group, metric)[1] > 0, metric
+    assert obs.take_spans() == []
+
+
+def test_no_listener_keeps_the_disabled_path():
+    from paimon_tpu.obs import trace as T
+    assert not obs.tracing_enabled() and not obs.profiler_listening()
+    assert T.span("scan.admit", cat="scan", split=1) is T._NOOP
+    grouped = T.span("scan.split", group="scan", metric="split_ms")
+    assert type(grouped) is T._MetricSpan
+    obs.set_metrics_enabled(False)
+    assert T.span("scan.split", group="scan",
+                  metric="split_ms") is T._NOOP
+
+    def fn():
+        return 1
+    assert T.carry(fn) is fn        # nothing to carry, nothing wrapped
+
+
+def test_carry_records_the_submitters_span_as_parent():
+    from concurrent.futures import ThreadPoolExecutor
+
+    from paimon_tpu.obs.trace import carry, span
+    obs.enable_tracing()
+
+    def task():
+        with span("child"):
+            pass
+        return obs.current_context_token()
+
+    with ThreadPoolExecutor(max_workers=1) as pool:
+        pool.submit(lambda: None).result()      # the worker exists
+        with span("submitter"):
+            carried = pool.submit(carry(task)).result()
+            bare = pool.submit(task).result()
+        after = pool.submit(task).result()
+    spans = obs.take_spans()
+    submitter = next(s for s in spans if s.name == "submitter")
+    children = [s for s in spans if s.name == "child"]
+    assert [c.parent_id for c in children] == \
+        [submitter.span_id, None, None]
+    assert carried.endswith(f":{submitter.span_id}")
+    # the worker's own context is left as it was found
+    assert bare is None and after is None
+
+
+def _roots(spans):
+    """{span id: its root span}, walking parent ids inside the ring."""
+    by_id = {s.span_id: s for s in spans}
+    out = {}
+    for s in spans:
+        top = s
+        while top.parent_id is not None:
+            assert top.parent_id in by_id, \
+                f"{s.name}: parent of {top.name} is not in the ring"
+            top = by_id[top.parent_id]
+        out[s.span_id] = top
+    return out
+
+
+@pytest.mark.parametrize("operation", ["to_arrow", "compact", "commit"])
+def test_every_span_walks_back_to_its_operations_root(tmp_path,
+                                                      operation):
+    table = _small_agg_table(str(tmp_path / "t"))
+    obs.enable_tracing(max_spans=50_000)
+    if operation == "to_arrow":
+        table.copy({"scan.split.parallelism": "4"}).to_arrow()
+        allowed = {"scan.to_arrow"}
+        threads = ("MainThread",)
+    elif operation == "compact":
+        assert table.compact(full=True) is not None
+        # the task, then the commit of its result
+        allowed = {"compact.task", "commit"}
+        threads = ("MainThread", "paimon-prefetch-pump",
+                   "ThreadPoolExecutor")
+    else:
+        wb = table.new_batch_write_builder()
+        with wb.new_write() as w:
+            w.write_arrow(pa.table({
+                "id": pa.array(np.arange(3_000), pa.int64()),
+                "v": pa.array(np.ones(3_000, np.int64))}))
+            wb.new_commit().commit(w.prepare_commit())
+        allowed = {"write.batch", "write.prepare", "write.commit"}
+        threads = ("MainThread", "paimon-write-prep", "paimon-write")
+    spans = obs.take_spans()
+    roots = _roots(spans)
+    assert {r.name for r in roots.values()} == allowed
+    assert all(r.thread == "MainThread" for r in roots.values())
+    seen = {s.thread.split("_")[0].split("-0")[0] for s in spans}
+    for prefix in threads:
+        assert any(t.startswith(prefix) for t in seen), (prefix, seen)
+
+
+@pytest.mark.parametrize("route", ["host", "device"])
+def test_stage_histograms_fill_and_bytes_only_on_the_device_route(
+        tmp_path, monkeypatch, route):
+    """A tiny write -> compact -> scan leaves time in every stage
+    histogram the benchmark reads and a span for every other stage;
+    bytes cross the link only when a merge took the device route."""
+    monkeypatch.delenv("PAIMON_FORCE_HOST_SORT", raising=False)
+    if route == "device":
+        monkeypatch.setenv("PAIMON_FORCE_DEVICE_SORT", "1")
+    else:
+        monkeypatch.delenv("PAIMON_FORCE_DEVICE_SORT", raising=False)
+    before = _registry_totals()
+    obs.enable_tracing(max_spans=50_000)
+    table = _small_agg_table(str(tmp_path / "t"))
+    dedup = _build_traced_table(str(tmp_path / "d"), rows=2_000)
+    assert table.compact(full=True) is not None
+    table.to_arrow()
+    dedup.to_arrow()
+    spans = obs.take_spans()
+    after = _registry_totals()
+    for group, metric in (("merge", "prep_ms"), ("merge", "agg_ms"),
+                          ("write", "route_ms"), ("io", "decode_ms")):
+        total, samples = _delta(before, after, group, metric)
+        assert samples > 0 and total > 0, (group, metric)
+    # stages no metric reads are spans only: no histogram, no counter
+    for group, metric in (("merge", "gather_ms"), ("merge", "host_ms"),
+                          ("merge", "h2d_bytes"), ("compaction", "wait_ms"),
+                          ("compaction", "cut_ms"),
+                          ("scan", "assemble_ms")):
+        assert (group, metric) not in after, (group, metric)
+    names = {s.name for s in spans}
+    assert {"merge.gather", "merge.cut", "compact.window",
+            "scan.assemble", "wait"} <= names
+    waits = {s.attrs["what"] for s in spans if s.name == "wait"}
+    assert {"compaction prefetch", "compaction merge window",
+            "compaction file write"} <= waits
+    device = [s for s in spans if s.name == "merge.device"]
+    device_ms = _delta(before, after, "merge", "device_ms")
+    if route == "device":
+        assert device and device_ms[1] == len(device)
+        for s in device:
+            assert s.attrs["h2d_bytes"] > 0 and s.attrs["d2h_bytes"] > 0
+            # whole padded operands of uint32
+            assert s.attrs["h2d_bytes"] % 4096 == 0
+    else:
+        assert not device and device_ms[1] == 0
+        assert "merge.host" in names
+
+
+def test_streamed_decode_has_the_decode_span(tmp_path):
+    """`read_batches` — the compaction's streamed decode — records the
+    `decode` span per batch, the name `read`'s span has."""
+    from paimon_tpu.format import get_format
+    from paimon_tpu.fs import LocalFileIO
+    path = str(tmp_path / "f.parquet")
+    io = LocalFileIO()
+    get_format("parquet").create_writer("zstd", {}).write(
+        io, path, pa.table({"a": pa.array(np.arange(5_000))}))
+    obs.enable_tracing()
+    before = _registry_totals()
+    batches = list(get_format("parquet").create_reader().read_batches(
+        io, path, batch_rows=2_000))
+    assert sum(b.num_rows for b in batches) == 5_000
+    decodes = [s for s in obs.take_spans() if s.name == "decode"]
+    assert len(decodes) >= len(batches) == 3
+    assert _delta(before, _registry_totals(), "io", "decode_ms")[1] \
+        >= 3
+
+
+def test_task_and_commit_durations_keep_one_sample_each(tmp_path):
+    table = _small_agg_table(str(tmp_path / "t"), streamed=False)
+    before = _registry_totals()
+    assert table.compact(full=True) is not None
+    after = _registry_totals()
+    # one bucket, one task; its result is one commit
+    assert _delta(before, after, "compaction", "duration_ms")[1] == 1
+    assert _delta(before, after, "compaction", "tasks")[0] == 1
+    assert _delta(before, after, "commit", "duration_ms")[1] == 1
+    assert _delta(before, after, "commit", "commits")[0] == 1
+    wb = table.new_batch_write_builder()
+    with wb.new_write() as w:
+        w.write_arrow(pa.table({"id": pa.array([1], pa.int64()),
+                                "v": pa.array([1], pa.int64())}))
+        wb.new_commit().commit(w.prepare_commit())
+    assert _delta(after, _registry_totals(), "commit",
+                  "duration_ms")[1] == 1
