@@ -5,9 +5,13 @@ AggregateMergeFunction + 24 FieldAggregators (mergetree/compact/aggregate/).
 
 The record-at-a-time accumulate loop becomes: device sort by (key, seq)
 (shared kernel in ops/merge.py) -> per-key segment ids -> per-column
-segmented reduction. Numeric sum/max/min/count/product run on device via
-jax.ops.segment_* (float64 columns on the host: the chip has no 64-bit
-float, see _host_segment_reduce); order-based aggregates
+segmented reduction. The sort leaves each key's rows contiguous, so the
+ids are ascending and dense (the contract `_segment_ends` states and
+checks) and a reduction is a segmented scan: numeric
+sum/max/min/count/product run on device as a few element-wise passes
+over the rows (`_sorted_segment_scan`; no scatter, which the chip would
+execute row by row), float64 columns by `reduceat` on the host (the chip
+has no 64-bit float, see _host_segment_reduce); order-based aggregates
 (last/first[-non-null] value,
 listagg, strings) reduce to a per-segment index selection computed on
 device and a host-side Arrow take, so variable-length data never crosses
@@ -16,7 +20,6 @@ to HBM.
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -140,24 +143,77 @@ def _segment_ids_from_sort(lanes: np.ndarray, seq: np.ndarray,
     return order, seg_id, win_sorted
 
 
-@partial(jax.jit, static_argnums=2)
-def _seg_sum_jit(vals, seg_ids, num_seg):
-    return jax.ops.segment_sum(vals, seg_ids, num_segments=num_seg)
+def _segment_ends(seg_ids: np.ndarray, num_seg: int):
+    """The sorted-segment contract of this module, stated once: the rows
+    of a reduction arrive in sorted order and `seg_ids` is ascending and
+    dense — run k of equal ids carries id k, for k in [0, num_seg) — as
+    the device sort's winner flags give it (`_segment_ids_from_sort`,
+    the mesh engine's epilogue).  Both the host and the device reduce
+    rely on it and neither can tell wrong ids from their result, so it
+    is checked here: ValueError otherwise.
+
+    Returns (is_end, ends): bool[n], True at the last row of each
+    segment, and those rows' positions."""
+    n = len(seg_ids)
+    is_end = np.ones(n, dtype=bool)
+    np.not_equal(seg_ids[1:], seg_ids[:-1], out=is_end[:n - 1])
+    ends = np.flatnonzero(is_end)
+    if len(ends) != num_seg or not np.array_equal(
+            seg_ids[ends], np.arange(num_seg, dtype=seg_ids.dtype)):
+        raise ValueError(f"segment ids not ascending and dense: "
+                         f"{len(ends)} runs for {num_seg} segments")
+    return is_end, ends
 
 
-@partial(jax.jit, static_argnums=2)
-def _seg_max_jit(vals, seg_ids, num_seg):
-    return jax.ops.segment_max(vals, seg_ids, num_segments=num_seg)
+def _sorted_segment_scan(op, vals, starts):
+    """Segmented inclusive scan of `vals` under the binary `op`; a
+    segment begins at each True of `starts` (row 0 is one).  Afterwards
+    the last row of a segment holds the segment's reduction.
+
+    Doubling shifts: pass d folds row i-d into row i unless a segment
+    start lies in (i-d, i]; `f` carries that OR over the 2d-1 rows up to
+    i, so rows before d (f[0] is set) never take a rolled-around value.
+    The loop ends once every row has seen its start: ceil(log2(longest
+    segment)) element-wise passes, no scatter and no gather.  Only rows
+    of one segment are ever combined, so integer results are exact
+    (wrap-around included) and floats associate as a tree.
+
+    A 64-bit result leaves as its two 32-bit words, uint32[2, n] (low,
+    high): the chip keeps an int64 as such a pair, and fetched as int64
+    it crosses the link several times slower than its words do."""
+    def fold(carry):
+        d, f, v = carry
+        return (d * 2, f | jnp.roll(f, d),
+                jnp.where(f, v, op(jnp.roll(v, d), v)))
+
+    _, _, v = jax.lax.while_loop(lambda carry: ~jnp.all(carry[1]), fold,
+                                 (jnp.int32(1), starts, vals))
+    if v.dtype.itemsize == 8:
+        return jnp.stack([v.astype(jnp.uint32),
+                          (v >> 32).astype(jnp.uint32)])
+    return v
 
 
-@partial(jax.jit, static_argnums=2)
-def _seg_min_jit(vals, seg_ids, num_seg):
-    return jax.ops.segment_min(vals, seg_ids, num_segments=num_seg)
+# one XLA module each, under these names: the benchmark's
+# segreduce_kernel_roofline finds its work by the `_seg_` in them
+@jax.jit
+def _seg_sum_jit(vals, starts):
+    return _sorted_segment_scan(jnp.add, vals, starts)
 
 
-@partial(jax.jit, static_argnums=2)
-def _seg_prod_jit(vals, seg_ids, num_seg):
-    return jax.ops.segment_prod(vals, seg_ids, num_segments=num_seg)
+@jax.jit
+def _seg_max_jit(vals, starts):
+    return _sorted_segment_scan(jnp.maximum, vals, starts)
+
+
+@jax.jit
+def _seg_min_jit(vals, starts):
+    return _sorted_segment_scan(jnp.minimum, vals, starts)
+
+
+@jax.jit
+def _seg_prod_jit(vals, starts):
+    return _sorted_segment_scan(jnp.multiply, vals, starts)
 
 
 def _host_segment_reduce(ufunc, vals: np.ndarray, seg_ids: np.ndarray,
@@ -165,48 +221,53 @@ def _host_segment_reduce(ufunc, vals: np.ndarray, seg_ids: np.ndarray,
     """Segmented reduce on the host, for the one value type the chip
     cannot hold: XLA's TPU backend keeps a float64 as a pair of
     float32, so 1e300 arrives as inf, 1e-300 as 0 and pi without its
-    low bits — a reduce there cannot be bit-identical.  Relies on this
-    module's sorted-segment contract (`seg_ids` ascending and dense)."""
+    low bits — a reduce there cannot be bit-identical.  Relies on the
+    sorted-segment contract (`_segment_ends`)."""
     with span("agg.host", cat="merge", rows=len(vals)):
-        starts = np.flatnonzero(np.concatenate(
-            [[True], seg_ids[1:] != seg_ids[:-1]]))
-        if len(starts) != num_seg:
-            raise ValueError(f"segment ids not ascending and dense: "
-                             f"{len(starts)} runs for {num_seg} segments")
+        _, ends = _segment_ends(seg_ids, num_seg)
+        starts = np.zeros(num_seg, dtype=np.intp)
+        starts[1:] = ends[:-1] + 1
         return ufunc.reduceat(vals, starts)
 
 
 def _padded_seg(fn_jit, ufunc):
-    """BOTH the row count and num_segments pad to powers of two, so XLA
-    compiles O(log^2) distinct shapes across a whole compaction instead
-    of one per window (a streamed merge emits hundreds of distinct
-    (rows, segments) pairs; each used to recompile).  Padding rows
-    point at a dedicated dummy segment past num_seg, which the final
-    slice drops — their values never touch a real segment.
+    """Sorted-segment reduce `call(vals, seg_ids, num_seg) -> np.ndarray`
+    under the contract of `_segment_ends`, which it checks.
 
-    float64 values reduce on the host (`_host_segment_reduce`).
-    Returns a numpy array: every caller fetched the result at once, so
-    the fetch sits here, inside the `agg.device` span (pad, upload,
-    program, slice, download)."""
+    The device program (`_sorted_segment_scan`) needs boundaries, not
+    ids: what crosses the link is the values and one start flag a row,
+    and the whole scan comes back, of which the host keeps each
+    segment's last row.  The row count pads to a power of two, so a
+    compaction compiles O(log) shapes instead of one per window (a
+    streamed merge emits hundreds of distinct row counts); every
+    padding row is a segment of its own past the real ones, so it costs
+    no pass and its value never touches a real segment.
+
+    float64 values reduce on the host (`_host_segment_reduce`).  The
+    fetch sits inside the `agg.device` span (bounds, pad, upload,
+    program, download, take)."""
     def call(vals, seg_ids, num_seg):
         vals = np.asarray(vals)
         seg_ids = np.asarray(seg_ids)
         n = len(vals)
-        if vals.dtype == np.float64 and n:
+        if vals.dtype == np.float64:
             return _host_segment_reduce(ufunc, vals, seg_ids, num_seg)
         with span("agg.device", cat="merge", rows=n, segments=num_seg):
-            # strictly greater than num_seg so the dummy segment exists
-            padded_seg = 1 << max(4, int(num_seg).bit_length())
-            m = 1 << max(10, int(n - 1).bit_length()) if n > 1 else 1024
-            if m > n:
-                vals = np.concatenate(
-                    [vals, np.zeros(m - n, dtype=vals.dtype)])
-                seg_ids = np.concatenate(
-                    [seg_ids, np.full(m - n, padded_seg - 1,
-                                      dtype=seg_ids.dtype)])
-            out = fn_jit(jnp.asarray(vals), jnp.asarray(seg_ids),
-                         padded_seg)
-            return np.asarray(jnp.asarray(out)[:num_seg])
+            is_end, ends = _segment_ends(seg_ids, num_seg)
+            m = 1 << max(10, int(n - 1).bit_length())
+            padded = np.zeros(m, dtype=vals.dtype)
+            padded[:n] = vals
+            starts = np.ones(m, dtype=bool)
+            starts[1:n] = is_end[:n - 1]
+            out = np.asarray(fn_jit(jnp.asarray(padded),
+                                    jnp.asarray(starts)))
+            if out.ndim == 1:
+                return out[ends]
+            # a 64-bit scan comes back as its words: (low, high)
+            joined = out[1][ends].astype(np.uint64)
+            joined <<= np.uint64(32)
+            joined |= out[0][ends]
+            return joined.view(vals.dtype)
     return call
 
 
