@@ -34,6 +34,7 @@ __all__ = ["Counter", "Gauge", "Histogram", "MetricGroup",
            "COMMIT_DURATION_MS", "COMPACTION_DURATION_MS",
            "WRITE_ROUTE_MS",
            "MERGE_PREP_MS", "MERGE_DEVICE_MS", "MERGE_AGG_MS",
+           "MERGE_SELECT_MS", "MERGE_GATHER_MS", "MERGE_GATHER_BYTES",
            "STREAM_EVENTS_INGESTED", "STREAM_CHECKPOINTS",
            "STREAM_CHECKPOINT_MS", "STREAM_LOOP_RESTARTS",
            "STREAM_FRESHNESS_MS", "STREAM_CHANGELOG_ROWS",
@@ -143,6 +144,9 @@ WRITE_ROUTE_MS = "route_ms"                 # write: hash/group-by/take
 MERGE_PREP_MS = "prep_ms"                   # concat, lane encode, pad
 MERGE_DEVICE_MS = "device_ms"               # first upload -> result on host
 MERGE_AGG_MS = "agg_ms"                     # aggregation epilogue, whole
+MERGE_SELECT_MS = "select_ms"               # its per-segment row selections
+MERGE_GATHER_MS = "gather_ms"               # Arrow take in merge order
+MERGE_GATHER_BYTES = "gather_bytes"         # counter: buffer bytes taken
 
 # streaming-daemon counter/gauge/histogram names (stream metric group;
 # producer is service/stream_daemon.py, consumers tests/soak_harness.py

@@ -28,7 +28,7 @@ import numpy as np
 import pyarrow as pa
 import pyarrow.compute as pc
 
-from paimon_tpu.metrics import MERGE_AGG_MS
+from paimon_tpu.metrics import MERGE_AGG_MS, MERGE_SELECT_MS
 from paimon_tpu.obs.trace import span
 from paimon_tpu.options import CoreOptions, MergeEngine
 from paimon_tpu.ops.merge import (
@@ -296,6 +296,27 @@ def _first_index_where(mask: np.ndarray, seg_id: np.ndarray,
     return np.where(out > n, -1, out)
 
 
+# order-based aggregates -> (index selection, over non-null rows only)
+_INDEX_SELECTIONS = {
+    "last_non_null_value": (_last_index_where, True),
+    "last_value": (_last_index_where, False),
+    "first_non_null_value": (_first_index_where, True),
+    "first_value": (_first_index_where, False),
+    # reference FieldPrimaryKeyAgg: the first value sticks
+    "primary_key": (_first_index_where, True),
+}
+
+
+def _select_span(rows: int, groups: int, columns: int):
+    """`agg.select`: which row of each segment gives a column its value
+    — a sequence group's resolution (`groups` of them over `columns`
+    members) or one order-based aggregate (`groups` 0) — masks on the
+    host around the `agg.device` / `agg.host` spans of its reductions."""
+    return span("agg.select", cat="merge", group="merge",
+                metric=MERGE_SELECT_MS, rows=rows, groups=groups,
+                columns=columns)
+
+
 def _masked_numeric(result: np.ndarray, any_valid: np.ndarray,
                     out_type: pa.DataType) -> pa.Array:
     """Vectorized (values, null-mask) -> typed Arrow array; a per-row
@@ -405,19 +426,22 @@ def _aggregate_sorted_segments(table, order, seg_id, win_sorted, key_cols,
     # PartialUpdateMergeFunction sequence groups; ties -> later row wins)
     seq_group_idx: Dict[str, np.ndarray] = {}
     if options.merge_engine == MergeEngine.PARTIAL_UPDATE:
-        for gkey, cols in sequence_groups(schema, options).items():
-            seq_fields = [s.strip() for s in gkey.split(",")]
-            idx = _seq_group_winner_index(sorted_tbl, seq_fields, seg_id,
-                                          num_seg, add_mask)
-            for colname in dict.fromkeys(list(cols) + seq_fields):
-                if options.options.get_or(
-                        f"fields.{colname}.aggregate-function",
-                        None) is not None:
-                    raise NotImplementedError(
-                        f"aggregate-function on sequence-group member "
-                        f"{colname!r} (reference: aggregation within "
-                        f"sequence groups) is not supported yet")
-                seq_group_idx[colname] = idx
+        groups = sequence_groups(schema, options)
+        with _select_span(len(order), len(groups),
+                          sum(len(cols) for cols in groups.values())):
+            for gkey, cols in groups.items():
+                seq_fields = [s.strip() for s in gkey.split(",")]
+                idx = _seq_group_winner_index(sorted_tbl, seq_fields,
+                                              seg_id, num_seg, add_mask)
+                for colname in dict.fromkeys(list(cols) + seq_fields):
+                    if options.options.get_or(
+                            f"fields.{colname}.aggregate-function",
+                            None) is not None:
+                        raise NotImplementedError(
+                            f"aggregate-function on sequence-group member "
+                            f"{colname!r} (reference: aggregation within "
+                            f"sequence groups) is not supported yet")
+                    seq_group_idx[colname] = idx
 
     for f in schema.fields:
         name = f.name
@@ -483,14 +507,11 @@ def _aggregate_sorted_segments(table, order, seg_id, win_sorted, key_cols,
                                                  col_sorted.type)
                 continue
         # order-based aggregates: pick an index per segment, host gather
-        if func == "last_non_null_value":
-            idx = _last_index_where(valid & add_mask, seg_id, num_seg)
-        elif func == "last_value":
-            idx = _last_index_where(add_mask, seg_id, num_seg)
-        elif func == "first_non_null_value":
-            idx = _first_index_where(valid & add_mask, seg_id, num_seg)
-        elif func == "first_value":
-            idx = _first_index_where(add_mask, seg_id, num_seg)
+        if func in _INDEX_SELECTIONS:
+            pick, non_null = _INDEX_SELECTIONS[func]
+            with _select_span(len(order), 0, 1):
+                idx = pick(valid & add_mask if non_null else add_mask,
+                           seg_id, num_seg)
         elif func == "listagg":
             out_cols[name] = _listagg(col_sorted, valid & add_mask, seg_id,
                                       num_seg, options, name)
@@ -509,9 +530,6 @@ def _aggregate_sorted_segments(table, order, seg_id, win_sorted, key_cols,
             out_cols[name] = _merge_map(col_sorted, valid & add_mask,
                                         seg_id, num_seg)
             continue
-        elif func == "primary_key":
-            # reference FieldPrimaryKeyAgg: the first value sticks
-            idx = _first_index_where(valid & add_mask, seg_id, num_seg)
         elif func in ("rbm32", "rbm64"):
             out_cols[name] = _rbm_agg(col_sorted, valid & add_mask,
                                       seg_id, num_seg, func, name)
@@ -561,15 +579,21 @@ def _seq_group_winner_index(sorted_tbl: pa.Table, seq_fields: List[str],
                             seg_id: np.ndarray, num_seg: int,
                             add_mask: np.ndarray) -> np.ndarray:
     """Per segment: position (into sorted order) of the row with the
-    largest non-null group-sequence tuple; -1 if no row qualifies.
-    Rows with any null sequence field never update the group (reference
-    PartialUpdateMergeFunction: null sequence -> skip)."""
-    n = sorted_tbl.num_rows
-    valid = np.ones(n, dtype=bool)
-    mats = []
-    for fname in seq_fields:
-        arr = sorted_tbl.column(fname).combine_chunks()
-        valid &= np.asarray(pc.is_valid(arr))
+    largest non-null group-sequence tuple, the later row of equals; -1
+    if no row qualifies.  Rows with any null sequence field never update
+    the group (reference PartialUpdateMergeFunction: null sequence ->
+    skip).
+
+    The lexicographic maximum, field by field in declaration order: the
+    segment maximum of the field among the rows still in the running,
+    which then keeps the rows that equal it.  Nothing is sorted or
+    ranked; each field is compared on its native values (integers and
+    temporals as int64, so values above 2^53 stay distinct)."""
+    columns = [sorted_tbl.column(f).combine_chunks() for f in seq_fields]
+    running = add_mask.copy()
+    for arr in columns:
+        running &= np.asarray(pc.is_valid(arr))
+    for fname, arr in zip(seq_fields, columns):
         t = arr.type
         if pa.types.is_date32(t) or pa.types.is_time32(t):
             # 32-bit temporals -> int64 is not a direct arrow cast
@@ -586,18 +610,24 @@ def _seq_group_winner_index(sorted_tbl: pa.Table, seq_fields: List[str],
             raise ValueError(
                 f"sequence-group field {fname!r} must be numeric or "
                 f"temporal, got {t}")
-        # rank per field on its native dtype (no cross-field upcasting,
-        # which would collapse int64 values above 2^53 into float64)
-        _, field_rank = np.unique(vals, return_inverse=True)
-        mats.append(field_rank.astype(np.int64))
-    # order-preserving combined rank with tie equality
-    stacked = np.stack(mats, axis=1)
-    _, rank = np.unique(stacked, axis=0, return_inverse=True)
-    mask = valid & add_mask
-    masked = np.where(mask, rank.astype(np.int64), -1)
-    mx = np.asarray(_seg_max(masked, seg_id, num_seg))
-    is_max = mask & (masked == mx[seg_id]) & (mx[seg_id] >= 0)
-    return _last_index_where(is_max, seg_id, num_seg)
+        if vals.dtype == object:
+            # unscaled decimals are Python integers, wider than any
+            # word the device holds: their maxima stay on the host
+            best = _host_segment_reduce(
+                np.maximum, np.where(running, vals, vals.min() - 1),
+                seg_id, num_seg)
+        else:
+            best = _seg_max(
+                np.where(running, vals, _np_min_ident(vals.dtype.type)),
+                seg_id, num_seg)
+        best = best[seg_id]
+        at_best = vals == best
+        if vals.dtype.kind == "f":
+            # a NaN is the largest value and equals itself, as a sort
+            # would have it; `maximum` propagates it into `best`
+            at_best |= np.isnan(vals) & np.isnan(best)
+        running &= at_best
+    return _last_index_where(running, seg_id, num_seg)
 
 
 def _collect(col_sorted, mask, seg_id, num_seg, options, name):

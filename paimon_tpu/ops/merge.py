@@ -32,8 +32,11 @@ import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 
-from paimon_tpu.metrics import MERGE_DEVICE_MS, MERGE_PREP_MS
-from paimon_tpu.obs.trace import span
+from paimon_tpu.metrics import (
+    MERGE_DEVICE_MS, MERGE_GATHER_BYTES, MERGE_GATHER_MS, MERGE_PREP_MS,
+    global_registry,
+)
+from paimon_tpu.obs.trace import metrics_enabled, span
 from paimon_tpu.ops.normkey import NormalizedKeyEncoder
 from paimon_tpu.types import RowKind
 
@@ -65,9 +68,19 @@ def _pad_size(n: int) -> int:
 
 def gather(table: pa.Table, indices: np.ndarray) -> pa.Table:
     """`merge.gather`: the rows a merge chose, taken out of the Arrow
-    table in the merge's order."""
-    with span("merge.gather", cat="merge", rows=len(indices)):
-        return table.take(pa.array(indices))
+    table in the merge's order.  The bytes of the buffers taken are
+    counted (`merge` / `gather_bytes`); the span learns them at its end,
+    which the ring records and the profiler's annotation cannot."""
+    with span("merge.gather", cat="merge", group="merge",
+              metric=MERGE_GATHER_MS, rows=len(indices),
+              columns=table.num_columns) as sp:
+        taken = table.take(pa.array(indices))
+        nbytes = taken.nbytes
+        sp.set(bytes=nbytes)
+        if metrics_enabled():
+            global_registry().group("merge").counter(MERGE_GATHER_BYTES) \
+                .inc(nbytes)
+        return taken
 
 
 def prep_span(rows: int):

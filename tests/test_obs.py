@@ -745,11 +745,12 @@ def test_stage_histograms_fill_and_bytes_only_on_the_device_route(
     spans = obs.take_spans()
     after = _registry_totals()
     for group, metric in (("merge", "prep_ms"), ("merge", "agg_ms"),
+                          ("merge", "gather_ms"), ("merge", "gather_bytes"),
                           ("write", "route_ms"), ("io", "decode_ms")):
         total, samples = _delta(before, after, group, metric)
         assert samples > 0 and total > 0, (group, metric)
     # stages no metric reads are spans only: no histogram, no counter
-    for group, metric in (("merge", "gather_ms"), ("merge", "host_ms"),
+    for group, metric in (("merge", "host_ms"),
                           ("merge", "h2d_bytes"), ("compaction", "wait_ms"),
                           ("compaction", "cut_ms"),
                           ("scan", "assemble_ms")):
@@ -771,6 +772,95 @@ def test_stage_histograms_fill_and_bytes_only_on_the_device_route(
     else:
         assert not device and device_ms[1] == 0
         assert "merge.host" in names
+
+
+def _small_partial_update_table(path, rows=3_000, commits=3):
+    """A partial-update table with one sequence group (`ts` over `a`,
+    `b`) and one ungrouped column, `commits` runs of every key."""
+    schema = (Schema.builder().column("id", BigIntType(False))
+              .column("ts", BigIntType()).column("a", BigIntType())
+              .column("b", BigIntType()).column("u", BigIntType())
+              .primary_key("id")
+              .options({"bucket": "1", "write-only": "true",
+                        "merge-engine": "partial-update",
+                        "fields.ts.sequence-group": "a,b"}).build())
+    table = FileStoreTable.create(path, schema)
+    rng = np.random.default_rng(11)
+    for _ in range(commits):
+        wb = table.new_batch_write_builder()
+        with wb.new_write() as w:
+            w.write_arrow(pa.table({
+                "id": pa.array(rng.permutation(rows), pa.int64()),
+                **{c: pa.array(rng.integers(0, 50, rows), pa.int64(),
+                               mask=rng.random(rows) < 0.2)
+                   for c in ("ts", "a", "b", "u")}}))
+            wb.new_commit().commit(w.prepare_commit())
+    return FileStoreTable.load(path)
+
+
+@pytest.mark.parametrize("engine", ["partial-update", "aggregation"])
+def test_agg_select_spans_the_selections_and_not_the_reductions(
+        tmp_path, engine):
+    """`agg.select` sits under `agg.reduce` around the sequence-group
+    resolution and each order-based selection, with the reductions'
+    `agg.device` spans inside it; `sum` / `max` columns open none."""
+    if engine == "partial-update":
+        table = _small_partial_update_table(str(tmp_path / "t"))
+    else:
+        table = _small_agg_table(str(tmp_path / "t"), streamed=False)
+    before = _registry_totals()
+    obs.enable_tracing(max_spans=50_000)
+    assert table.compact(full=True) is not None
+    spans = obs.take_spans()
+    by_id = {s.span_id: s for s in spans}
+    selects = [s for s in spans if s.name == "agg.select"]
+    total, samples = _delta(before, _registry_totals(), "merge",
+                            "select_ms")
+    assert samples == len(selects)
+    if engine == "aggregation":
+        assert not selects and "agg.reduce" in {s.name for s in spans}
+        return
+    assert total > 0
+    assert all(by_id[s.parent_id].name == "agg.reduce" for s in selects)
+    # one resolution of the one group (two members), one selection for `u`
+    assert sorted((s.attrs["groups"], s.attrs["columns"])
+                  for s in selects) == [(0, 1), (1, 2)]
+    assert all(s.attrs["rows"] == 9_000 for s in selects)
+    inside = {}
+    for s in spans:
+        if s.name == "agg.device":
+            inside[by_id[s.parent_id].name] = \
+                inside.get(by_id[s.parent_id].name, 0) + 1
+    # ts maximum + last position for the group, last position for `u`
+    assert inside == {"agg.select": 3}
+
+
+def test_gather_times_and_counts_its_bytes_with_tracing_off():
+    """`merge.gather` names a sink, so it is timed without a listener,
+    and counts the buffer bytes of the table it took."""
+    from paimon_tpu.ops.merge import gather
+    table = pa.table({"k": pa.array(np.arange(1_000), pa.int64()),
+                      "v": pa.array(np.arange(1_000, dtype=np.float64),
+                                    mask=np.arange(1_000) % 3 == 0),
+                      "s": pa.array([str(i) for i in range(1_000)])})
+    indices = np.arange(0, 1_000, 2)[::-1].copy()
+    assert not obs.tracing_enabled()
+    before = _registry_totals()
+    taken = gather(table, indices)
+    after = _registry_totals()
+    assert taken.equals(table.take(pa.array(indices)))
+    assert _delta(before, after, "merge", "gather_ms")[1] == 1
+    assert _delta(before, after, "merge", "gather_bytes")[0] == taken.nbytes
+    obs.enable_tracing()
+    gather(table, indices)
+    (sp,) = [s for s in obs.take_spans() if s.name == "merge.gather"]
+    assert sp.attrs == {"rows": 500, "columns": 3, "bytes": taken.nbytes}
+    obs.disable_tracing()
+    obs.set_metrics_enabled(False)
+    gather(table, indices)
+    # the traced call's bytes, and no more: metrics off counts nothing
+    assert _delta(after, _registry_totals(), "merge",
+                  "gather_bytes")[0] == taken.nbytes
 
 
 def test_streamed_decode_has_the_decode_span(tmp_path):
