@@ -32,7 +32,8 @@ from paimon_tpu.metrics import MERGE_AGG_MS, MERGE_SELECT_MS
 from paimon_tpu.obs.trace import span
 from paimon_tpu.options import CoreOptions, MergeEngine
 from paimon_tpu.ops.merge import (
-    KIND_COL, SEQ_COL, device_sorted_winners, gather, prep_span,
+    KIND_COL, SEQ_COL, device_sorted_winners, gather, gather_values,
+    prep_span,
 )
 from paimon_tpu.ops.normkey import NormalizedKeyEncoder
 from paimon_tpu.schema.table_schema import TableSchema
@@ -398,14 +399,39 @@ def aggregate_sorted_segments(table: pa.Table, order: np.ndarray,
                                           options)
 
 
+def _sorted_validity(column: pa.ChunkedArray,
+                     order: np.ndarray) -> np.ndarray:
+    """The column's validity in the merge's order, a bool a row.  A
+    column without nulls is not read."""
+    if column.null_count == 0:
+        return np.ones(len(order), dtype=bool)
+    return gather_values(np.asarray(pc.is_valid(column)), order)
+
+
+def _sorted_values(column: pa.ChunkedArray, order: np.ndarray,
+                   fill=0) -> np.ndarray:
+    """A fixed-width column's values in the merge's order, nulls as
+    `fill`."""
+    return gather_values(
+        np.asarray(column.combine_chunks().fill_null(fill)), order)
+
+
 def _aggregate_sorted_segments(table, order, seg_id, win_sorted, key_cols,
                                schema, options) -> pa.Table:
+    """No copy of the window in sorted order is made.  A column whose
+    value is one chosen row of each segment (a key, the sequence, the
+    kind, a sequence-group member, an order-based aggregate) is taken
+    from the unsorted `table` at those rows alone; what a selection or
+    a reduction reads over the window (the kinds, a sequence field, a
+    validity, a summed column) is gathered by `order` column by column,
+    when it is reached; a collection aggregate gathers its one column.
+    Every one of these is a `merge.gather`."""
     num_seg = int(seg_id[-1]) + 1 if len(seg_id) else 0
     win_pos = np.flatnonzero(win_sorted)           # last row of each segment
 
-    sorted_tbl = gather(table, order)
-    kinds_sorted = np.asarray(sorted_tbl.column(KIND_COL).combine_chunks()
-                              .cast(pa.int8()))
+    kinds_sorted = gather_values(
+        np.asarray(table.column(KIND_COL).combine_chunks().cast(pa.int8())),
+        order)
     retract = (kinds_sorted == RowKind.DELETE) | \
               (kinds_sorted == RowKind.UPDATE_BEFORE)
 
@@ -413,10 +439,25 @@ def _aggregate_sorted_segments(table, order, seg_id, win_sorted, key_cols,
     remove_on_delete = options.get(
         CoreOptions.PARTIAL_UPDATE_REMOVE_RECORD_ON_DELETE)
 
-    out_cols: Dict[str, pa.Array] = {}
-    # keys + sequence + kind from the segment winner row
-    for name in list(key_cols) + [SEQ_COL, KIND_COL]:
-        out_cols[name] = sorted_tbl.column(name).take(pa.array(win_pos))
+    out_cols: Dict[str, pa.ChunkedArray] = {}
+
+    def take_winners(columns: List[str], idx: np.ndarray) -> None:
+        """`out_cols[name]`, for each of `columns`: the column's value
+        at sorted position `idx` of each segment, null where `idx` < 0
+        (a null index takes a null)."""
+        missing = idx < 0
+        rows = pa.array(order[idx], mask=missing if missing.any() else None)
+        taken = gather(table.select(columns), rows)
+        out_cols.update(zip(columns, taken.columns))
+
+    def sorted_column(name: str) -> pa.ChunkedArray:
+        return gather(table.select([name]), order).column(name)
+
+    # the output's columns, in order: keys, sequence and kind, then the
+    # schema's; those with no aggregator come from the segment winner row
+    names = list(dict.fromkeys(list(key_cols) + [SEQ_COL, KIND_COL]
+                               + [f.name for f in schema.fields]))
+    take_winners([name for name in names if name not in aggs], win_pos)
 
     add_mask = ~retract
 
@@ -424,53 +465,53 @@ def _aggregate_sorted_segments(table, order, seg_id, win_sorted, key_cols,
     # their values from the row with the LARGEST group-sequence value
     # instead of the global sequence order (reference
     # PartialUpdateMergeFunction sequence groups; ties -> later row wins)
-    seq_group_idx: Dict[str, np.ndarray] = {}
+    group_of: Dict[str, int] = {}      # member or sequence column -> group
     if options.merge_engine == MergeEngine.PARTIAL_UPDATE:
-        groups = sequence_groups(schema, options)
+        groups = [([s.strip() for s in gkey.split(",")], cols)
+                  for gkey, cols in sequence_groups(schema, options).items()]
+        for g, (seq_fields, cols) in enumerate(groups):
+            for colname in list(cols) + seq_fields:
+                if options.options.get_or(
+                        f"fields.{colname}.aggregate-function",
+                        None) is not None:
+                    raise NotImplementedError(
+                        f"aggregate-function on sequence-group member "
+                        f"{colname!r} (reference: aggregation within "
+                        f"sequence groups) is not supported yet")
+                group_of[colname] = g
+        views = [[_sorted_sequence_field(table, s, order)
+                  for s in seq_fields] for seq_fields, _ in groups]
         with _select_span(len(order), len(groups),
-                          sum(len(cols) for cols in groups.values())):
-            for gkey, cols in groups.items():
-                seq_fields = [s.strip() for s in gkey.split(",")]
-                idx = _seq_group_winner_index(sorted_tbl, seq_fields,
-                                              seg_id, num_seg, add_mask)
-                for colname in dict.fromkeys(list(cols) + seq_fields):
-                    if options.options.get_or(
-                            f"fields.{colname}.aggregate-function",
-                            None) is not None:
-                        raise NotImplementedError(
-                            f"aggregate-function on sequence-group member "
-                            f"{colname!r} (reference: aggregation within "
-                            f"sequence groups) is not supported yet")
-                    seq_group_idx[colname] = idx
+                          sum(len(cols) for _, cols in groups)):
+            group_idx = [_seq_group_winner_index(fields, seg_id, num_seg,
+                                                 add_mask)
+                         for fields in views]
+        del views
+        for g, idx in enumerate(group_idx):
+            members = [name for name in names
+                       if name in aggs and group_of.get(name) == g]
+            if members:
+                take_winners(members, idx)
 
     for f in schema.fields:
         name = f.name
-        col_sorted = sorted_tbl.column(name)
-        if name not in aggs:   # key column: winner value
-            out_cols[name] = col_sorted.take(pa.array(win_pos))
-            continue
-        if name in seq_group_idx:
-            idx = seq_group_idx[name]
-            taken = col_sorted.take(pa.array(np.where(idx < 0, 0, idx)))
-            nulls = pa.array(idx < 0)
-            out_cols[name] = pc.if_else(
-                nulls, pa.nulls(num_seg, taken.type),
-                taken.combine_chunks())
+        if name not in aggs or name in group_of:
             continue
         func = aggs[name]
-        valid = np.asarray(pc.is_valid(col_sorted.combine_chunks()))
-        if func in _NUMERIC_DEVICE_AGGS and \
-                col_sorted.type in _JAX_NUMERIC:
-            np_dtype = _JAX_NUMERIC[col_sorted.type]
-            vals = np.asarray(col_sorted.combine_chunks()
-                              .fill_null(0)).astype(np_dtype)
-            contrib_mask = valid & add_mask
+        column = table.column(name)
+        ctype = column.type
+        valid = _sorted_validity(column, order)
+        contrib_mask = valid & add_mask
+        if func in _NUMERIC_DEVICE_AGGS and ctype in _JAX_NUMERIC:
+            np_dtype = _JAX_NUMERIC[ctype]
             if func == "count":
                 dev = _seg_sum(contrib_mask.astype(np.int64), seg_id,
                                num_seg)
                 result = np.asarray(dev)
                 out_cols[name] = pa.array(result, pa.int64())
                 continue
+            vals = _sorted_values(column, order).astype(np_dtype,
+                                                       copy=False)
             if func == "sum":
                 ignore_retract = options.options.get_or(
                     f"fields.{name}.ignore-retract", "false") == "true"
@@ -488,81 +529,69 @@ def _aggregate_sorted_segments(table, order, seg_id, win_sorted, key_cols,
                 result = np.asarray(dev)
                 any_valid = np.asarray(_seg_max(
                     contributed.astype(np.int32), seg_id, num_seg)) > 0
-                out_cols[name] = _masked_numeric(result, any_valid,
-                                                 col_sorted.type)
+                out_cols[name] = _masked_numeric(result, any_valid, ctype)
                 continue
             if func in ("max", "min", "product"):
                 ident = {"max": _np_min_ident(np_dtype),
                          "min": _np_max_ident(np_dtype),
                          "product": np_dtype(1)}[func]
-                masked = np.where(valid & add_mask, vals, ident)
+                masked = np.where(contrib_mask, vals, ident)
                 dev = {"max": _seg_max, "min": _seg_min,
                        "product": _seg_prod}[func](masked, seg_id,
                                                    num_seg)
                 result = np.asarray(dev)
                 any_valid = np.asarray(_seg_max(
-                    (valid & add_mask).astype(np.int32), seg_id,
-                    num_seg)) > 0
-                out_cols[name] = _masked_numeric(result, any_valid,
-                                                 col_sorted.type)
+                    contrib_mask.astype(np.int32), seg_id, num_seg)) > 0
+                out_cols[name] = _masked_numeric(result, any_valid, ctype)
                 continue
         # order-based aggregates: pick an index per segment, host gather
         if func in _INDEX_SELECTIONS:
             pick, non_null = _INDEX_SELECTIONS[func]
             with _select_span(len(order), 0, 1):
-                idx = pick(valid & add_mask if non_null else add_mask,
+                idx = pick(contrib_mask if non_null else add_mask,
                            seg_id, num_seg)
+            take_winners([name], idx)
         elif func == "listagg":
-            out_cols[name] = _listagg(col_sorted, valid & add_mask, seg_id,
-                                      num_seg, options, name)
-            continue
+            out_cols[name] = _listagg(sorted_column(name), contrib_mask,
+                                      seg_id, num_seg, options, name)
         elif func == "collect":
-            if not pa.types.is_list(col_sorted.type) and \
-                    not pa.types.is_large_list(col_sorted.type):
+            if not pa.types.is_list(ctype) and \
+                    not pa.types.is_large_list(ctype):
                 raise ValueError(
                     f"collect aggregate requires field {name!r} to be "
                     f"declared ARRAY<...>, got {f.type} (reference "
                     f"FieldCollectAgg)")
-            out_cols[name] = _collect(col_sorted, valid & add_mask, seg_id,
-                                      num_seg, options, name)
-            continue
+            out_cols[name] = _collect(sorted_column(name), contrib_mask,
+                                      seg_id, num_seg, options, name)
         elif func == "merge_map":
-            out_cols[name] = _merge_map(col_sorted, valid & add_mask,
-                                        seg_id, num_seg)
-            continue
+            out_cols[name] = _merge_map(sorted_column(name),
+                                        contrib_mask, seg_id, num_seg)
         elif func in ("rbm32", "rbm64"):
-            out_cols[name] = _rbm_agg(col_sorted, valid & add_mask,
+            out_cols[name] = _rbm_agg(sorted_column(name), contrib_mask,
                                       seg_id, num_seg, func, name)
-            continue
         elif func in ("hll_sketch", "theta_sketch"):
-            out_cols[name] = _sketch_agg(col_sorted, valid & add_mask,
-                                         seg_id, num_seg, func, name)
-            continue
+            out_cols[name] = _sketch_agg(sorted_column(name),
+                                         contrib_mask, seg_id, num_seg,
+                                         func, name)
         elif func == "nested_update":
-            out_cols[name] = _nested_update(col_sorted, valid & add_mask,
-                                            seg_id, num_seg, options,
-                                            name, f)
-            continue
+            out_cols[name] = _nested_update(sorted_column(name),
+                                            contrib_mask, seg_id,
+                                            num_seg, options, name, f)
         elif func in ("bool_and", "bool_or"):
-            vals = np.asarray(col_sorted.combine_chunks()
-                              .fill_null(func == "bool_and"))
-            masked = vals if func == "bool_or" else vals | ~(valid & add_mask)
+            vals = _sorted_values(column, order, fill=False)
             if func == "bool_or":
-                masked = vals & (valid & add_mask)
+                masked = vals & contrib_mask
+            else:
+                masked = vals | ~contrib_mask
             dev = (_seg_max if func == "bool_or" else _seg_min)(
                 masked.astype(np.int32), seg_id, num_seg)
             out_cols[name] = pa.array(np.asarray(dev).astype(bool),
                                       pa.bool_())
-            continue
         else:
             raise ValueError(f"Unknown aggregate function {func!r} "
                              f"for field {name}")
-        taken = col_sorted.take(pa.array(np.where(idx < 0, 0, idx)))
-        nulls = pa.array(idx < 0)
-        out_cols[name] = pc.if_else(nulls, pa.nulls(num_seg, taken.type),
-                                    taken.combine_chunks())
 
-    out = pa.table(out_cols)
+    out = pa.table({name: out_cols[name] for name in names})
     # delete handling: drop segments whose winner is a retract
     winner_kinds = np.asarray(out.column(KIND_COL).combine_chunks()
                               .cast(pa.int8()))
@@ -575,8 +604,37 @@ def _aggregate_sorted_segments(table, order, seg_id, win_sorted, key_cols,
     return out
 
 
-def _seq_group_winner_index(sorted_tbl: pa.Table, seq_fields: List[str],
-                            seg_id: np.ndarray, num_seg: int,
+def _sequence_values(fname: str, arr: pa.Array) -> np.ndarray:
+    """A sequence field's values, nulls as 0, each type as the values
+    its order is compared on: integers and temporals as int64 (so
+    values above 2^53 stay distinct), floats as float64, decimals as
+    their unscaled Python integers."""
+    t = arr.type
+    if pa.types.is_date32(t) or pa.types.is_time32(t):
+        # 32-bit temporals -> int64 is not a direct arrow cast
+        return np.asarray(arr.cast(pa.int32()).fill_null(0)) \
+            .astype(np.int64)
+    if pa.types.is_integer(t) or pa.types.is_temporal(t):
+        return np.asarray(arr.cast(pa.int64()).fill_null(0))
+    if pa.types.is_floating(t):
+        return np.asarray(arr.cast(pa.float64()).fill_null(0))
+    if pa.types.is_decimal(t):
+        return np.array([0 if v is None else int(v.scaleb(t.scale))
+                         for v in arr.to_pylist()], dtype=object)
+    raise ValueError(f"sequence-group field {fname!r} must be numeric or "
+                     f"temporal, got {t}")
+
+
+def _sorted_sequence_field(table: pa.Table, fname: str, order: np.ndarray):
+    """(values, validity) of one sequence field in the merge's order,
+    as `_seq_group_winner_index` reads them."""
+    column = table.column(fname)
+    return (gather_values(_sequence_values(fname, column.combine_chunks()),
+                          order),
+            _sorted_validity(column, order))
+
+
+def _seq_group_winner_index(fields, seg_id: np.ndarray, num_seg: int,
                             add_mask: np.ndarray) -> np.ndarray:
     """Per segment: position (into sorted order) of the row with the
     largest non-null group-sequence tuple, the later row of equals; -1
@@ -584,32 +642,16 @@ def _seq_group_winner_index(sorted_tbl: pa.Table, seq_fields: List[str],
     the group (reference PartialUpdateMergeFunction: null sequence ->
     skip).
 
-    The lexicographic maximum, field by field in declaration order: the
-    segment maximum of the field among the rows still in the running,
-    which then keeps the rows that equal it.  Nothing is sorted or
-    ranked; each field is compared on its native values (integers and
-    temporals as int64, so values above 2^53 stay distinct)."""
-    columns = [sorted_tbl.column(f).combine_chunks() for f in seq_fields]
+    `fields`: a (values, validity) pair of numpy arrays in sorted order
+    for each sequence field, in declaration order (`_sequence_values`).
+    The lexicographic maximum, field by field: the segment maximum of
+    the field among the rows still in the running, which then keeps the
+    rows that equal it.  Nothing is sorted or ranked; each field is
+    compared on its native values."""
     running = add_mask.copy()
-    for arr in columns:
-        running &= np.asarray(pc.is_valid(arr))
-    for fname, arr in zip(seq_fields, columns):
-        t = arr.type
-        if pa.types.is_date32(t) or pa.types.is_time32(t):
-            # 32-bit temporals -> int64 is not a direct arrow cast
-            vals = np.asarray(arr.cast(pa.int32()).fill_null(0)) \
-                .astype(np.int64)
-        elif pa.types.is_integer(t) or pa.types.is_temporal(t):
-            vals = np.asarray(arr.cast(pa.int64()).fill_null(0))
-        elif pa.types.is_floating(t):
-            vals = np.asarray(arr.cast(pa.float64()).fill_null(0))
-        elif pa.types.is_decimal(t):
-            vals = np.array([0 if v is None else int(v.scaleb(t.scale))
-                             for v in arr.to_pylist()], dtype=object)
-        else:
-            raise ValueError(
-                f"sequence-group field {fname!r} must be numeric or "
-                f"temporal, got {t}")
+    for _, valid in fields:
+        running &= valid
+    for vals, _ in fields:
         if vals.dtype == object:
             # unscaled decimals are Python integers, wider than any
             # word the device holds: their maxima stay on the host

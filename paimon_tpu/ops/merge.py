@@ -66,21 +66,36 @@ def _pad_size(n: int) -> int:
     return 1 << (n - 1).bit_length()
 
 
-def gather(table: pa.Table, indices: np.ndarray) -> pa.Table:
-    """`merge.gather`: the rows a merge chose, taken out of the Arrow
-    table in the merge's order.  The bytes of the buffers taken are
-    counted (`merge` / `gather_bytes`); the span learns them at its end,
-    which the ring records and the profiler's annotation cannot."""
+def _gathered(rows: int, columns: int, take):
+    """`merge.gather`: rows taken out of a merge's input in an order the
+    merge chose — `take()`, whose result's bytes are counted (`merge` /
+    `gather_bytes`); the span learns them at its end, which the ring
+    records and the profiler's annotation cannot."""
     with span("merge.gather", cat="merge", group="merge",
-              metric=MERGE_GATHER_MS, rows=len(indices),
-              columns=table.num_columns) as sp:
-        taken = table.take(pa.array(indices))
-        nbytes = taken.nbytes
-        sp.set(bytes=nbytes)
+              metric=MERGE_GATHER_MS, rows=rows, columns=columns) as sp:
+        taken = take()
+        sp.set(bytes=taken.nbytes)
         if metrics_enabled():
             global_registry().group("merge").counter(MERGE_GATHER_BYTES) \
-                .inc(nbytes)
+                .inc(taken.nbytes)
         return taken
+
+
+def gather(table: pa.Table, indices) -> pa.Table:
+    """`merge.gather` of an Arrow table: its rows at `indices`, a numpy
+    array of positions or an Arrow array of them, whose null entries give
+    null rows.  The bytes counted are the buffers of the table taken."""
+    if not isinstance(indices, pa.Array):
+        indices = pa.array(indices)
+    return _gathered(len(indices), table.num_columns,
+                     lambda: table.take(indices))
+
+
+def gather_values(values: np.ndarray, indices: np.ndarray) -> np.ndarray:
+    """`merge.gather` of one column held as a numpy array (a column's
+    values or its validity): the view of it that a reduction over the
+    merge's order reads, where no Arrow column is wanted."""
+    return _gathered(len(indices), 1, lambda: values[indices])
 
 
 def prep_span(rows: int):

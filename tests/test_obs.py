@@ -863,6 +863,78 @@ def test_gather_times_and_counts_its_bytes_with_tracing_off():
                   "gather_bytes")[0] == taken.nbytes
 
 
+@pytest.mark.parametrize("engine", ["partial-update", "aggregation"])
+def test_the_epilogue_gathers_the_winners_and_not_the_window(engine,
+                                                            monkeypatch):
+    """`merge` / `gather_bytes` says how far the epilogue engages: a
+    partial-update merge of S snapshots takes a fifth of the window's
+    rows plus a few one-column views, an aggregation no more than the
+    window; every take is a `merge.gather` leaf under `agg.reduce`, and
+    a segment with no qualifying row is null through a null index."""
+    from paimon_tpu.ops import agg
+    from paimon_tpu.ops.merge import KIND_COL, SEQ_COL
+    from paimon_tpu.options import CoreOptions
+    from paimon_tpu.schema.table_schema import TableSchema
+    snapshots, keys = 5, 2_000
+    members = [f"m{i:02d}" for i in range(12)]
+    if engine == "partial-update":
+        options = {"fields.ts.sequence-group": ",".join(members)}
+    else:
+        options = {f"fields.{c}.aggregate-function": "sum" for c in members}
+        options["fields.ts.aggregate-function"] = "max"
+    builder = Schema.builder().column("id", BigIntType(False))
+    for name in ["ts"] + members + ["u"]:
+        builder = builder.column(name, BigIntType())
+    schema = TableSchema.from_schema(0, builder.primary_key("id").options(
+        {"bucket": "1", "merge-engine": engine, **options}).build())
+    rng = np.random.default_rng(5)
+    ids = pa.array(np.arange(keys), pa.int64())
+    dead = np.arange(keys) == 7         # a key whose `ts` is never set
+    runs = [pa.table({
+        "_KEY_id": ids,
+        SEQ_COL: pa.array(np.arange(keys) + s * keys, pa.int64()),
+        KIND_COL: pa.array(np.zeros(keys), pa.int8()),
+        "id": ids,
+        **{c: pa.array(rng.integers(0, 1 << 30, keys), pa.int64(),
+                       mask=(rng.random(keys) < 0.1) | (dead & (c == "ts")))
+           for c in ["ts"] + members + ["u"]}}) for s in range(snapshots)]
+    window_bytes = pa.concat_tables(runs).nbytes
+    taken_at = []
+    real_gather = agg.gather
+    monkeypatch.setattr(agg, "gather", lambda table, indices: (
+        taken_at.append(indices), real_gather(table, indices))[1])
+    before = _registry_totals()
+    obs.enable_tracing(max_spans=10_000)
+    out = agg.merge_runs_agg(runs, ["_KEY_id"], schema,
+                             CoreOptions(schema.options))
+    spans = obs.take_spans()
+    taken = _delta(before, _registry_totals(), "merge", "gather_bytes")[0]
+    assert out.num_rows == keys
+    if engine == "partial-update":
+        assert 0 < taken <= (1 / snapshots + 0.15) * window_bytes
+        # key 7: no row qualifies, so the group's take has one null
+        # index and its columns are null there, `ts` among them
+        assert [i.null_count for i in taken_at] == [0, 1, 0]
+        assert out.slice(7, 1).select(["ts"] + members).to_pylist() == \
+            [dict.fromkeys(["ts"] + members)]
+        assert out.slice(7, 1).column("u").null_count == 0
+    else:
+        assert 0 < taken <= window_bytes
+    by_id = {s.span_id: s for s in spans}
+    gathers = [s for s in spans if s.name == "merge.gather"]
+    assert sum(s.attrs["bytes"] for s in gathers) == taken
+    assert all(by_id[s.parent_id].name == "agg.reduce" for s in gathers)
+    assert not {s.parent_id for s in spans} & {s.span_id for s in gathers}
+    winners = sorted(s.attrs["columns"] for s in gathers
+                     if s.attrs["rows"] == keys)
+    views = [s for s in gathers if s.attrs["rows"] == snapshots * keys]
+    assert len(winners) + len(views) == len(gathers)
+    assert all(s.attrs["columns"] == 1 for s in views)
+    # keys + sequence + kind; the group (ts + members); `u`
+    assert winners == ([1, 4, 13] if engine == "partial-update"
+                       else [1, 4])
+
+
 def test_streamed_decode_has_the_decode_span(tmp_path):
     """`read_batches` — the compaction's streamed decode — records the
     `decode` span per batch, the name `read`'s span has."""

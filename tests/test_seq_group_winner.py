@@ -46,6 +46,15 @@ def _segments(rng, n):
     return seg_id.astype(np.int64), int(seg_id[-1]) + 1
 
 
+def _winner_index(tbl, names, seg_id, num_seg, add_mask):
+    """The resolution over the table's rows as they stand (they are in
+    sorted order already): each field as its (values, validity)."""
+    identity = np.arange(tbl.num_rows)
+    return agg._seq_group_winner_index(
+        [agg._sorted_sequence_field(tbl, name, identity) for name in names],
+        seg_id, num_seg, add_mask)
+
+
 def _replay(columns, seg_id, num_seg, add_mask):
     """The reference's loop: rows in order, a null in any field or a
     retract skips the row, `>=` keeps the later of equals."""
@@ -95,7 +104,7 @@ def test_winner_index_equals_the_replay(kinds, null_field):
     add_mask = rng.random(len(seg_id)) < 0.8         # retracts masked
     # segments with no row in the running: all masked, all null
     add_mask[np.isin(seg_id, [0, 3, num_seg - 1])] = False
-    got = agg._seq_group_winner_index(tbl, names, seg_id, num_seg, add_mask)
+    got = _winner_index(tbl, names, seg_id, num_seg, add_mask)
     want = _replay(tbl.columns, seg_id, num_seg, add_mask)
     assert got.dtype == np.int64 and got.tolist() == want.tolist()
     assert got[0] == got[3] == got[-1] == -1
@@ -120,8 +129,8 @@ def test_winner_index_sorts_nothing(kinds, monkeypatch):
         monkeypatch.setattr(
             np, name, lambda *a, _n=name, _r=real, **k:
             (calls.append(_n), _r(*a, **k))[1])
-    got = agg._seq_group_winner_index(tbl, names, seg_id, num_seg,
-                                      np.ones(len(seg_id), dtype=bool))
+    got = _winner_index(tbl, names, seg_id, num_seg,
+                        np.ones(len(seg_id), dtype=bool))
     assert calls == []
     monkeypatch.undo()
     assert got.tolist() == _replay(tbl.columns, seg_id, num_seg,
@@ -133,6 +142,6 @@ def test_values_above_2_53_stay_distinct():
     resolution: the largest wins, not the last."""
     tbl = pa.table({"s": pa.array([ABOVE_2_53 + 1, ABOVE_2_53 + 2,
                                    ABOVE_2_53], pa.int64())})
-    got = agg._seq_group_winner_index(
-        tbl, ["s"], np.zeros(3, np.int64), 1, np.ones(3, dtype=bool))
+    got = _winner_index(tbl, ["s"], np.zeros(3, np.int64), 1,
+                        np.ones(3, dtype=bool))
     assert got.tolist() == [1]
