@@ -12,7 +12,7 @@ with a plain numpy reference written here, independent of `paimon_tpu`.
 
 * Phase A: the default route at a real size — no pin, no non-default
   option — on the bench's own table shape (bench.py `build_table`).
-* Phase B: every device program, pinned, at a smaller size: the three
+* Phase B: every device program, pinned, at a smaller size: the two
   device-sort return formats, the mesh window kernel and the device
   decode plane, on an aggregation and a deduplicate table.
 
@@ -370,24 +370,19 @@ def _phase_a(np, rec, tmp: str, rows: int, seed: int):
 # phase B: every device program, pinned
 # ---------------------------------------------------------------------------
 
-# name -> (engine, pins, dynamic table options, mesh?)
 _DEVICE = {"PAIMON_FORCE_DEVICE_SORT": "1"}
-_BITMASK = {"PAIMON_FORCE_DEVICE_SORT": "1",
-            "PAIMON_FORCE_BITMASK_SORT": "1"}
 _DECODE = {"read.device-decode": "true"}
-# Variants that share one set of pins run side by side: on the chip a
-# variadic lax.sort takes minutes to compile and each return format is
-# its own program, so four in a row would not fit the time limit while
-# four at once cost little more than one.
-_GROUPS = [
-    (_BITMASK, [("dedup.bitmask", "deduplicate", {}, False),
-                ("agg.full-perm", "aggregation", {}, False),
-                ("agg.mesh", "aggregation", {}, True)]),
-    (_DEVICE, [("dedup.packed", "deduplicate", {}, False),
-               ("dedup.mesh", "deduplicate", {}, True),
-               ("dedup.device-decode", "deduplicate", _DECODE, False),
-               ("agg.device-decode", "aggregation", _DECODE, False)]),
-]
+# (name, engine, dynamic table options, mesh?).  The variants run side
+# by side under one set of pins: on the chip a variadic lax.sort takes
+# minutes to compile and each return format is its own program, so six
+# in a row would not fit the time limit while six at once cost little
+# more than one.
+_VARIANTS = [("dedup.packed", "deduplicate", {}, False),
+             ("agg.full-perm", "aggregation", {}, False),
+             ("dedup.mesh", "deduplicate", {}, True),
+             ("agg.mesh", "aggregation", {}, True),
+             ("dedup.device-decode", "deduplicate", _DECODE, False),
+             ("agg.device-decode", "aggregation", _DECODE, False)]
 
 
 def _run_variant(np, jax, name, path, want, options, mesh):
@@ -424,13 +419,12 @@ def _run_variant(np, jax, name, path, want, options, mesh):
     return out
 
 
-def _phase_b(np, jax, rec, tmp: str, rows: int, seed: int, rehearsal: bool):
+def _phase_b(np, jax, rec, tmp: str, rows: int, seed: int):
     from paimon_tpu.metrics import (COMPACTION_BUCKET_FALLBACKS,
                                     COMPACTION_BUCKET_RETRIES,
                                     SCAN_DEVICE_DECODE_FALLBACKS,
                                     SCAN_DEVICE_DECODE_FILES)
     from paimon_tpu.ops import merge as M
-    from paimon_tpu.ops import pallas_kernels as PK
     from paimon_tpu.parallel import mesh_engine
 
     n_dev = jax.local_device_count()
@@ -451,69 +445,56 @@ def _phase_b(np, jax, rec, tmp: str, rows: int, seed: int, rehearsal: bool):
                         run_list)
     del run_list, cols
 
-    if not PK.pallas_enabled():
-        raise SmokeFailure("Pallas kernel is switched off")
-    variants = []
-    for pins, group in _GROUPS:
-        paths = {}
-        for name, engine, _, _ in group:
-            paths[name] = os.path.join(tmp, "b_" + name)
-            shutil.copytree(base[engine], paths[name])
-        decode0 = (_counter("scan", SCAN_DEVICE_DECODE_FILES),
-                   _counter("scan", SCAN_DEVICE_DECODE_FALLBACKS))
-        ladder0 = (_counter("compaction", COMPACTION_BUCKET_RETRIES),
-                   _counter("compaction", COMPACTION_BUCKET_FALLBACKS))
-        label = "B[%s]" % ",".join(n for n, _, _, _ in group)
-        with _pins(**pins), rec.step(label), \
-                ThreadPoolExecutor(max_workers=len(group)) as pool:
-            futures = [pool.submit(_run_variant, np, jax, name,
-                                   paths[name], want[engine], options, mesh)
-                       for name, engine, options, mesh in group]
-            variants += [f.result() for f in futures]
-        step = rec.last()
-        step["pins"] = pins
-        if step["merge_paths"]["device"] <= 0 \
-                or step["merge_paths"]["host"] \
-                or step["merge_paths"]["ovc"]:
-            raise SmokeFailure(f"{label}: pinned to the device, merges "
-                               f"went {step['merge_paths']}")
-        want_routes = ["bitmask", "device"] if pins is _BITMASK \
-            else ["device"]
-        if step["routes"] != want_routes:
-            raise SmokeFailure(f"{label}: routes {step['routes']}, "
-                               f"expected {want_routes}")
-        files = _counter("scan", SCAN_DEVICE_DECODE_FILES) - decode0[0]
-        fallbacks = _counter("scan", SCAN_DEVICE_DECODE_FALLBACKS) \
-            - decode0[1]
-        step["device_decode_files"] = files
-        step["device_decode_fallbacks"] = fallbacks
-        decoding = any(o for _, _, o, _ in group)
-        if fallbacks or (decoding and not files):
-            raise SmokeFailure(f"{label}: device_decode_files={files} "
-                               f"device_decode_fallbacks={fallbacks}")
-        ladder = (_counter("compaction", COMPACTION_BUCKET_RETRIES)
-                  - ladder0[0],
-                  _counter("compaction", COMPACTION_BUCKET_FALLBACKS)
-                  - ladder0[1])
-        if any(ladder):
-            raise SmokeFailure(f"{label}: compaction retries/fallbacks "
-                               f"{ladder}")
+    paths = {}
+    for name, engine, _, _ in _VARIANTS:
+        paths[name] = os.path.join(tmp, "b_" + name)
+        shutil.copytree(base[engine], paths[name])
+    decode0 = (_counter("scan", SCAN_DEVICE_DECODE_FILES),
+               _counter("scan", SCAN_DEVICE_DECODE_FALLBACKS))
+    ladder0 = (_counter("compaction", COMPACTION_BUCKET_RETRIES),
+               _counter("compaction", COMPACTION_BUCKET_FALLBACKS))
+    label = "B[%s]" % ",".join(n for n, _, _, _ in _VARIANTS)
+    with _pins(**_DEVICE), rec.step(label), \
+            ThreadPoolExecutor(max_workers=len(_VARIANTS)) as pool:
+        futures = [pool.submit(_run_variant, np, jax, name,
+                               paths[name], want[engine], options, mesh)
+                   for name, engine, options, mesh in _VARIANTS]
+        variants = [f.result() for f in futures]
+    step = rec.last()
+    step["pins"] = _DEVICE
+    if step["merge_paths"]["device"] <= 0 \
+            or step["merge_paths"]["host"] \
+            or step["merge_paths"]["ovc"]:
+        raise SmokeFailure(f"{label}: pinned to the device, merges "
+                           f"went {step['merge_paths']}")
+    if step["routes"] != ["device"]:
+        raise SmokeFailure(f"{label}: routes {step['routes']}, "
+                           f"expected ['device']")
+    files = _counter("scan", SCAN_DEVICE_DECODE_FILES) - decode0[0]
+    fallbacks = _counter("scan", SCAN_DEVICE_DECODE_FALLBACKS) \
+        - decode0[1]
+    step["device_decode_files"] = files
+    step["device_decode_fallbacks"] = fallbacks
+    if fallbacks or not files:
+        raise SmokeFailure(f"{label}: device_decode_files={files} "
+                           f"device_decode_fallbacks={fallbacks}")
+    ladder = (_counter("compaction", COMPACTION_BUCKET_RETRIES)
+              - ladder0[0],
+              _counter("compaction", COMPACTION_BUCKET_FALLBACKS)
+              - ladder0[1])
+    if any(ladder):
+        raise SmokeFailure(f"{label}: compaction retries/fallbacks "
+                           f"{ladder}")
 
-    # which device programs were built, and how the Pallas kernel ran
+    # which device programs were built
     programs = {
         "sort.packed": M._merge_fn_packed.cache_info().currsize,
-        "sort.bitmask": M._merge_fn_bitmask.cache_info().currsize,
         "sort.full-perm": M._merge_fn.cache_info().currsize,
         "mesh.window": len(mesh_engine._KERNEL_CACHE),
-        "pallas.eq_next": PK._eq_next_fn.cache_info().currsize,
     }
     missing = [k for k, v in programs.items() if not v]
     if missing:
         raise SmokeFailure(f"B: device programs never built: {missing}")
-    pallas_mode = "interpret" if jax.default_backend() != "tpu" \
-        else "compiled"
-    if pallas_mode != "compiled" and not rehearsal:
-        raise SmokeFailure("Pallas kernel ran in interpret mode")
     mesh_devices = sorted(
         {d.id for k in mesh_engine._KERNEL_CACHE.values()
          for d in k.sharding.device_set})
@@ -523,7 +504,7 @@ def _phase_b(np, jax, rec, tmp: str, rows: int, seed: int, rehearsal: bool):
     return {"rows": rows, "runs": RUNS_B, "buckets": buckets,
             "keys": len(want["deduplicate"]["id"]),
             "variants": variants, "programs_built": programs,
-            "pallas": pallas_mode, "mesh_devices": mesh_devices}
+            "mesh_devices": mesh_devices}
 
 
 # ---------------------------------------------------------------------------
@@ -588,7 +569,7 @@ def main(argv=None) -> int:
             snap = meter.snapshot()
             report["phase_b"] = _phase_b(np, jax, rec, tmp,
                                          max(args.rows // 4, RUNS_B),
-                                         args.seed, rehearsal)
+                                         args.seed)
             report["phase_b"].update(meter.since(snap))
     except Exception:                       # noqa: BLE001
         # the boundary of the script: report, print NO result, fail

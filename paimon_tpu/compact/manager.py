@@ -383,8 +383,7 @@ class MergeTreeCompactManager:
             with span("compact.window", cat="compaction",
                       rows=sum(t.num_rows for t in tables)):
                 return self._merge_tables(tables, drop_delete,
-                                          encoded=encoded,
-                                          overlapped=True)
+                                          encoded=encoded)
 
         # two merge workers: the OVC/native merges and the numpy
         # epilogues release the GIL, so adjacent windows genuinely
@@ -582,14 +581,11 @@ class MergeTreeCompactManager:
 
     def _merge_tables(self, run_tables: List[pa.Table],
                       drop_deletes: bool,
-                      encoded=None, overlapped: bool = False) -> pa.Table:
+                      encoded=None) -> pa.Table:
         """Merge run-ordered tables under the table's merge engine —
         the single dispatch shared by the one-shot and streamed paths.
         `encoded`: optional pre-computed (lanes, truncated) per table
-        (the streamed path encodes once for the window cut).
-        `overlapped`: the caller runs merges on a pipeline worker, so
-        device transfer/sort time hides under decode+cut of the next
-        window (unlocks the bitmask device path's cost model)."""
+        (the streamed path encodes once for the window cut)."""
         engine = self.options.merge_engine
         seq_fields = self.options.sequence_field or None
         if engine in (MergeEngine.DEDUPLICATE, MergeEngine.FIRST_ROW):
@@ -601,8 +597,7 @@ class MergeTreeCompactManager:
                 key_encoder=self.key_encoder,
                 seq_fields=seq_fields,
                 seq_desc=self.options.sequence_field_descending,
-                encoded=encoded,
-                overlapped=overlapped)
+                encoded=encoded)
             return self._record_level_expire(res.take())
         from paimon_tpu.ops.agg import merge_runs_agg
         merged = merge_runs_agg(run_tables, self.key_cols, self.schema,

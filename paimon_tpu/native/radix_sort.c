@@ -107,7 +107,7 @@ int merge_winners_u64(const uint64_t *keys, const int64_t *seq,
 
 /* Segmented winners in one pass over radix-sorted keys: for each run of
  * equal keys pick the entry with max (seq, perm) [keep_last=1] or min
- * (seq, perm) [keep_last=0], writing a winner bitmask.  Fuses what the
+ * (seq, perm) [keep_last=0], writing a winner mask.  Fuses what the
  * Python path does with reduceat + three temporaries.
  *
  * sorted_keys/sorted_perm: the radix output order; seq indexed by perm.

@@ -18,10 +18,25 @@ and merge functions with one data-parallel plan:
 
 Static shapes: inputs are padded to the next power of two; padding rows
 carry validity=1 which sorts after all real rows and never joins a segment.
+
+The router (`device_sorted_winners`) sends each merge one of two ways:
+to the host (offset-value coded merge of sorted runs, C radix sort of a
+packed key, or the general lexsort) or to the device, from one cost
+model over the measured link (`_device_path_pays`).  The device returns
+one of two formats: one packed word a row (perm | winner << 31) where
+the caller promised `winners_only`, else the full perm / winner / prev
+triple, with offset-value codes riding the sort where the input is
+sorted runs.  One winner-select body (`segmented_merge_body`) serves
+both, the mesh engines and the fused decode.  Two environment pins take
+the decision away from the model: PAIMON_FORCE_HOST_SORT (the
+benchmark's table build; the tests' reference) and
+PAIMON_FORCE_DEVICE_SORT (tests of the device programs on the cpu
+backend).
 """
 
 from __future__ import annotations
 
+import os
 import threading
 from dataclasses import dataclass
 from functools import lru_cache
@@ -38,6 +53,9 @@ from paimon_tpu.metrics import (
 )
 from paimon_tpu.obs.trace import metrics_enabled, span
 from paimon_tpu.ops.normkey import NormalizedKeyEncoder
+from paimon_tpu.ops.ovc import (
+    OVC_OFF_SENTINEL, ovc_sorted_winners, run_ovc_offsets,
+)
 from paimon_tpu.types import RowKind
 
 __all__ = ["merge_runs", "MergeResult", "device_sorted_winners",
@@ -146,11 +164,32 @@ def _host_span(route: str, rows: int):
     return span("merge.host", cat="merge", rows=rows, route=route)
 
 
+def _eq_next(lane_list, invalid, ovc_off, perm):
+    """bool[N]: sorted position i continues the same (validity, key
+    lanes...) segment at i+1.  The validity guard keeps a real row whose
+    key encodes like padding out of the padding segment.  With
+    `ovc_off` (sorted-order offset-value-code offsets, else None) and
+    `perm` (the sort permutation) a pair that is also run-consecutive resolves key
+    equality from the next row's code alone (offset past the key lanes =
+    same key); only the remaining pairs use the lane-compare chain
+    (ops/ovc.run_ovc_offsets documents the code)."""
+    lanes_mat = jnp.stack(lane_list)
+    eq = jnp.all(lanes_mat[:, :-1] == lanes_mat[:, 1:], axis=0)
+    if ovc_off is not None:
+        consec = perm[1:] == perm[:-1] + 1
+        known = ovc_off[1:] != jnp.uint32(OVC_OFF_SENTINEL)
+        eq_code = ovc_off[1:] >= jnp.uint32(len(lane_list))
+        eq = jnp.where(consec & known, eq_code, eq)
+    eq = eq & (invalid[:-1] == invalid[1:])
+    return jnp.concatenate([eq, jnp.array([False])])
+
+
 def segmented_merge_body(lane_list, seq_hi, seq_lo, invalid, keep: str,
                          num_key_lanes: Optional[int] = None,
-                         use_pallas: bool = False, ovc_off=None):
-    """Traceable kernel body shared by the single-chip path, the sharded
-    multi-bucket path (parallel/sharded_merge.py) and the driver entry.
+                         ovc_off=None):
+    """Traceable kernel body shared by the single-chip path, the mesh
+    paths (parallel/mesh_engine.py, sharded_merge.py, sharded_compact.py),
+    the fused decode (ops/decode.py) and the driver entry.
 
     lane_list: list of uint32[N] arrays (most-significant lane first).
     The first `num_key_lanes` define SEGMENT identity; any further lanes
@@ -177,20 +216,7 @@ def segmented_merge_body(lane_list, seq_hi, seq_lo, invalid, keep: str,
     perm = sorted_ops[num_lanes + 3]
     s_off = sorted_ops[-1] if ovc_off is not None else None
 
-    if use_pallas:
-        # fused VMEM pass over all lanes at once; eq_next_mask itself
-        # falls back to the identical XLA ops for unsupported shapes or
-        # backends (ops/pallas_kernels.py)
-        from paimon_tpu.ops.pallas_kernels import eq_next_mask
-        eq_next = eq_next_mask(list(s_lanes), s_invalid,
-                               ovc_off=s_off, perm=perm)
-    else:
-        # single source of truth for the mask semantics (incl. the
-        # validity guard: a real row whose key encodes like padding
-        # must not join the padding segment)
-        from paimon_tpu.ops.pallas_kernels import _eq_next_xla
-        eq_next = _eq_next_xla(list(s_lanes), s_invalid, s_off, perm,
-                               num_key_lanes)
+    eq_next = _eq_next(s_lanes, s_invalid, s_off, perm)
     eq_prev = jnp.concatenate([jnp.array([False]), eq_next[:-1]])
     valid = s_invalid == 0
     if keep == "last":
@@ -205,10 +231,9 @@ def segmented_merge_body(lane_list, seq_hi, seq_lo, invalid, keep: str,
 
 @lru_cache(maxsize=64)
 def _merge_fn(num_lanes: int, keep: str, num_key_lanes: int,
-              use_pallas: bool, with_ovc: bool = False):
-    """Build the jitted merge kernel for a lane count.  `use_pallas`
-    is part of the cache key so PAIMON_DISABLE_PALLAS takes effect on
-    the next call, not the next process."""
+              with_ovc: bool = False):
+    """Build the jitted merge kernel for a lane count: the full
+    (perm, winner, prev) return."""
 
     if with_ovc:
         @jax.jit
@@ -216,7 +241,7 @@ def _merge_fn(num_lanes: int, keep: str, num_key_lanes: int,
             return segmented_merge_body(
                 [lanes[i] for i in range(num_lanes)], seq_hi, seq_lo,
                 invalid, keep, num_key_lanes=num_key_lanes,
-                use_pallas=use_pallas, ovc_off=ovc_off)
+                ovc_off=ovc_off)
 
         return fn_ovc
 
@@ -224,52 +249,23 @@ def _merge_fn(num_lanes: int, keep: str, num_key_lanes: int,
     def fn(lanes, seq_hi, seq_lo, invalid):
         return segmented_merge_body(
             [lanes[i] for i in range(num_lanes)], seq_hi, seq_lo, invalid,
-            keep, num_key_lanes=num_key_lanes, use_pallas=use_pallas)
+            keep, num_key_lanes=num_key_lanes)
 
     return fn
 
 
 @lru_cache(maxsize=64)
-def _merge_fn_bitmask(num_lanes: int, keep: str, num_key_lanes: int,
-                      use_pallas: bool):
-    """Winner BITMASK variant: uint32[M/32] output — one BIT per row
-    (winner flag scattered back to original row order), 1/32nd of the
-    packed-u32 return, for links whose device->host direction is the
-    narrow one.  The host recovers key order by radix-sorting just the
-    winners' packed keys (~half the rows), which it can do while the
-    device already works on the next window."""
-
-    @jax.jit
-    def fn(lanes, seq_hi, seq_lo, invalid):
-        perm, winner, _ = segmented_merge_body(
-            [lanes[i] for i in range(num_lanes)], seq_hi, seq_lo, invalid,
-            keep, num_key_lanes=num_key_lanes, use_pallas=use_pallas)
-        m = invalid.shape[0]
-        # scatter winner flags from sorted order to original positions
-        w_orig = jnp.zeros(m, jnp.bool_).at[perm].set(winner)
-        # pack 32 flags per word, little-endian bit order (matches
-        # np.unpackbits(..., bitorder="little") on the u8 view)
-        w = w_orig.reshape(-1, 32).astype(jnp.uint32)
-        return (w << jnp.arange(32, dtype=jnp.uint32)[None, :]).sum(
-            axis=1, dtype=jnp.uint32)
-
-    return fn
-
-
-@lru_cache(maxsize=64)
-def _merge_fn_packed(num_lanes: int, keep: str, num_key_lanes: int,
-                     use_pallas: bool):
+def _merge_fn_packed(num_lanes: int, keep: str, num_key_lanes: int):
     """Winners-only variant: ONE uint32[N] output, perm in the low 31
     bits and the winner flag in bit 31.  Callers that never read `prev`
     or intra-segment order pull 4 bytes/row off the device instead of
-    13 — the dominant cost wherever device->host is the narrow
-    direction."""
+    13."""
 
     @jax.jit
     def fn(lanes, seq_hi, seq_lo, invalid):
         perm, winner, _ = segmented_merge_body(
             [lanes[i] for i in range(num_lanes)], seq_hi, seq_lo, invalid,
-            keep, num_key_lanes=num_key_lanes, use_pallas=use_pallas)
+            keep, num_key_lanes=num_key_lanes)
         return perm.astype(jnp.uint32) | (
             winner.astype(jnp.uint32) << 31)
 
@@ -365,41 +361,6 @@ def _device_path_pays(n: int, num_lanes: int, winners_only: bool,
     return t_dev < n / host_rate
 
 
-# measured winner fraction of recent merges (adaptive duplicate-ratio
-# estimate for the bitmask cost model); starts at the conservative 1.0
-# (no dedup benefit assumed until observed)
-_WINNER_FRAC = {"num": 0.0, "den": 0.0}
-
-
-def _observed_winner_frac() -> float:
-    if _WINNER_FRAC["den"] < 1.0:
-        return 1.0
-    return max(0.05, _WINNER_FRAC["num"] / _WINNER_FRAC["den"])
-
-
-def _bitmask_device_pays(n: int, num_lanes: int,
-                         overlapped: bool) -> bool:
-    """Cost model for the bitmask return: device sorts + dedups, host
-    re-sorts only the winners.  With `overlapped=True` the caller runs
-    merges on a pipeline worker so upload/sort/download hide under the
-    next window's decode+cut — only the host epilogue stays on the
-    merge critical path."""
-    m = _pad_size(n)
-    h2d, d2h = _measure_link_bandwidth()
-    host_rate = _host_fast_rate()
-    frac = _observed_winner_frac()
-    t_link = (m * (4 * num_lanes + 12)) / h2d \
-        + m / _DEVICE_SORT_ROWS_PER_SEC + (m / 8) / d2h
-    t_epilogue = frac * n / host_rate      # radix of winners only
-    t_dev = t_epilogue + (0.0 if overlapped else t_link)
-    # even overlapped, the link must keep up with the pipeline or the
-    # worker stalls: charge any link time beyond the host-path budget
-    if overlapped:
-        budget = n / host_rate
-        t_dev += max(0.0, t_link - budget)
-    return t_dev < n / host_rate
-
-
 def _host_sorted_winners_fast(lanes: np.ndarray, seq: np.ndarray,
                               keep: str,
                               packed: Optional[np.ndarray] = None
@@ -430,8 +391,6 @@ def _host_sorted_winners_fast(lanes: np.ndarray, seq: np.ndarray,
     fused = native.merge_winners(key, seq, keep == "last")
     if fused is not None:
         perm, winner = fused
-        _WINNER_FRAC["num"] += float(np.count_nonzero(winner))
-        _WINNER_FRAC["den"] += float(n)
         return perm, winner, np.broadcast_to(np.int64(-1), n)
     perm = np.argsort(key, kind="stable").astype(np.int32)
     k_sorted = key[perm]
@@ -512,125 +471,64 @@ def _host_sorted_winners(lanes: np.ndarray, seq: np.ndarray, keep: str,
     return _winner_epilogue(perm, eq, keep)
 
 
-def _bitmask_sorted_winners(lanes, seq: np.ndarray, keep: str,
-                            order_lanes: Optional[np.ndarray],
-                            packed: np.ndarray
-                            ) -> Tuple[np.ndarray, np.ndarray,
-                                       np.ndarray]:
-    """Device path with the N/8-byte return: upload lanes+seq, device
-    sorts and computes the winner mask in ORIGINAL row order, host
-    radix-sorts only the winners' packed keys to recover key order.
-    Returns (winner_indices_in_key_order, all-true, -1) — valid under
-    the winners_only contract (callers select via the mask and never
-    read intra-segment order or prev)."""
-    PATH_COUNTS["device"] += 1
-    n = packed.shape[0]
-    lanes, lanes_p, seq_hi, seq_lo, invalid = _padded_operands(
-        lanes, order_lanes, seq)
-    m, num_lanes = lanes_p.shape
-    num_key_lanes = 2                     # bitmask requires packed u64
-
-    from paimon_tpu.ops.pallas_kernels import pallas_enabled
-    with _device_span("bitmask", n, m, 4 * m * (num_lanes + 3), m // 8):
-        lane_list = tuple(jnp.asarray(lanes_p[:, i])
-                          for i in range(num_lanes))
-        fn = _merge_fn_bitmask(num_lanes, keep, num_key_lanes,
-                               pallas_enabled())
-        words = fn(lane_list, jnp.asarray(seq_hi),
-                   jnp.asarray(seq_lo), jnp.asarray(invalid))
-        words = np.asarray(words)
-    with _host_span("bitmask_epilogue", n):
-        mask = np.unpackbits(words.view(np.uint8),
-                             bitorder="little")[:n].astype(bool)
-        widx = np.flatnonzero(mask)       # winners, original row order
-        _WINNER_FRAC["num"] += float(len(widx))
-        _WINNER_FRAC["den"] += float(n)
-        wkeys = np.ascontiguousarray(packed[widx])
-        from paimon_tpu import native
-        perm_w = native.radix_argsort(wkeys)
-        if perm_w is None:
-            perm_w = np.argsort(wkeys, kind="stable")
-        indices = widx[perm_w].astype(np.int32)
-    return (indices, np.ones(len(indices), dtype=bool),
-            np.broadcast_to(np.int64(-1), len(indices)))
-
-
 def device_sorted_winners(lanes: np.ndarray, seq: np.ndarray,
                           keep: str = "last",
                           order_lanes: Optional[np.ndarray] = None,
                           winners_only: bool = False,
                           packed: Optional[np.ndarray] = None,
-                          overlapped: bool = False,
                           run_starts: Optional[np.ndarray] = None
                           ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Run the device kernel.
+    """Sort one merge's rows and select each key's winner, on the host
+    or on the device.
 
     lanes: uint32[N, L] (segment identity); seq: int64[N] (non-negative);
     order_lanes: optional uint32[N, O] user-defined sequence lanes that
     rank within a key BEFORE the internal sequence.
     `winners_only=True` promises the caller uses ONLY the winner rows
-    (never full perm ordering within segments nor prev), unlocking the
-    packed-key fast path for fixed-width two-lane keys.
+    (never full perm ordering within segments nor prev): the device
+    returns one packed word a row, the host takes the packed-key fast
+    path for fixed-width two-lane keys (`packed`: their u64 form).
     `run_starts`: optional int64[k+1] boundaries marking the input as k
-    concatenated (key, seq)-SORTED runs — unlocks the offset-value
-    coded O(n log k) tree-of-losers merge (ops/ovc.py) on the host
-    path, replacing the full sort; rows need not be pre-validated (the
-    OVC path verifies the sort contract and falls back when violated).
+    concatenated (key, seq)-SORTED runs — on the host the offset-value
+    coded O(n log k) tree-of-losers merge (ops/ovc.py) replaces the full
+    sort (it verifies the sort contract and falls back when violated);
+    on the device the full return ships the codes to the winner-select.
     Returns (perm, winner_mask, prev_in_segment) as numpy arrays — of
-    the power-of-two padded size on the accelerator path, UNPADDED
-    (length N, all rows valid) on the host lexsort path.  Callers must
-    select via the winner mask / `perm < n`, never assume a padded
-    length.
+    the power-of-two padded size on the device route, UNPADDED (length
+    N, all rows valid) on the host route.  Callers must select via the
+    winner mask / `perm < n`, never assume a padded length.
 
-    Path selection is LINK-ADAPTIVE on accelerator backends: the first
-    call measures h2d/d2h bandwidth and each merge offloads only when
-    the modeled transfer+sort time beats the host sort
-    (_device_path_pays) — a wide link takes the device path, a narrow
-    one keeps data-heavy merges host-side.  Overrides:
-    PAIMON_FORCE_DEVICE_SORT=1 pins the device kernel (also on cpu,
-    for padding/validity tests); PAIMON_FORCE_HOST_SORT=1 pins the
-    host path.
+    Two routes.  On the cpu backend every merge stays on the host; on an
+    accelerator the first call measures h2d/d2h bandwidth and each merge
+    offloads only when the modeled transfer+sort time beats the host
+    sort (_device_path_pays).  Two pins override the model:
+    PAIMON_FORCE_HOST_SORT=1 (the benchmark builds its tables under it:
+    the build is not under test and its flush sorts stay off the device;
+    tests take the host route as their reference) and
+    PAIMON_FORCE_DEVICE_SORT=1 (tests run the device programs on the cpu
+    backend, for padding and validity).
     """
-    import os as _os
     n, num_key_lanes = lanes.shape
-    force_device = _os.environ.get("PAIMON_FORCE_DEVICE_SORT") == "1"
-    force_bitmask = _os.environ.get("PAIMON_FORCE_BITMASK_SORT") == "1"
-    force_host = _os.environ.get("PAIMON_FORCE_HOST_SORT") == "1"
-    host_fast = (num_key_lanes == 2 and winners_only
-                 and (order_lanes is None or order_lanes.shape[1] == 0))
-    # bitmask return: winners-only callers with a pre-packed u64 key
-    # (the host epilogue recovers key order by radix-sorting winners)
-    bitmask_ok = winners_only and packed is not None and n > 0
-    nl_total = lanes.shape[1] + (order_lanes.shape[1]
-                                 if order_lanes is not None else 0)
-    use_bitmask = force_bitmask and bitmask_ok
+    force_device = os.environ.get("PAIMON_FORCE_DEVICE_SORT") == "1"
+    force_host = os.environ.get("PAIMON_FORCE_HOST_SORT") == "1"
+    no_user_order = order_lanes is None or order_lanes.shape[1] == 0
+    host_fast = num_key_lanes == 2 and winners_only and no_user_order
+    nl_total = num_key_lanes + (0 if no_user_order
+                                else order_lanes.shape[1])
     use_host = force_host
-    pinned = force_host or force_device or force_bitmask
+    pinned = force_host or force_device
     if not pinned and n > 0:
-        if jax.default_backend() == "cpu":
-            use_host = True
-        else:
-            use_bitmask = bitmask_ok and _bitmask_device_pays(
-                n, nl_total, overlapped)
-            if not use_bitmask:
-                use_host = not _device_path_pays(n, nl_total,
-                                                 winners_only, host_fast)
+        use_host = jax.default_backend() == "cpu" \
+            or not _device_path_pays(n, nl_total, winners_only, host_fast)
     if len(ROUTE_LOG) < _ROUTE_LOG_CAP:
         ROUTE_LOG.append({
             "rows": n, "lanes": nl_total, "winners_only": winners_only,
-            "host_fast": host_fast, "bitmask_ok": bitmask_ok,
-            "overlapped": overlapped, "pinned": pinned,
-            "route": ("bitmask" if use_bitmask
-                      else "host" if use_host else "device")})
-    if use_bitmask:
-        return _bitmask_sorted_winners(lanes, seq, keep, order_lanes,
-                                       np.asarray(packed))
+            "host_fast": host_fast, "pinned": pinned,
+            "route": "host" if use_host else "device"})
     if use_host:
-        no_user_order = order_lanes is None or order_lanes.shape[1] == 0
         if run_starts is not None and no_user_order and len(run_starts) > 1:
             # sorted-run inputs: offset-value coded merge replaces the
             # sort (single-int compares, segment boundaries for free)
-            from paimon_tpu.ops.ovc import ovc_sorted_winners
             with _host_span("ovc", n):
                 res = ovc_sorted_winners(lanes, seq, keep, run_starts,
                                          num_key_lanes, packed=packed)
@@ -650,7 +548,6 @@ def device_sorted_winners(lanes: np.ndarray, seq: np.ndarray,
         lanes, order_lanes, seq)
     m, num_lanes = lanes_p.shape
 
-    from paimon_tpu.ops.pallas_kernels import pallas_enabled
     with_ovc = run_starts is not None and not winners_only
     with _device_span("packed" if winners_only
                       else "full_ovc" if with_ovc else "full", n, m,
@@ -661,23 +558,19 @@ def device_sorted_winners(lanes: np.ndarray, seq: np.ndarray,
         # sorted-run inputs ship their offset-value codes to the device:
         # the winner-select consumes the single-int offsets first and
         # only lane-compares pairs the codes cannot decide (full variant
-        # only — the packed/bitmask returns already collapse keys to one
-        # u64).  The codes are computed here, after the lanes' upload
-        # was started, as before the span was put around it.
+        # only — the packed return already collapses keys to one u64).
+        # The codes are computed here, after the lanes' upload was
+        # started, as before the span was put around it.
         ovc_args = ()
         if with_ovc:
-            from paimon_tpu.ops.ovc import (
-                OVC_OFF_SENTINEL, run_ovc_offsets,
-            )
             off = np.full(m, OVC_OFF_SENTINEL, dtype=np.uint32)
             off[:n] = run_ovc_offsets(lanes, run_starts)
             ovc_args = (jnp.asarray(off),)
         # a kernel the compiler refuses raises here: there is no second,
         # quieter program to fall back to
-        fn = _merge_fn_packed(num_lanes, keep, num_key_lanes,
-                              pallas_enabled()) if winners_only \
-            else _merge_fn(num_lanes, keep, num_key_lanes,
-                           pallas_enabled(), with_ovc)
+        fn = _merge_fn_packed(num_lanes, keep, num_key_lanes) \
+            if winners_only \
+            else _merge_fn(num_lanes, keep, num_key_lanes, with_ovc)
         out = fn(lane_list, jnp.asarray(seq_hi),
                  jnp.asarray(seq_lo), jnp.asarray(invalid), *ovc_args)
         if winners_only:
@@ -788,8 +681,7 @@ def merge_runs(runs: Sequence[pa.Table], key_names: Sequence[str],
                seq_fields: Optional[Sequence[str]] = None,
                seq_desc: bool = False,
                encoded: Optional[Sequence[Tuple[np.ndarray, np.ndarray]]]
-               = None,
-               overlapped: bool = False) -> MergeResult:
+               = None) -> MergeResult:
     """Merge k sorted runs (oldest first) into the latest row per key.
 
     Equivalent reference path: MergeTreeReaders.readerForMergeTree
@@ -865,7 +757,7 @@ def merge_runs(runs: Sequence[pa.Table], key_names: Sequence[str],
     perm, winner, prev = device_sorted_winners(
         lanes, seq, keep, order_lanes,
         winners_only=not with_prev and not truncated.any(),
-        packed=packed, overlapped=overlapped,
+        packed=packed,
         run_starts=run_starts if order_lanes is None else None)
 
     win_pos = np.flatnonzero(winner)

@@ -48,7 +48,8 @@ OVC_PATH_ROWS = {"rows": 0, "merges": 0}
 
 def ovc_enabled() -> bool:
     """OVC merge on unless explicitly disabled (kill switch mirrors
-    PAIMON_DISABLE_PALLAS / PAIMON_DISABLE_NATIVE)."""
+    PAIMON_DISABLE_NATIVE): tests take the sort-based paths it
+    replaces as their reference."""
     return os.environ.get("PAIMON_DISABLE_OVC") != "1"
 
 
@@ -60,7 +61,7 @@ def run_ovc_offsets(lanes, run_starts: np.ndarray) -> np.ndarray:
     run-consecutive resolves key-(in)equality from the offset alone —
     offset >= num_key_lanes means same key — and only the remaining
     pairs fall through to the full lane-compare chain
-    (ops/pallas_kernels.eq_next_mask)."""
+    (ops/merge.py _eq_next)."""
     mat = np.asarray(lanes)
     n, num_lanes = mat.shape
     out = np.full(n, np.uint32(num_lanes), dtype=np.uint32)

@@ -1,9 +1,11 @@
 """Link-adaptive merge path selection + packed winners-only output."""
 
 import numpy as np
+import pyarrow as pa
 import pytest
 
 from paimon_tpu.ops import merge as M
+from paimon_tpu.ops.normkey import NormalizedKeyEncoder
 
 
 def _mk(n, dupes=2, seed=0):
@@ -109,34 +111,82 @@ class TestForceHost:
 
 
 class TestKernelFailurePropagates:
-    @pytest.mark.parametrize("pin,builder", [
-        ("PAIMON_FORCE_DEVICE_SORT", "_merge_fn_packed"),
-        ("PAIMON_FORCE_BITMASK_SORT", "_merge_fn_bitmask"),
+    @pytest.mark.parametrize("winners_only,builder", [
+        (True, "_merge_fn_packed"),
+        (False, "_merge_fn"),
     ])
-    def test_compile_refusal_fails_the_merge(self, monkeypatch, pin,
-                                             builder):
-        """A kernel the compiler refuses fails the call that needed it:
-        no retry on a second program, no per-process switch that
-        retires the Pallas kernel for the rest of the run."""
+    def test_compile_refusal_fails_the_merge(self, monkeypatch,
+                                             winners_only, builder):
+        """A program the compiler refuses fails the call that needed
+        it: no retry on a second program."""
         from jax.errors import JaxRuntimeError
 
         built = []
 
-        def refusing_builder(num_lanes, keep, num_key_lanes, use_pallas):
-            built.append(use_pallas)
+        def refusing_builder(*key):
+            built.append(key)
 
             def fn(*args):
                 raise JaxRuntimeError(
-                    "INTERNAL: Mosaic failed to compile TPU kernel")
+                    "INTERNAL: failed to compile the sort program")
             return fn
 
-        monkeypatch.setenv(pin, "1")
+        monkeypatch.setenv("PAIMON_FORCE_DEVICE_SORT", "1")
         monkeypatch.setattr(M, builder, refusing_builder)
         lanes, seq = _mk(3000)
         packed = lanes[:, 0].astype(np.uint64) << np.uint64(32)
-        with pytest.raises(JaxRuntimeError, match="Mosaic"):
+        with pytest.raises(JaxRuntimeError, match="failed to compile"):
             M.device_sorted_winners(lanes, seq, "last",
-                                    winners_only=True, packed=packed)
-        assert built == [True]          # asked once, with Pallas on
-        from paimon_tpu.ops import pallas_kernels
-        assert pallas_kernels.pallas_enabled()
+                                    winners_only=winners_only,
+                                    packed=packed)
+        assert len(built) == 1          # asked once
+
+
+class TestRouteLog:
+    @pytest.mark.parametrize("pin", [None, "PAIMON_FORCE_HOST_SORT",
+                                     "PAIMON_FORCE_DEVICE_SORT"])
+    def test_entries_name_two_routes_and_six_inputs(self, monkeypatch,
+                                                    pin):
+        """What the benchmark's meters and the chip smoke read: one
+        entry a merge, `route` host or device, whichever engine merged
+        and whatever pinned it."""
+        from paimon_tpu.ops.agg import merge_runs_agg
+        from paimon_tpu.options import CoreOptions
+        from paimon_tpu.schema import Schema
+        from paimon_tpu.schema.table_schema import TableSchema
+        from paimon_tpu.types import BigIntType
+
+        schema = (Schema.builder()
+                  .column("id", BigIntType(False))
+                  .column("v", BigIntType())
+                  .primary_key("id")
+                  .options({"bucket": "1", "merge-engine": "aggregation",
+                            "fields.v.aggregate-function": "sum"})
+                  .build())
+        rng = np.random.default_rng(7)
+        runs = []
+        for r in range(3):
+            ids = np.sort(rng.integers(0, 400, 1500))
+            runs.append(pa.table({
+                "_KEY_id": pa.array(ids, pa.int64()),
+                "_SEQUENCE_NUMBER": pa.array(
+                    np.arange(r * 1500, (r + 1) * 1500), pa.int64()),
+                "_VALUE_KIND": pa.array(np.zeros(1500, np.int8), pa.int8()),
+                "id": pa.array(ids, pa.int64()),
+                "v": pa.array(rng.integers(0, 9, 1500), pa.int64())}))
+        if pin:
+            monkeypatch.setenv(pin, "1")
+        monkeypatch.setattr(M, "ROUTE_LOG", [])
+        enc = NormalizedKeyEncoder([pa.int64()], nullable=[False])
+        M.merge_runs(runs, ["_KEY_id"], key_encoder=enc)
+        M.merge_runs(runs, ["_KEY_id"], key_encoder=enc, with_prev=True)
+        merge_runs_agg(runs, ["_KEY_id"], TableSchema.from_schema(0, schema),
+                       CoreOptions(schema.options), key_encoder=enc)
+        assert len(M.ROUTE_LOG) == 3
+        for entry in M.ROUTE_LOG:
+            assert set(entry) == {"rows", "lanes", "winners_only",
+                                  "host_fast", "pinned", "route"}
+            assert entry["rows"] == 4500 and entry["lanes"] == 2
+            assert entry["pinned"] is (pin is not None)
+            assert entry["route"] == (
+                "device" if pin == "PAIMON_FORCE_DEVICE_SORT" else "host")

@@ -103,54 +103,85 @@ def test_winners_only_fast_path_matches_full_sort(keep, seed):
     assert w_fast == w_full
 
 
+def _packed_key(lanes):
+    return (lanes[:, 0].astype(np.uint64) << np.uint64(32)) \
+        | lanes[:, 1].astype(np.uint64)
+
+
 @pytest.mark.parametrize("keep", ["last", "first"])
 @pytest.mark.parametrize("seed", [2, 11, 77])
-def test_bitmask_path_matches_host(keep, seed):
-    """The N/8-byte bitmask device return + host winner radix must pick
-    the SAME winners in the SAME key order as the host fast path."""
+def test_packed_route_matches_host(keep, seed, monkeypatch):
+    """The device's one-word-a-row return (perm | winner << 31) must
+    pick the SAME winners in the SAME key order as the host fast path."""
     rng = np.random.default_rng(seed)
     n = int(rng.integers(40, 9000))
     lanes = rng.integers(0, 50, (n, 2), dtype=np.uint64) \
         .astype(np.uint32)
-    packed = (lanes[:, 0].astype(np.uint64) << np.uint64(32)) \
-        | lanes[:, 1].astype(np.uint64)
+    packed = _packed_key(lanes)
     seq = rng.integers(0, 15, n).astype(np.int64)
 
     host = device_sorted_winners(lanes, seq, keep, winners_only=True,
                                  packed=packed)
-    os.environ["PAIMON_FORCE_BITMASK_SORT"] = "1"
-    try:
-        bm = device_sorted_winners(lanes, seq, keep, winners_only=True,
-                                   packed=packed)
-    finally:
-        os.environ.pop("PAIMON_FORCE_BITMASK_SORT", None)
-    h_idx = np.asarray(host[0])[np.asarray(host[1], bool)
-                                & (np.asarray(host[0]) < n)]
-    b_idx = np.asarray(bm[0])[np.asarray(bm[1], bool)]
+    monkeypatch.setenv("PAIMON_FORCE_DEVICE_SORT", "1")
+    dev = device_sorted_winners(lanes, seq, keep, winners_only=True,
+                                packed=packed)
+    assert len(host[0]) == n and len(dev[0]) >= 1024    # padded
+    h_idx = _winners(host[0], host[1], n)
+    d_idx = _winners(dev[0], dev[1], n)
     # identical winners, identical (key-sorted) order
-    assert np.array_equal(h_idx, b_idx)
+    assert np.array_equal(h_idx, d_idx)
+    assert np.all(np.diff(packed[d_idx].astype(np.int64)) > 0)
 
 
-def test_bitmask_path_with_order_lanes():
+def test_packed_route_with_order_lanes(monkeypatch):
     rng = np.random.default_rng(5)
     n = 3000
     lanes = rng.integers(0, 20, (n, 2), dtype=np.uint64) \
         .astype(np.uint32)
-    packed = (lanes[:, 0].astype(np.uint64) << np.uint64(32)) \
-        | lanes[:, 1].astype(np.uint64)
+    packed = _packed_key(lanes)
     order = rng.integers(0, 4, (n, 1), dtype=np.uint64).astype(np.uint32)
     seq = np.arange(n, dtype=np.int64)
     host = device_sorted_winners(lanes, seq, "last", order_lanes=order,
                                  winners_only=True, packed=None)
-    os.environ["PAIMON_FORCE_BITMASK_SORT"] = "1"
-    try:
-        bm = device_sorted_winners(lanes, seq, "last", order_lanes=order,
-                                   winners_only=True, packed=packed)
-    finally:
-        os.environ.pop("PAIMON_FORCE_BITMASK_SORT", None)
-    h_idx = np.asarray(host[0])[np.asarray(host[1], bool)
-                                & (np.asarray(host[0]) < n)]
-    b_idx = np.asarray(bm[0])[np.asarray(bm[1], bool)]
-    assert set(h_idx.tolist()) == set(b_idx.tolist())
-    # bitmask output is key-ordered
-    assert np.all(np.diff(packed[b_idx].astype(np.int64)) >= 0)
+    monkeypatch.setenv("PAIMON_FORCE_DEVICE_SORT", "1")
+    dev = device_sorted_winners(lanes, seq, "last", order_lanes=order,
+                                winners_only=True, packed=packed)
+    h_idx = _winners(host[0], host[1], n)
+    d_idx = _winners(dev[0], dev[1], n)
+    assert set(h_idx.tolist()) == set(d_idx.tolist())
+    # the packed return is key-ordered
+    assert np.all(np.diff(packed[d_idx].astype(np.int64)) > 0)
+
+
+def _winner_set(perm, winner, prev, n):
+    """(winners, winner -> predecessor map) of one route's result."""
+    perm, prev = np.asarray(perm), np.asarray(prev)
+    pos = np.flatnonzero(np.asarray(winner, bool) & (perm < n))
+    return (set(perm[pos].tolist()),
+            {int(perm[i]): int(prev[i]) for i in pos})
+
+
+@pytest.mark.parametrize("keep", ["last", "first"])
+@pytest.mark.parametrize("seed", [0, 5, 11])
+def test_full_route_matches_host_three_lanes(keep, seed):
+    """The device's full return on three-lane keys: the winners and the
+    winner -> predecessor map of the host route."""
+    rng = np.random.default_rng(seed)
+    n = int(rng.integers(100, 6000))
+    lanes = rng.integers(0, 12, (n, 3), dtype=np.uint64) \
+        .astype(np.uint32)
+    seq = rng.permutation(n).astype(np.int64)
+    host, dev = _both_paths(lanes, seq, keep)
+    assert _winner_set(*host, n) == _winner_set(*dev, n)
+
+
+def test_padding_never_joins_segments(monkeypatch):
+    """All-zero real keys must not merge with the all-zero padding
+    rows (validity is part of segment identity in the kernel too)."""
+    monkeypatch.setenv("PAIMON_FORCE_DEVICE_SORT", "1")
+    lanes = np.zeros((5, 2), dtype=np.uint32)
+    seq = np.arange(5, dtype=np.int64)
+    perm, winner, _ = device_sorted_winners(lanes, seq, "last")
+    perm, winner = np.asarray(perm), np.asarray(winner, bool)
+    win = perm[winner & (perm < 5)]
+    assert win.tolist() == [4]       # one segment, max-seq row

@@ -267,9 +267,9 @@ def test_run_ovc_offsets_semantics():
 
 def test_device_kernel_ovc_equivalence(monkeypatch):
     """Forced device sort with run_starts exercises the OVC-aware
-    winner-select (Pallas interpret on cpu) — identical to the host
-    path, including run-boundary equal keys that the sentinel must
-    send through the lane-compare fallthrough."""
+    winner-select — identical to the host path, including
+    run-boundary equal keys that the sentinel must send through the
+    lane-compare fallthrough."""
     runs = [
         pa.table({"_KEY_id": pa.array([1, 2, 7], pa.int64()),
                   "_SEQUENCE_NUMBER": pa.array([0, 1, 2], pa.int64()),

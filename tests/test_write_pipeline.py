@@ -205,7 +205,11 @@ def _storm_table(tmp_path, storm, **opts):
 def test_mid_write_503_storm_retries_and_completes(tmp_path):
     from paimon_tpu.metrics import WRITE_RETRIES, global_registry
     storm = WriteStormFileIO(get_file_io(str(tmp_path)), faults=3)
-    table = _storm_table(tmp_path, storm)
+    # the faults are counted across flush tasks: one task may meet all
+    # three, so it gets faults + 1 attempts (the exhausted case is the
+    # next test's)
+    table = _storm_table(tmp_path, storm,
+                         **{"write.retry.max-attempts": "4"})
     r0 = global_registry().write_metrics().counter(WRITE_RETRIES).count
     wb = table.new_batch_write_builder()
     with wb.new_write() as w:
