@@ -1,12 +1,54 @@
-"""Table-level compact action: pick + rewrite + commit per bucket.
+"""Table-level compact action: pick per bucket, rewrite the buckets
+side by side, commit once.
 
 reference: the dedicated compaction job path (flink action/CompactAction ->
 StoreCompactOperator -> MergeTreeCompactManager), engine-free here.
+
+`compact_table` plans on the calling thread — for every (partition,
+bucket) group the partition decode, the `group_filter`, the manager's
+pick (or the append plan): metadata only, no file is opened — and so
+knows the groups that have work, each one's input rows and on-disk
+bytes.  The rewrites of those groups then run as tasks on one bounded
+pool (threads `paimon-compact_N`); nothing inside a task differs from
+what the serial loop ran, and their `CommitMessage`s are committed in
+`groups`' order as ONE COMPACT snapshot, whatever order they finish in.
+
+* **Workers** = min(groups with work, the scan's default ceiling
+  `scan_pipeline.default_parallelism()` = min(8, cpu count)).  Derived;
+  no option.  One group with work — or one core — runs on the calling
+  thread with no pool: the file operations of a single-bucket
+  compaction are the serial loop's, in its order.
+* **Byte budget.**  In-flight groups are also bounded by their input
+  files' on-disk bytes against `read.prefetch.max-bytes`, the budget the
+  scan of the same table decodes under; at least one group is always
+  admitted.  The submitter waits for room in a `compact.admit` span.
+* **Streamed groups.**  A primary-key group whose input rows exceed
+  `tpu.merge.stream-threshold-rows` takes `_rewrite_streamed`, which
+  owns five pool threads and a prefetch thread per run: it is admitted
+  only when nothing else is in flight, and nothing beside it.
+* **The merge router's link reading** (`ops/merge.py`, once a process)
+  is taken on the calling thread before the pool starts, where the
+  router would take one at all: timed by the first merge with the
+  other tasks' decode and prep contending for the host, it reads the
+  link several times too narrow and sends every merge to the host.
+* **Failure.**  Once a task has raised nothing more is admitted; the
+  running tasks are waited for, the first failure in `groups`' order is
+  re-raised and nothing is committed — the files that finished tasks
+  wrote are orphans, as the serial loop's earlier buckets' were.  The
+  pool is shut down on every path; only a spent request deadline leaves
+  without joining workers that may hang (the scan pipeline's rule).
+* **Observability.**  `compact.table` (root; `compaction` / `table_ms`;
+  attrs `groups`, `workers`, `rows`) is the wall time of the group
+  phase; every `compact.task` is its child, on the pool through
+  `carry`, so `duration_ms` sums over threads and `duration_ms` /
+  `table_ms` is the concurrency achieved.  The gauge `compaction` /
+  `concurrent_tasks_peak` holds the last call's most tasks in flight.
 """
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Tuple
+from dataclasses import dataclass
+from typing import Callable, Dict, List, Optional, Tuple
 
 from paimon_tpu.compact.manager import MergeTreeCompactManager
 from paimon_tpu.options import CoreOptions
@@ -123,34 +165,9 @@ def compact_table(table, full: bool = False,
         from paimon_tpu.core.row_tracking import compact_row_tracked
         return compact_row_tracked(table,
                                    partition_filter=partition_filter)
-    dv_index = scan._load_deletion_vectors(snapshot.id, snapshot) \
-        if is_append else {}
-    messages: List[CommitMessage] = []
-    for (pbytes, bucket), files in groups.items():
-        partition = scan._partition_codec.from_bytes(pbytes)
-        if group_filter is not None and \
-                not group_filter(tuple(partition), bucket):
-            continue              # another host's share
-        if is_append:
-            result = _append_compact(
-                table, scan, partition, bucket, files, full,
-                bucket_dvs=dv_index.get((pbytes, bucket)),
-                pbytes=pbytes, snapshot=snapshot)
-        else:
-            mgr = MergeTreeCompactManager(
-                table.file_io, table.path, table.schema, table.options,
-                partition, bucket, files,
-                schema_manager=table.schema_manager)
-            result = mgr.compact(full=full)
-        if result is None or result.is_empty():
-            continue
-        messages.append(CommitMessage(
-            partition=partition, bucket=bucket,
-            total_buckets=total_buckets[(pbytes, bucket)],
-            compact_before=result.before,
-            compact_after=result.after,
-            compact_changelog=result.changelog,
-            index_entries=getattr(result, "index_entries", [])))
+    tasks = _plan_tasks(table, scan, snapshot, groups, total_buckets,
+                        full, group_filter)
+    messages = [m for m in _run_tasks(table, tasks) if m is not None]
 
     if not messages:
         return None
@@ -163,6 +180,178 @@ def compact_table(table, full: bool = False,
     return commit.commit(messages, BATCH_COMMIT_IDENTIFIER,
                          index_entries=index_list or None,
                          properties=properties)
+
+
+@dataclass
+class _GroupTask:
+    """One (partition, bucket) group that has work: `rewrite()` is the
+    whole of it (no argument, returns the manager's or the append
+    plan's result), planned on the calling thread from metadata."""
+    partition: Tuple
+    bucket: int
+    total_buckets: int
+    rewrite: Callable[[], object]
+    rows: int               # input rows, from the DataFileMetas
+    est_bytes: int          # input bytes on disk (the scan's estimate)
+    streamed: bool          # takes _rewrite_streamed: runs alone
+
+    def run(self) -> Optional[CommitMessage]:
+        result = self.rewrite()
+        if result is None or result.is_empty():
+            return None
+        return CommitMessage(
+            partition=self.partition, bucket=self.bucket,
+            total_buckets=self.total_buckets,
+            compact_before=result.before,
+            compact_after=result.after,
+            compact_changelog=result.changelog,
+            index_entries=getattr(result, "index_entries", []))
+
+
+def _plan_tasks(table, scan, snapshot, groups, total_buckets, full,
+                group_filter) -> List[_GroupTask]:
+    """The groups with work, in `groups`' order.  Metadata only: a
+    group another host owns, or one with nothing picked, costs no
+    worker and opens no file."""
+    from functools import partial
+
+    from paimon_tpu.core.append import append_compact_plan
+
+    is_append = not table.schema.primary_keys
+    dv_index = scan._load_deletion_vectors(snapshot.id, snapshot) \
+        if is_append else {}
+    stream_rows = table.options.get(CoreOptions.MERGE_STREAM_THRESHOLD_ROWS)
+    tasks: List[_GroupTask] = []
+    for (pbytes, bucket), files in groups.items():
+        partition = scan._partition_codec.from_bytes(pbytes)
+        if group_filter is not None and \
+                not group_filter(tuple(partition), bucket):
+            continue              # another host's share
+        if is_append:
+            bucket_dvs = dv_index.get((pbytes, bucket))
+            inputs = append_compact_plan(files, table.options, full=full,
+                                         dvs=bucket_dvs)
+            if not inputs:
+                continue
+            rewrite = partial(_append_rewrite, table, scan, partition,
+                              bucket, inputs, bucket_dvs, pbytes,
+                              snapshot)
+        else:
+            mgr = MergeTreeCompactManager(
+                table.file_io, table.path, table.schema, table.options,
+                partition, bucket, files,
+                schema_manager=table.schema_manager)
+            unit = mgr.pick(full)
+            if unit is None or not unit.files:
+                continue
+            inputs = unit.files
+            rewrite = partial(mgr.do_compact, unit)
+        rows = sum(f.row_count for f in inputs)
+        tasks.append(_GroupTask(
+            partition, bucket, total_buckets[(pbytes, bucket)], rewrite,
+            rows=rows,
+            est_bytes=sum(f.file_size for f in inputs),
+            streamed=not is_append and rows > stream_rows))
+    return tasks
+
+
+def _run_tasks(table, tasks: List[_GroupTask]
+               ) -> List[Optional[CommitMessage]]:
+    """Run the groups' rewrites, side by side where there are several
+    and the cores to run them; their results in `tasks`' order."""
+    from paimon_tpu.metrics import (
+        COMPACTION_CONCURRENT_TASKS_PEAK, COMPACTION_TABLE_MS,
+        global_registry,
+    )
+    from paimon_tpu.obs.trace import span
+    from paimon_tpu.parallel.scan_pipeline import default_parallelism
+
+    if not tasks:
+        return []
+    workers = min(len(tasks), default_parallelism())
+    peak = global_registry().group("compaction").gauge(
+        COMPACTION_CONCURRENT_TASKS_PEAK)
+    with span("compact.table", cat="compaction", group="compaction",
+              metric=COMPACTION_TABLE_MS, groups=len(tasks),
+              workers=workers, rows=sum(t.rows for t in tasks)):
+        if workers <= 1:
+            peak.set(1)
+            return [t.run() for t in tasks]
+        if table.schema.primary_keys:
+            # the merge router's one link reading, before the tasks'
+            # prep contends for the host: what the serial loop's first
+            # merge read
+            from paimon_tpu.ops.merge import take_link_reading
+            take_link_reading()
+        return _run_pooled(tasks, workers, table.options.get(
+            CoreOptions.READ_PREFETCH_MAX_BYTES), peak)
+
+
+def _run_pooled(tasks: List[_GroupTask], workers: int, max_bytes: int,
+                peak) -> List[Optional[CommitMessage]]:
+    import concurrent.futures as cf
+
+    from paimon_tpu.obs.trace import carry, span
+    from paimon_tpu.parallel.executors import new_thread_pool
+    from paimon_tpu.utils.deadline import (
+        DeadlineExceededError, check_deadline, wait_future,
+    )
+
+    pool = new_thread_pool(workers, "paimon-compact")
+    futures: list = []          # in `tasks`' order
+    inflight: dict = {}         # future -> its task, until seen done
+    inflight_bytes = 0
+    most = 0
+    failed = abandoned = False
+    try:
+        for task in tasks:
+            # admit: worker ceiling + byte budget, always >= 1 in
+            # flight; a streamed group alone.  The wait is for any
+            # running task to end, sliced so a spent deadline escapes
+            with span("compact.admit", cat="compaction",
+                      bucket=task.bucket, est_bytes=task.est_bytes):
+                while True:
+                    for fut in [f for f in inflight if f.done()]:
+                        inflight_bytes -= inflight.pop(fut).est_bytes
+                        failed = failed or fut.exception() is not None
+                    alone = task.streamed or any(
+                        t.streamed for t in inflight.values())
+                    if failed or not inflight or (
+                            not alone and len(inflight) < workers and
+                            inflight_bytes + task.est_bytes
+                            <= max_bytes):
+                        break
+                    check_deadline("compaction admit")
+                    cf.wait(list(inflight), timeout=0.5,
+                            return_when=cf.FIRST_COMPLETED)
+            if failed:
+                break               # start nothing after a failure
+            fut = pool.submit(carry(task.run))
+            futures.append(fut)
+            inflight[fut] = task
+            inflight_bytes += task.est_bytes
+            most = max(most, len(inflight))
+        peak.set(most)
+        # wait for what runs, in order; the first failure in `tasks`'
+        # order is the one raised
+        messages, error = [], None
+        for fut in futures:
+            try:
+                messages.append(wait_future(fut, "compaction task"))
+            except DeadlineExceededError:
+                raise
+            except Exception as e:      # noqa: BLE001 — re-raised below
+                error = error or e
+        if error is not None:
+            raise error
+        return messages
+    except DeadlineExceededError:
+        # workers may be hung in store calls: answer within the
+        # deadline's grace, do not join them (the scan pipeline's rule)
+        abandoned = True
+        raise
+    finally:
+        pool.shutdown(wait=not abandoned, cancel_futures=True)
 
 
 def rescale_postpone(table) -> Optional[int]:
@@ -332,22 +521,17 @@ def sort_compact(table, order_by, strategy: str = "zorder"):
                          index_entries=index_list or None)
 
 
-def _append_compact(table, scan, partition, bucket, files, full,
-                    bucket_dvs=None, pbytes=None, snapshot=None):
-    """Concatenate small append files into target-size files (reference
+def _append_rewrite(table, scan, partition, bucket, picked, bucket_dvs,
+                    pbytes, snapshot):
+    """Concatenate the small append files `append_compact_plan` picked
+    into target-size files (reference
     append/BucketedAppendCompactManager: no keys, order by sequence).
     Deletion vectors of rewritten files are applied (rows physically
     dropped) and the bucket's DV index entries rewritten to cover only
     the surviving files."""
-    from paimon_tpu.core.append import (
-        AppendCompactResult, append_compact_plan,
-    )
+    from paimon_tpu.core.append import AppendCompactResult
     from paimon_tpu.manifest import FileSource
 
-    picked = append_compact_plan(files, table.options, full=full,
-                                 dvs=bucket_dvs)
-    if not picked:
-        return None
     writer = _make_append_writer(table, scan.path_factory)
     data = _read_bucket(table, scan.path_factory, partition, bucket,
                         picked, dvs=bucket_dvs)

@@ -32,6 +32,7 @@ __all__ = ["Counter", "Gauge", "Histogram", "MetricGroup",
            "COMPACTION_WINDOW_MS", "COMPACTION_FALLBACK_MS",
            "COMMIT_CAS_MS", "COMMIT_MANIFEST_ENCODE_MS",
            "COMMIT_DURATION_MS", "COMPACTION_DURATION_MS",
+           "COMPACTION_TABLE_MS", "COMPACTION_CONCURRENT_TASKS_PEAK",
            "WRITE_ROUTE_MS",
            "MERGE_PREP_MS", "MERGE_DEVICE_MS", "MERGE_AGG_MS",
            "MERGE_SELECT_MS", "MERGE_GATHER_MS", "MERGE_GATHER_BYTES",
@@ -137,6 +138,11 @@ COMMIT_MANIFEST_ENCODE_MS = "manifest_encode_ms"
 COMMIT_DURATION_MS = "duration_ms"          # commit: one whole commit,
                                             # published or given up
 COMPACTION_DURATION_MS = "duration_ms"      # compaction: one whole task
+COMPACTION_TABLE_MS = "table_ms"            # compaction: compact_table's
+                                            # group phase, wall (tasks
+                                            # side by side inside it)
+COMPACTION_CONCURRENT_TASKS_PEAK = "concurrent_tasks_peak"  # gauge: most
+                                            # tasks in flight, last call
 WRITE_ROUTE_MS = "route_ms"                 # write: hash/group-by/take
 # merge metric group: the stages of one sorted-run merge, whoever
 # called it (scan split, flush sort, compaction window) — producers

@@ -59,7 +59,8 @@ from paimon_tpu.ops.ovc import (
 from paimon_tpu.types import RowKind
 
 __all__ = ["merge_runs", "MergeResult", "device_sorted_winners",
-           "user_seq_order_lanes", "SEQ_COL", "KIND_COL"]
+           "take_link_reading", "user_seq_order_lanes", "SEQ_COL",
+           "KIND_COL"]
 
 SEQ_COL = "_SEQUENCE_NUMBER"
 KIND_COL = "_VALUE_KIND"
@@ -320,6 +321,21 @@ def _measure_link_bandwidth() -> Tuple[float, float]:
         if _LINK_BW is None:
             _LINK_BW = _time_link()
         return _LINK_BW
+
+
+def take_link_reading() -> None:
+    """Take the process's one link reading now, on the calling thread,
+    if the router is going to want one (an accelerator backend, no pin).
+    For a caller about to route merges from several threads at once:
+    the first merge otherwise times the link while the other threads'
+    decode and prep contend for the host, reads it several times too
+    narrow (0.19–0.28 GB/s up where a quiet host reads 0.9–4.4; PERF.md
+    §6, PR 31) and sends every merge of the process to the host."""
+    if os.environ.get("PAIMON_FORCE_HOST_SORT") == "1" or \
+            os.environ.get("PAIMON_FORCE_DEVICE_SORT") == "1":
+        return
+    if jax.default_backend() != "cpu":
+        _measure_link_bandwidth()
 
 
 def _time_link() -> Tuple[float, float]:

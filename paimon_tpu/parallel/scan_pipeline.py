@@ -46,17 +46,24 @@ from paimon_tpu.options import CoreOptions
 
 __all__ = ["iter_split_tables", "read_file_retrying",
            "read_fault_is_retryable", "read_or_skip_corrupt",
-           "resolve_parallelism"]
+           "resolve_parallelism", "default_parallelism"]
+
+
+def default_parallelism() -> int:
+    """The worker ceiling of a host-side pipeline over one table's
+    buckets when nothing sets one: min(8, cpu count).  The scan's
+    default, and the ceiling `compact_table` runs its groups under."""
+    return min(8, os.cpu_count() or 1)
 
 
 def resolve_parallelism(options: Optional[CoreOptions]) -> int:
     """Worker threads for the pipelined scan: scan.split.parallelism,
-    defaulting to min(8, cpu count).  1 means serial."""
+    defaulting to `default_parallelism()`.  1 means serial."""
     par = None
     if options is not None:
         par = options.get(CoreOptions.SCAN_SPLIT_PARALLELISM)
     if par is None:
-        par = min(8, os.cpu_count() or 1)
+        par = default_parallelism()
     return max(1, int(par))
 
 

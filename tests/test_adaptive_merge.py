@@ -72,6 +72,29 @@ class TestLinkMeasurement:
         assert got == [(1e9, 2e9)] * 8
 
 
+    @pytest.mark.parametrize("backend,pin,taken", [
+        ("tpu", None, True), ("cpu", None, False),
+        ("tpu", "PAIMON_FORCE_HOST_SORT", False),
+        ("tpu", "PAIMON_FORCE_DEVICE_SORT", False)])
+    def test_a_reading_is_taken_ahead_only_where_the_router_wants_one(
+            self, monkeypatch, backend, pin, taken):
+        """`take_link_reading` (before `compact_table`'s tasks go side by
+        side): an accelerator and no pin, as in the router itself."""
+        calls = []
+        monkeypatch.delenv("PAIMON_FORCE_HOST_SORT", raising=False)
+        monkeypatch.delenv("PAIMON_FORCE_DEVICE_SORT", raising=False)
+        if pin:
+            monkeypatch.setenv(pin, "1")
+        monkeypatch.setattr(M.jax, "default_backend", lambda: backend)
+        monkeypatch.setattr(M, "_LINK_BW", None)
+        monkeypatch.setattr(M, "_time_link",
+                            lambda: calls.append(1) or (1e9, 2e9))
+        M.take_link_reading()
+        M.take_link_reading()                   # one reading a process
+        assert len(calls) == (1 if taken else 0)
+        assert M._LINK_BW == ((1e9, 2e9) if taken else None)
+
+
 class TestPackedDevicePath:
     def test_packed_matches_host(self, monkeypatch):
         monkeypatch.setenv("PAIMON_FORCE_DEVICE_SORT", "1")

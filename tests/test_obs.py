@@ -702,8 +702,8 @@ def test_every_span_walks_back_to_its_operations_root(tmp_path,
         threads = ("MainThread",)
     elif operation == "compact":
         assert table.compact(full=True) is not None
-        # the task, then the commit of its result
-        allowed = {"compact.task", "commit"}
+        # the group phase (the one task under it), then the commit
+        allowed = {"compact.table", "commit"}
         threads = ("MainThread", "paimon-prefetch-pump",
                    "ThreadPoolExecutor")
     else:
@@ -722,6 +722,72 @@ def test_every_span_walks_back_to_its_operations_root(tmp_path,
     seen = {s.thread.split("_")[0].split("-0")[0] for s in spans}
     for prefix in threads:
         assert any(t.startswith(prefix) for t in seen), (prefix, seen)
+
+
+def test_compact_table_is_the_root_of_every_task_across_the_pool(
+        tmp_path, monkeypatch):
+    """Eight buckets on eight cores: `compact.table` on the calling
+    thread is the root, every `compact.task` its child on a
+    `paimon-compact` worker (through `carry`), the main thread's waits
+    are `wait` leaves, and the most tasks in flight is at least two."""
+    from paimon_tpu.metrics import global_registry
+    monkeypatch.setattr(os, "cpu_count", lambda: 8)
+    table = _build_traced_table(str(tmp_path / "t"), rows=8_000)
+    before = _registry_totals()
+    obs.enable_tracing(max_spans=50_000)
+    assert table.compact(full=True) is not None
+    spans = obs.take_spans()
+    roots = _roots(spans)
+    assert {r.name for r in roots.values()} == {"compact.table", "commit"}
+    top = next(s for s in spans if s.name == "compact.table")
+    assert top.thread == "MainThread"
+    assert (top.attrs["groups"], top.attrs["workers"]) == (8, 8)
+    assert top.attrs["rows"] == 16_000
+    tasks = [s for s in spans if s.name == "compact.task"]
+    assert len(tasks) == 8
+    assert all(t.parent_id == top.span_id for t in tasks)
+    assert all(t.thread.startswith("paimon-compact") for t in tasks)
+    assert sorted(t.attrs["bucket"] for t in tasks) == list(range(8))
+    admits = [s for s in spans if s.name == "compact.admit"]
+    assert len(admits) == 8
+    assert all(a.parent_id == top.span_id and a.thread == "MainThread"
+               for a in admits)
+    waits = [s for s in spans if s.name == "wait"
+             and s.attrs["what"] == "compaction task"]
+    assert len(waits) == 8
+    assert all(w.parent_id == top.span_id and w.thread == "MainThread"
+               for w in waits)
+    after = _registry_totals()
+    assert _delta(before, after, "compaction", "table_ms")[1] == 1
+    assert _delta(before, after, "compaction", "duration_ms")[1] == 8
+    peak = global_registry().group("compaction") \
+        .gauge("concurrent_tasks_peak").value
+    assert 2 <= peak <= 8
+
+
+def test_compact_table_feeds_its_sinks_with_tracing_off(tmp_path,
+                                                        monkeypatch):
+    """No ring, no profiler: `compaction` / `table_ms` still takes one
+    sample a call and the gauge the call's peak; one group runs on the
+    calling thread and reads 1."""
+    from paimon_tpu.metrics import global_registry
+    monkeypatch.setattr(os, "cpu_count", lambda: 8)
+    assert not obs.tracing_enabled() and not obs.profiler_listening()
+    gauge = global_registry().group("compaction") \
+        .gauge("concurrent_tasks_peak")
+    many = _build_traced_table(str(tmp_path / "t"), rows=4_000)
+    before = _registry_totals()
+    assert many.compact(full=True) is not None
+    mid = _registry_totals()
+    total, samples = _delta(before, mid, "compaction", "table_ms")
+    assert samples == 1 and total > 0
+    assert 2 <= gauge.value <= 8
+    one = _small_agg_table(str(tmp_path / "a"), streamed=False)
+    assert one.compact(full=True) is not None
+    assert _delta(mid, _registry_totals(), "compaction",
+                  "table_ms")[1] == 1
+    assert gauge.value == 1
+    assert obs.take_spans() == []
 
 
 @pytest.mark.parametrize("route", ["host", "device"])
