@@ -43,6 +43,9 @@ what the serial loop ran, and their `CommitMessage`s are committed in
   `carry`, so `duration_ms` sums over threads and `duration_ms` /
   `table_ms` is the concurrency achieved.  The gauge `compaction` /
   `concurrent_tasks_peak` holds the last call's most tasks in flight.
+  The mesh route (`tpu.mesh.compact`, `parallel/mesh_engine.py`) opens
+  the same root through `table_span` — `workers` its lanes — around
+  one `compact.task`, its whole step loop on the calling thread.
 """
 
 from __future__ import annotations
@@ -255,28 +258,43 @@ def _plan_tasks(table, scan, snapshot, groups, total_buckets, full,
     return tasks
 
 
-def _run_tasks(table, tasks: List[_GroupTask]
-               ) -> List[Optional[CommitMessage]]:
-    """Run the groups' rewrites, side by side where there are several
-    and the cores to run them; their results in `tasks`' order."""
+def table_span(groups: int, workers: int, rows: int):
+    """`compact.table`: the root of a table compaction's group phase,
+    planning done and the commit still to come, on the calling thread —
+    whichever route runs the groups (`_run_tasks` below: `workers` pool
+    threads; the mesh engine: `workers` lanes).  Its sink `compaction` /
+    `table_ms` is the phase's wall time; the gauge beside it holds the
+    most tasks in flight, 1 until a pool says otherwise."""
     from paimon_tpu.metrics import (
         COMPACTION_CONCURRENT_TASKS_PEAK, COMPACTION_TABLE_MS,
         global_registry,
     )
     from paimon_tpu.obs.trace import span
+
+    global_registry().group("compaction").gauge(
+        COMPACTION_CONCURRENT_TASKS_PEAK).set(1)
+    return span("compact.table", cat="compaction", group="compaction",
+                metric=COMPACTION_TABLE_MS, groups=groups,
+                workers=workers, rows=rows)
+
+
+def _run_tasks(table, tasks: List[_GroupTask]
+               ) -> List[Optional[CommitMessage]]:
+    """Run the groups' rewrites, side by side where there are several
+    and the cores to run them; their results in `tasks`' order."""
+    from paimon_tpu.metrics import (
+        COMPACTION_CONCURRENT_TASKS_PEAK, global_registry,
+    )
     from paimon_tpu.parallel.scan_pipeline import default_parallelism
 
     if not tasks:
         return []
     workers = min(len(tasks), default_parallelism())
-    peak = global_registry().group("compaction").gauge(
-        COMPACTION_CONCURRENT_TASKS_PEAK)
-    with span("compact.table", cat="compaction", group="compaction",
-              metric=COMPACTION_TABLE_MS, groups=len(tasks),
-              workers=workers, rows=sum(t.rows for t in tasks)):
+    with table_span(len(tasks), workers, sum(t.rows for t in tasks)):
         if workers <= 1:
-            peak.set(1)
             return [t.run() for t in tasks]
+        peak = global_registry().group("compaction").gauge(
+            COMPACTION_CONCURRENT_TASKS_PEAK)
         if table.schema.primary_keys:
             # the merge router's one link reading, before the tasks'
             # prep contends for the host: what the serial loop's first

@@ -33,6 +33,7 @@ __all__ = ["Counter", "Gauge", "Histogram", "MetricGroup",
            "COMMIT_CAS_MS", "COMMIT_MANIFEST_ENCODE_MS",
            "COMMIT_DURATION_MS", "COMPACTION_DURATION_MS",
            "COMPACTION_TABLE_MS", "COMPACTION_CONCURRENT_TASKS_PEAK",
+           "COMPACTION_MESH_STEPS", "COMPACTION_MESH_PADDED_ROWS",
            "WRITE_ROUTE_MS",
            "MERGE_PREP_MS", "MERGE_DEVICE_MS", "MERGE_AGG_MS",
            "MERGE_SELECT_MS", "MERGE_GATHER_MS", "MERGE_GATHER_BYTES",
@@ -143,6 +144,11 @@ COMPACTION_TABLE_MS = "table_ms"            # compaction: compact_table's
                                             # side by side inside it)
 COMPACTION_CONCURRENT_TASKS_PEAK = "concurrent_tasks_peak"  # gauge: most
                                             # tasks in flight, last call
+COMPACTION_MESH_STEPS = "mesh_steps"        # counter: mesh engine steps run
+COMPACTION_MESH_PADDED_ROWS = "mesh_padded_rows"  # counter: lanes x n_pad
+                                            # of those steps: every row slot
+                                            # the chips sorted, padding and
+                                            # drained lanes included
 WRITE_ROUTE_MS = "route_ms"                 # write: hash/group-by/take
 # merge metric group: the stages of one sorted-run merge, whoever
 # called it (scan split, flush sort, compaction window) — producers

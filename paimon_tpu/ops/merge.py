@@ -148,16 +148,17 @@ def _padded_operands(lanes, order_lanes: Optional[np.ndarray],
     return lanes, lanes_p, seq_hi, seq_lo, invalid
 
 
-def _device_span(route: str, rows: int, padded_rows: int,
-                 h2d_bytes: int, d2h_bytes: int):
-    """`merge.device`: one round trip to the chip, from the first
-    operand's upload to the host holding the result.  It times the
-    calls as they are — dispatch is asynchronous, the download blocks —
-    and adds none.  Bytes are the operands' and the results' sizes."""
+def device_span(route: str, rows: int, padded_rows: int,
+                h2d_bytes: int, d2h_bytes: int, **attrs):
+    """`merge.device`: one round trip to the chip (or, route `mesh`, to
+    the chips of a mesh step), from the first operand's upload to the
+    host holding the result.  It times the calls as they are — dispatch
+    is asynchronous, the download blocks — and adds none.  Bytes are
+    the operands' and the results' sizes."""
     return span("merge.device", cat="merge", group="merge",
                 metric=MERGE_DEVICE_MS, rows=rows,
                 padded_rows=padded_rows, h2d_bytes=h2d_bytes,
-                d2h_bytes=d2h_bytes, route=route)
+                d2h_bytes=d2h_bytes, route=route, **attrs)
 
 
 def _host_span(route: str, rows: int):
@@ -565,10 +566,10 @@ def device_sorted_winners(lanes: np.ndarray, seq: np.ndarray,
     m, num_lanes = lanes_p.shape
 
     with_ovc = run_starts is not None and not winners_only
-    with _device_span("packed" if winners_only
-                      else "full_ovc" if with_ovc else "full", n, m,
-                      4 * m * (num_lanes + 3 + with_ovc),
-                      4 * m if winners_only else 9 * m):
+    with device_span("packed" if winners_only
+                     else "full_ovc" if with_ovc else "full", n, m,
+                     4 * m * (num_lanes + 3 + with_ovc),
+                     4 * m if winners_only else 9 * m):
         lane_list = tuple(jnp.asarray(lanes_p[:, i])
                           for i in range(num_lanes))
         # sorted-run inputs ship their offset-value codes to the device:

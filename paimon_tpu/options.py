@@ -301,16 +301,22 @@ class CoreOptions:
         "windows)")
     MESH_COMPACT = ConfigOption(
         "tpu.mesh.compact", _parse_bool, False,
-        "Route full compactions of primary-key tables through the "
-        "streaming mesh engine (parallel/mesh_engine.py): all buckets "
-        "compact in one mesh program, streamed in bounded key windows "
-        "with skew-aware bucket->device packing (ours)")
+        "Route full compactions of primary-key tables through the mesh "
+        "engine (parallel/mesh_engine.py): one lane per device JAX "
+        "finds, the buckets packed onto the lanes by manifest row "
+        "counts, each bucket streamed in key windows cut at "
+        "tpu.merge.window-rows rows a run, one window a lane sorted "
+        "per lock-step shard_map step; engines or requests it cannot "
+        "run take the single-chip path (ours; on four v5e chips one "
+        "host thread drives the steps and sets the rate: PERF.md, "
+        "dedup_compact_mesh4)")
     MESH_WINDOW_ROWS = ConfigOption(
         "tpu.mesh.window-rows", int, 1 << 20,
-        "Decoded chunk rows per sorted run for the mesh engine's "
-        "bounded key-window streaming; per-bucket peak host memory is "
-        "~ runs x window-rows x row-bytes, independent of bucket size "
-        "(ours)")
+        "Decoded chunk rows per sorted run on the mesh engine's "
+        "prefetch threads (the decode chunk, NOT the merge window: "
+        "that is cut at tpu.merge.window-rows); per-bucket peak host "
+        "memory is ~ runs x this x row-bytes, independent of bucket "
+        "size (ours)")
     BRANCH = ConfigOption("branch", str, "main", "")
     METASTORE_PARTITIONED_TABLE = ConfigOption("metastore.partitioned-table",
                                                _parse_bool, False, "")

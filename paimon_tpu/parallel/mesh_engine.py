@@ -1,50 +1,70 @@
-"""Streaming mesh compaction engine: every merge engine, bounded
-key-windows, skew-aware bucket packing.
+"""Mesh compaction engine: one full compaction of a primary-key table
+across the chips of a host, every merge engine, bounded key windows.
 
-Replaces the monolithic pad-everything path in sharded_compact.py for
-table-level mesh compaction.  Three deltas over that path:
+Reached through the normal entry point — `FileStoreTable.compact(
+full=True)` on a table with `tpu.mesh.compact=true` (`compact/
+compact_action.py`; CLI, SQL `CALL compact` and the maintenance plane's
+`group_filter` alike) — or directly as `compact_table_mesh`.
 
-1. ENGINE DISPATCH.  The window kernel is parameterized on the table's
-   merge engine — deduplicate, partial-update (incl. sequence groups),
-   aggregation and first-row — instead of hard-coding the deduplicate
-   winner select.  Deduplicate/first-row consume the kernel's winner
-   mask directly; aggregation/partial-update feed the kernel's sorted
-   order + segment boundaries into the SAME aggregation epilogue the
-   single-chip path runs (ops/agg.py aggregate_sorted_segments), so
-   mesh output is row-identical to single-chip output by construction.
-   Any other engine raises UnsupportedMergeEngineError — never a
-   silent dedup.
+**What a run is.**  Lanes = the devices JAX finds (`bucket_mesh()`: 4
+on a four-chip v5e host, 1 on one chip, 8 virtual ones in the tests),
+one lane a device.  The buckets with work are packed onto the lanes by
+their manifest row counts (greedy LPT, `parallel/packing.py`; no file
+is read) and each lane works through its queue one bucket at a time.
+A bucket streams as key windows: its sorted runs decode in chunks of
+`tpu.mesh.window-rows` rows on one prefetch thread a run (the lane
+encode runs there too), and `ops/merge_stream.py iter_merge_windows`
+cuts a window at `tpu.merge.window-rows` rows a run, so a window holds
+at most runs x that many rows (8 x 256Ki = 2Mi by default) and a key
+never straddles two.  After the capped windows comes one at the natural
+bound and then a tail of a few rows each time a run ends.
 
-2. BOUNDED WINDOWS.  Buckets stream through the mesh in key windows
-   (ops/merge_stream.py iter_merge_windows lifted to [B, window]): each
-   mesh step stacks one window per device lane, so a 100M-row bucket
-   compacts under a host-RAM budget of ~ runs x window-rows per bucket
-   (Krueger et al., "Fast Updates on Read-Optimized Databases Using
-   Multi-Core CPUs": bounded multi-pass merges beat whole-table
-   materialization exactly here).  Window row counts pad to the next
-   power of two, so XLA compiles O(log) shapes per engine run.
+**The step loop** is ONE host thread in lock step: a step takes the
+next window of every lane's current bucket, stacks them padded to the
+largest one's power of two as `[lanes, n_pad(, L)]` uint32 operands
+(key lanes, sequence halves, validity, offset-value codes), uploads
+them with one `NamedSharding`, runs `shard_map(segmented merge)` — each
+chip sorts its one lane with `ops/merge.py segmented_merge_body`, the
+single-chip sort and winner select, plus a `psum` of the winners — downloads `perm` and `winner`,
+and takes and emits each lane's winners in turn (`ops/merge.py gather`;
+aggregation and partial-update feed the sorted order into the
+single-chip epilogue `ops/agg.py aggregate_sorted_segments`, so the
+output is row-identical by construction).  A lane whose bucket has
+drained, or whose tail is shorter than its neighbours', sorts padding
+in that step.  A window that holds a prefix-truncated key takes the
+exact host merge (`merge_runs`) instead.  Output files roll per bucket
+on the same thread as windows emit; one COMPACT snapshot at the end.
+Any merge engine without a kernel raises
+`UnsupportedMergeEngineError`, never a silent deduplicate.
 
-3. SKEW-AWARE PACKING.  Buckets pack onto mesh lanes by manifest row
-   counts with a greedy LPT bin-packer (parallel/packing.py) — one
-   lane per device — so a hot bucket no longer pads every lane to its
-   size; it occupies one lane while cold buckets share the rest.
+**Spans and counters** are the normal path's where the work is the
+same: `compact.table` (root) > `compact.task` (`route` mesh: the one
+task of this route, on the calling thread) > per step `merge.prep`
+(assembly), `compaction.window` > `merge.device` (`route` mesh: first
+upload to last download), `merge.gather` per lane; `merge.prep`,
+`decode`, `io.read` on the prefetch threads; `encode`, `io.upload` at
+each roll.  `PATH_COUNTS["device"]` counts a kernel window, and
+`compaction` / `mesh_steps`, `mesh_padded_rows` (lanes x n_pad of every
+step) say what the lock step cost.  Per operation that is a few spans a
+step and a chunk, never one a row.
 
-4. PER-BUCKET FAULT ISOLATION.  A bucket is the failure domain: a
-   transient error (object-store 503, injected IO fault, lane/device
-   loss) anywhere in one bucket's window stream aborts and retries
-   that bucket with capped decorrelated-jitter backoff
-   (compaction.retry.max-attempts / compaction.retry.backoff), then
-   degrades it to the single-chip compact/manager.py path
-   (compaction.mesh.fallback) instead of failing the whole job.
-   Partial output files of a failed attempt are deleted before the
-   retry, so the committed result is file-level identical to a
-   fault-free run.  Non-transient errors propagate immediately
-   (parallel/fault.py is the classification + policy).
+**On the chip** (PERF.md, PR 32; `dedup_compact_mesh4`): the 50M-row,
+8-bucket table of `chipbench/configs/mor50m-dedup-mesh4.json` on four
+v5e chips — see PERF.md sections 5 and 6 for the readings; the step
+loop's single thread, not the chips, sets the rate.
 
-The device still only ever sees fixed-width u32 normkey lanes + u64
-sequence halves (Graefe et al.'s offset-value-coding lesson: keep the
-comparison loop on fixed-width prefixes); variable-length Arrow data
-stays on host, and output files roll per bucket as windows emit.
+**Fault isolation.**  A bucket is the failure domain: a transient error
+(object-store 503, injected IO fault, lane or device loss) anywhere in
+one bucket's window stream aborts that bucket, deletes the files the
+attempt already rolled and requeues it with capped decorrelated-jitter
+backoff (`compaction.retry.max-attempts`, `compaction.retry.backoff`)
+while the other lanes keep streaming, then degrades it to the
+single-chip `compact/manager.py` path (`compaction.mesh.fallback`)
+instead of failing the job.  Non-transient errors propagate at once
+(`parallel/fault.py` classifies).
+
+The legacy `parallel/sharded_compact.py` (pad every bucket to the
+largest) is a second, older engine that no option reaches (ROADMAP D3).
 """
 
 from __future__ import annotations
@@ -94,20 +114,22 @@ class MeshCompactStats:
 
 
 # ---------------------------------------------------------------------------
-# window kernel: shard_map(vmap(segmented merge)) over [B, N]
+# window kernel: shard_map(segmented merge) over [lanes, N], a lane a device
 # ---------------------------------------------------------------------------
 
 _KERNEL_CACHE: dict = {}
 
 
 class _MeshWindowKernel:
-    """Engine-parameterized window merge over a [B, N] lane stack.
+    """Engine-parameterized window merge over a [B, N] lane stack, B the
+    mesh's devices: each sorts the one lane its shard holds.
 
-    __call__(lanes[B,N,L], seq_hi[B,N], seq_lo[B,N], invalid[B,N]) ->
-    (perm[B,N], winner[B,N], psum'd total winners).  `keep` selects the
-    winner row per key segment (last = dedup/partial-update/agg segment
-    ends, first = first-row); the first `num_key_lanes` lanes define
-    segment identity, further lanes are user-defined sequence order.
+    __call__(lanes[B,N,L], seq_hi[B,N], seq_lo[B,N], invalid[B,N],
+    ovc_off[B,N]) -> (perm[B,N], winner[B,N], psum'd total winners).
+    `keep` selects the winner row per key segment (last =
+    dedup/partial-update/agg segment ends, first = first-row); the first
+    `num_key_lanes` lanes define segment identity, further lanes are
+    user-defined sequence order.
     """
 
     def __init__(self, mesh, num_lanes: int, num_key_lanes: int,
@@ -133,11 +155,17 @@ class _MeshWindowKernel:
                            P(axis)),
                  out_specs=(P(axis), P(axis), P()))
         def step(lanes, seq_hi, seq_lo, invalid, ovc_off):
-            perm, winner = jax.vmap(per_lane)(lanes, seq_hi, seq_lo,
-                                              invalid, ovc_off)
+            # one lane a device, so a shard is [1, n_pad(, L)]: sorted
+            # as the 1-D program the single-chip path runs.  A vmap
+            # over the unit axis has XLA sort [1, n_pad] operands,
+            # 57.7 ms a 2Mi-row window on a v5e where the 1-D sort
+            # takes a fifth of that (PERF.md section 6, PR 32)
+            assert lanes.shape[0] == 1, lanes.shape
+            perm, winner = per_lane(lanes[0], seq_hi[0], seq_lo[0],
+                                    invalid[0], ovc_off[0])
             total = jax.lax.psum(
                 jnp.sum(winner.astype(jnp.int64)), axis)
-            return perm, winner, total.reshape(1)
+            return perm[None], winner[None], total.reshape(1)
 
         self._fn = jax.jit(step)
 
@@ -268,12 +296,43 @@ class _EngineContext:
             merged = self.live_filter(merged)
         return self.expire_filter(merged)
 
+    def window_operands(self, items):
+        """One lane's window as the step stacks it: (window table, lane
+        matrix with any user order lanes, int64 sequence, the items'
+        row offsets), or None where the window goes to the host merge —
+        it is empty, or holds a prefix-truncated key."""
+        import pyarrow as pa
+
+        from paimon_tpu.ops.merge import SEQ_COL, user_seq_order_lanes
+
+        wtable = pa.concat_tables([it[0] for it in items],
+                                  promote_options="none") \
+            if len(items) > 1 else items[0][0]
+        if wtable.num_rows == 0 or \
+                any(np.asarray(it[2]).any() for it in items):
+            return None
+        lanes_mat = np.concatenate([np.asarray(it[1]) for it in items]) \
+            if len(items) > 1 else np.asarray(items[0][1])
+        if self.seq_fields:
+            lanes_mat = np.concatenate(
+                [lanes_mat, user_seq_order_lanes(
+                    wtable, self.seq_fields, self.seq_desc)], axis=1)
+        seq = np.asarray(wtable.column(SEQ_COL).combine_chunks()
+                         .cast("int64"))
+        # each window item is one sorted-run piece: its offset-value
+        # codes ride to the device so the kernel's winner-select
+        # consumes the single-int offsets first
+        item_starts = np.concatenate(
+            [[0], np.cumsum([it[0].num_rows for it in items])]
+        ).astype(np.int64)
+        return wtable, lanes_mat, seq, item_starts
+
     def merge_window_device(self, wtable, perm_row: np.ndarray,
                             winner_row: np.ndarray):
         """Fold one window given the mesh kernel's sorted order."""
         import pyarrow as pa
 
-        from paimon_tpu.ops.merge import KIND_COL
+        from paimon_tpu.ops.merge import KIND_COL, gather
         from paimon_tpu.types import RowKind
 
         n = wtable.num_rows
@@ -284,7 +343,7 @@ class _EngineContext:
                                .cast(pa.int8()))
             keep_mask = (kinds[indices] == RowKind.INSERT) | \
                         (kinds[indices] == RowKind.UPDATE_AFTER)
-            merged = wtable.take(pa.array(indices[keep_mask]))
+            merged = gather(wtable, indices[keep_mask])
             return self.expire_filter(merged)
         # aggregation / partial-update: kernel order + segment ends feed
         # the shared single-chip aggregation epilogue
@@ -304,6 +363,33 @@ class _EngineContext:
             wtable, order, seg_id, win_sorted, self.key_cols,
             self.schema, self.options)
         return self.expire_filter(self.live_filter(merged))
+
+
+def _stack_windows(device_rows, n_pad: int, num_lanes: int):
+    """The step's kernel operands: every lane's window padded to
+    `n_pad` rows and stacked `[lanes, n_pad(, L)]`; a lane with no
+    window this step (drained, backing off, host-merged) is all
+    padding, which sorts last and wins nothing."""
+    from paimon_tpu.ops.ovc import OVC_OFF_SENTINEL, run_ovc_offsets
+
+    n_dev = len(device_rows)
+    lanes_arr = np.zeros((n_dev, n_pad, num_lanes), dtype=np.uint32)
+    seq_hi = np.zeros((n_dev, n_pad), dtype=np.uint32)
+    seq_lo = np.zeros((n_dev, n_pad), dtype=np.uint32)
+    invalid = np.ones((n_dev, n_pad), dtype=np.uint32)
+    ovc_arr = np.full((n_dev, n_pad), OVC_OFF_SENTINEL, dtype=np.uint32)
+    for li, entry in enumerate(device_rows):
+        if entry is None:
+            continue
+        _, wtable, lanes_mat, seq, item_starts = entry
+        k = wtable.num_rows
+        lanes_arr[li, :k] = lanes_mat
+        u = seq.astype(np.int64).view(np.uint64)
+        seq_hi[li, :k] = (u >> np.uint64(32)).astype(np.uint32)
+        seq_lo[li, :k] = (u & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+        invalid[li, :k] = 0
+        ovc_arr[li, :k] = run_ovc_offsets(lanes_arr[li, :k], item_starts)
+    return lanes_arr, seq_hi, seq_lo, invalid, ovc_arr
 
 
 class _BucketJob:
@@ -334,9 +420,16 @@ class _BucketJob:
         from paimon_tpu.format import get_format
 
         from paimon_tpu.fs.caching import scoped_batches
+        from paimon_tpu.ops.merge import prep_span
 
         ctx = self.ctx
         options = ctx.table.options
+
+        def encode(t):
+            with prep_span(t.num_rows):
+                return (t, *ctx.key_encoder.encode_table_ex(
+                    t, ctx.key_cols))
+
         for f in run_files:
             if ctx.has_blobs:
                 t = read_kv_file(ctx.table.file_io, ctx.path_factory,
@@ -347,8 +440,7 @@ class _BucketJob:
                 t = evolve_table(t, f.schema_id, ctx.schema,
                                  ctx.schema_manager, ctx.schema_cache,
                                  keep_sys_cols=True)
-                yield (t, *ctx.key_encoder.encode_table_ex(
-                    t, ctx.key_cols))
+                yield encode(t)
                 continue
             ext = f.file_name.rsplit(".", 1)[-1]
             fmt = get_format(ext)
@@ -379,8 +471,7 @@ class _BucketJob:
                             batch, f.schema_id, ctx.schema,
                             ctx.schema_manager, ctx.schema_cache,
                             keep_sys_cols=True)
-                        yield (t, *ctx.key_encoder.encode_table_ex(
-                            t, ctx.key_cols))
+                        yield encode(t)
                     continue
             # gate held only while advancing the inner iterator (see
             # fs.caching.scoped_batches), never across our yields
@@ -391,8 +482,7 @@ class _BucketJob:
                 t = evolve_table(batch, f.schema_id, ctx.schema,
                                  ctx.schema_manager, ctx.schema_cache,
                                  keep_sys_cols=True)
-                yield (t, *ctx.key_encoder.encode_table_ex(
-                    t, ctx.key_cols))
+                yield encode(t)
 
     def next_window(self):
         """Next run-ordered item list, or None when the bucket drains."""
@@ -495,7 +585,7 @@ def compact_table_mesh(table, mesh=None, axis: str = "buckets",
         COMPACTION_BUCKET_FAILURES, COMPACTION_BUCKET_FALLBACKS,
         COMPACTION_BUCKET_RETRIES, global_registry,
     )
-    from paimon_tpu.ops.merge import SEQ_COL, _pad_size
+    from paimon_tpu.ops.merge import _pad_size
     from paimon_tpu.parallel.fault import (
         BucketRetryPolicy, is_transient_error,
     )
@@ -665,110 +755,69 @@ def compact_table_mesh(table, mesh=None, axis: str = "buckets",
         fault_metrics.counter(COMPACTION_BUCKET_FAILURES).inc()
         raise exc
 
-    import pyarrow as pa
+    from paimon_tpu.compact.compact_action import table_span
+    from paimon_tpu.metrics import (
+        COMPACTION_DURATION_MS, COMPACTION_MESH_PADDED_ROWS,
+        COMPACTION_MESH_STEPS, COMPACTION_WINDOW_MS,
+    )
+    from paimon_tpu.ops.merge import PATH_COUNTS, device_span, prep_span
 
     kernel = _window_kernel(mesh, ctx.num_lanes, ctx.num_key_lanes,
                             ctx.keep, axis)
-    while True:
-        step: List[Optional[Tuple]] = []
-        for li, lane in enumerate(lanes_state):
-            try:
-                step.append(lane.next_window(finalize))
-            except Exception as e:          # noqa: BLE001
-                failed = lane.current
-                if failed is None:
-                    raise
-                _handle_bucket_failure(li, failed, e)
-                step.append(None)
-        if all(w is None for w in step):
-            deadlines = [j.ready_at for lane in lanes_state
-                         for j in lane.queue]
-            if not deadlines and all(lane.current is None
-                                     for lane in lanes_state):
-                break
-            # nothing runnable anywhere: every remaining job is inside
-            # its backoff window — sleep to the earliest deadline
-            # instead of spinning (only here does the loop ever wait)
-            if deadlines:
-                wait = min(deadlines) - _time.monotonic()
-                if wait > 0:
-                    from paimon_tpu.utils.backoff import wait_for
-                    with _obs_span("compaction.backoff_wait",
-                                   cat="compaction",
-                                   pending=len(deadlines)):
-                        wait_for(wait, what="compaction backoff")
-            continue
-        # assemble each active lane's window; truncated-key windows take
-        # the exact host merge instead of the device kernel
+
+    def run_step(step: List[Optional[Tuple]]) -> None:
+        """One lock-step mesh step over the lanes' current windows:
+        assemble, sort on the chips, take and emit each lane's winners.
+        A window with a prefix-truncated key takes the exact host merge
+        instead of the kernel."""
         device_rows: List[Optional[Tuple]] = [None] * n_dev
-        n_max = 0
-        for li, item in enumerate(step):
-            if item is None:
-                continue
-            job, items = item
-            try:
-                wtable = pa.concat_tables([it[0] for it in items],
-                                          promote_options="none") \
-                    if len(items) > 1 else items[0][0]
-                trunc_any = any(np.asarray(it[2]).any() for it in items)
-                if trunc_any or wtable.num_rows == 0:
-                    job.emit(ctx.merge_window_host(items))
+        host_windows: List[Tuple] = []
+        failures: List[Tuple] = []
+        stacks = None
+        with prep_span(sum(it[0].num_rows for item in step
+                           if item is not None for it in item[1])):
+            for li, item in enumerate(step):
+                if item is None:
                     continue
-                lanes_mat = np.concatenate([np.asarray(it[1])
-                                            for it in items]) \
-                    if len(items) > 1 else np.asarray(items[0][1])
-                if ctx.seq_fields:
-                    from paimon_tpu.ops.merge import user_seq_order_lanes
-                    order_lanes = user_seq_order_lanes(
-                        wtable, ctx.seq_fields, ctx.seq_desc)
-                    lanes_mat = np.concatenate([lanes_mat, order_lanes],
-                                               axis=1)
-                seq = np.asarray(wtable.column(SEQ_COL).combine_chunks()
-                                 .cast("int64"))
-                # each window item is one sorted-run piece: its
-                # offset-value codes ride to the device so the kernel's
-                # winner-select consumes the single-int offsets first
-                item_starts = np.concatenate(
-                    [[0], np.cumsum([it[0].num_rows
-                                     for it in items])]).astype(np.int64)
+                job, items = item
+                try:
+                    operands = ctx.window_operands(items)
+                except Exception as e:      # noqa: BLE001
+                    failures.append((li, job, e))
+                    continue
+                if operands is None:
+                    host_windows.append((li, job, items))
+                else:
+                    device_rows[li] = (job, *operands)
+            n_max = max((e[1].num_rows for e in device_rows
+                         if e is not None), default=0)
+            if n_max:
+                n_pad = _pad_size(n_max)
+                stacks = _stack_windows(device_rows, n_pad, ctx.num_lanes)
+        # outside the span: a failed bucket may run its whole single-chip
+        # fallback here, and the host merge opens `merge_runs`' own spans
+        for li, job, e in failures:
+            _handle_bucket_failure(li, job, e)
+        for li, job, items in host_windows:
+            try:
+                job.emit(ctx.merge_window_host(items))
             except Exception as e:          # noqa: BLE001
                 _handle_bucket_failure(li, job, e)
-                continue
-            device_rows[li] = (job, wtable, lanes_mat, seq, item_starts)
-            n_max = max(n_max, wtable.num_rows)
-        if n_max == 0:
-            continue
-        from paimon_tpu.ops.ovc import OVC_OFF_SENTINEL, run_ovc_offsets
-        n_pad = _pad_size(n_max)
-        lanes_arr = np.zeros((n_dev, n_pad, ctx.num_lanes),
-                             dtype=np.uint32)
-        seq_hi = np.zeros((n_dev, n_pad), dtype=np.uint32)
-        seq_lo = np.zeros((n_dev, n_pad), dtype=np.uint32)
-        invalid = np.ones((n_dev, n_pad), dtype=np.uint32)
-        ovc_arr = np.full((n_dev, n_pad), OVC_OFF_SENTINEL,
-                          dtype=np.uint32)
-        for li, entry in enumerate(device_rows):
-            if entry is None:
-                continue
-            _, wtable, lanes_mat, seq, item_starts = entry
-            k = wtable.num_rows
-            lanes_arr[li, :k] = lanes_mat
-            u = seq.astype(np.int64).view(np.uint64)
-            seq_hi[li, :k] = (u >> np.uint64(32)).astype(np.uint32)
-            seq_lo[li, :k] = (u & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-            invalid[li, :k] = 0
-            ovc_arr[li, :k] = run_ovc_offsets(lanes_arr[li, :k],
-                                              item_starts)
+        if stacks is None:
+            return
+        real = [e for e in device_rows if e is not None]
+        rows = sum(e[1].num_rows for e in real)
+        slots = n_dev * n_pad
         try:
-            from paimon_tpu.metrics import COMPACTION_WINDOW_MS
             with _obs_span("compaction.window", cat="compaction",
                            group="compaction",
                            metric=COMPACTION_WINDOW_MS,
-                           lanes=sum(1 for e in device_rows
-                                     if e is not None),
-                           rows=n_max, table=table.path):
-                perm, winner, _ = kernel(lanes_arr, seq_hi, seq_lo,
-                                         invalid, ovc_arr)
+                           lanes=len(real), rows=n_max,
+                           table=table.path), \
+                    device_span("mesh", rows, slots,
+                                4 * slots * (ctx.num_lanes + 4),
+                                5 * slots, lanes=len(real)):
+                perm, winner, _ = kernel(*stacks)
         except Exception as e:              # noqa: BLE001
             if not is_transient_error(e):
                 # a program the compiler refuses (or a bug) is not a
@@ -780,7 +829,10 @@ def compact_table_mesh(table, mesh=None, axis: str = "buckets",
             for li, entry in enumerate(device_rows):
                 if entry is not None:
                     _handle_bucket_failure(li, entry[0], e)
-            continue
+            return
+        PATH_COUNTS["device"] += len(real)
+        fault_metrics.counter(COMPACTION_MESH_STEPS).inc()
+        fault_metrics.counter(COMPACTION_MESH_PADDED_ROWS).inc(slots)
         for li, entry in enumerate(device_rows):
             if entry is None:
                 continue
@@ -794,6 +846,49 @@ def compact_table_mesh(table, mesh=None, axis: str = "buckets",
             stats.windows += 1
             stats.peak_window_rows = max(stats.peak_window_rows,
                                          wtable.num_rows)
+
+    def run_lanes() -> None:
+        while True:
+            step: List[Optional[Tuple]] = []
+            for li, lane in enumerate(lanes_state):
+                try:
+                    step.append(lane.next_window(finalize))
+                except Exception as e:      # noqa: BLE001
+                    failed = lane.current
+                    if failed is None:
+                        raise
+                    _handle_bucket_failure(li, failed, e)
+                    step.append(None)
+            if any(w is not None for w in step):
+                run_step(step)
+                continue
+            deadlines = [j.ready_at for lane in lanes_state
+                         for j in lane.queue]
+            if not deadlines and all(lane.current is None
+                                     for lane in lanes_state):
+                return
+            # nothing runnable anywhere: every remaining job is inside
+            # its backoff window — sleep to the earliest deadline
+            # instead of spinning (only here does the loop ever wait)
+            if deadlines:
+                wait = min(deadlines) - _time.monotonic()
+                if wait > 0:
+                    from paimon_tpu.utils.backoff import wait_for
+                    with _obs_span("compaction.backoff_wait",
+                                   cat="compaction",
+                                   pending=len(deadlines)):
+                        wait_for(wait, what="compaction backoff")
+
+    # the group phase, as `compact_table`'s other route has it: the root
+    # and, inside it, the one task it hands out — the mesh run, here on
+    # the calling thread (`duration_ms` / `table_ms` reads 1)
+    with table_span(stats.buckets, n_dev, stats.input_rows), \
+            _obs_span("compact.task", cat="compaction",
+                      group="compaction", metric=COMPACTION_DURATION_MS,
+                      route="mesh", lanes=n_dev, buckets=stats.buckets,
+                      rows=stats.input_rows, lane_rows=stats.lane_rows,
+                      skew=stats.skew):
+        run_lanes()
 
     if not messages:
         _trace.maybe_export()
