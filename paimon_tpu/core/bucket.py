@@ -7,14 +7,16 @@ bit-for-bit keeps our data files bucket-compatible with JVM/pypaimon
 readers and writers.
 
 The hash is vectorized over rows with numpy when the bucket key serializes
-to fixed-width BinaryRows (int/float/date keys); variable-width keys fall
-back to a per-row loop.
+to fixed-width BinaryRows (int/float/date keys): 32-bit words read from the
+key columns' own values, mixed in uint32 (which wraps as Java's int does),
+a cache-sized block of rows at a time.  Variable-width keys fall back to a
+per-row loop, which is also the reference the tests hold the fast path to.
 """
 
 from __future__ import annotations
 
 import struct
-from typing import Any, List, Sequence, Tuple
+from typing import Any, List, Optional, Sequence, Tuple
 
 import numpy as np
 import pyarrow as pa
@@ -34,19 +36,23 @@ _SEED = 42
 _M32 = 0xFFFFFFFF
 
 
+def _mix_word(h1: int, k1: int) -> int:
+    """One murmur round: the 32-bit word `k1` into the state `h1`."""
+    k1 = (k1 * _C1) & _M32
+    k1 = ((k1 << 15) | (k1 >> 17)) & _M32
+    k1 = (k1 * _C2) & _M32
+    h1 = (h1 ^ k1) & _M32
+    h1 = ((h1 << 13) | (h1 >> 19)) & _M32
+    return (h1 * 5 + 0xE6546B64) & _M32
+
+
 def murmur_hash_bytes(data: bytes, seed: int = _SEED) -> int:
     """Murmur3-style word hash over complete 4-byte words (tail bytes
     ignored, matching the reference's hashBytesByWords)."""
     n = len(data)
     h1 = seed
     for i in range(0, n - (n % 4), 4):
-        k1 = struct.unpack_from("<I", data, i)[0]
-        k1 = (k1 * _C1) & _M32
-        k1 = ((k1 << 15) | (k1 >> 17)) & _M32
-        k1 = (k1 * _C2) & _M32
-        h1 = (h1 ^ k1) & _M32
-        h1 = ((h1 << 13) | (h1 >> 19)) & _M32
-        h1 = (h1 * 5 + 0xE6546B64) & _M32
+        h1 = _mix_word(h1, struct.unpack_from("<I", data, i)[0])
     return _fmix(h1, n)
 
 
@@ -61,12 +67,11 @@ def _fmix(h1: int, length: int) -> int:
 
 
 def _bucket_from_hash(h: np.ndarray, num_buckets: int) -> np.ndarray:
-    """Java `Math.abs(h % n)` with truncated division, vectorized."""
-    signed = h.astype(np.int64)
-    signed = np.where(signed >= 1 << 31, signed - (1 << 32), signed)
-    rem = signed - np.trunc(signed / num_buckets).astype(np.int64) \
-        * num_buckets
-    return np.abs(rem).astype(np.int32)
+    """Java `Math.abs(h % n)` on the hash as an int: int32[N].  `h` is an
+    unsigned array whose low 32 bits are the hash; `np.fmod` truncates
+    toward zero as Java's `%` does, and |remainder| < n fits an int32."""
+    signed = h.astype(np.uint32, copy=False).view(np.int32)
+    return np.abs(np.fmod(signed, np.int32(num_buckets)))
 
 
 def bucket_of(values: Sequence[Any], types: Sequence[DataType],
@@ -78,8 +83,113 @@ def bucket_of(values: Sequence[Any], types: Sequence[DataType],
                                  num_buckets)[0])
 
 
-_FIXED_SLOT_TYPES = (BooleanType, TinyIntType, SmallIntType, IntType,
-                     BigIntType, FloatType, DoubleType, DateType, TimeType)
+# fixed-width key types: the Arrow type whose values buffer is the
+# BinaryRow slot's bytes, and those bytes as one little-endian number
+_FIXED_SLOTS = (
+    ((BooleanType,), pa.uint8(), "u1"),
+    ((TinyIntType,), pa.int8(), "u1"),
+    ((SmallIntType,), pa.int16(), "<u2"),
+    ((IntType, DateType, TimeType), pa.int32(), "<u4"),
+    ((BigIntType,), pa.int64(), "<u8"),
+    ((FloatType,), pa.float32(), "<u4"),
+    ((DoubleType,), pa.float64(), "<u8"),
+)
+_FIXED_SLOT_TYPES = tuple(t for types, _, _ in _FIXED_SLOTS for t in types)
+
+# rows hashed at a time: the block's state, word and scratch arrays
+# (256 KB each) stay in cache through the ~30 passes a row takes;
+# 64Ki measured fastest of 8Ki..whole-batch at 3.2M rows
+_BLOCK_ROWS = 1 << 16
+
+
+def _slot_words(col: pa.ChunkedArray, t: DataType
+                ) -> Tuple[np.ndarray, Optional[np.ndarray],
+                           Optional[np.ndarray]]:
+    """One key column as the two 32-bit words of its 8-byte BinaryRow
+    slot: (low, high, nulls).  The words are views of the column's own
+    values (a 1/2/4-byte type is its value zero-extended and a high word
+    that is zero in every row: None); `nulls` is bool[N] where the
+    column holds a null, under which the values are arbitrary."""
+    arr = col.chunk(0) if col.num_chunks == 1 else col.combine_chunks()
+    arrow_type, word = next((a, w) for types, a, w in _FIXED_SLOTS
+                            if isinstance(t, types))
+    if arr.type != arrow_type:
+        arr = arr.cast(arrow_type)
+    vals = np.frombuffer(arr.buffers()[1], dtype=word, count=len(arr),
+                         offset=arr.offset * np.dtype(word).itemsize)
+    nulls = arr.is_null().to_numpy(zero_copy_only=False) \
+        if arr.null_count else None
+    if vals.itemsize == 8:
+        halves = vals.view("<u4")
+        return halves[0::2], halves[1::2], nulls
+    return vals, None, nulls
+
+
+# A word of the rows is a loader — load(start, end, out) writes the
+# block's `word * C1` (the first step of its round) into `out` — or None
+# where the word is zero in every row of the batch.
+
+def _value_word(values: np.ndarray, nulls: Optional[np.ndarray]):
+    def load(s: int, e: int, out: np.ndarray):
+        np.multiply(values[s:e], np.uint32(_C1), out=out)
+        if nulls is not None:
+            out[nulls[s:e]] = 0          # a null's slot is zero
+    return load
+
+
+def _null_word(bits: List[Tuple[np.ndarray, np.uint32]]):
+    def load(s: int, e: int, out: np.ndarray):
+        out.fill(0)
+        for nulls, bit in bits:
+            np.bitwise_or(out, bit, out=out, where=nulls[s:e])
+        np.multiply(out, np.uint32(_C1), out=out)
+    return load
+
+
+def _rotl(x: np.ndarray, r: int, tmp: np.ndarray):
+    np.left_shift(x, np.uint32(r), out=tmp)
+    np.right_shift(x, np.uint32(32 - r), out=x)
+    np.bitwise_or(x, tmp, out=x)
+
+
+def _xorshift(x: np.ndarray, r: int, tmp: np.ndarray):
+    np.right_shift(x, np.uint32(r), out=tmp)
+    np.bitwise_xor(x, tmp, out=x)
+
+
+def _murmur_words(words: List, n: int, row_len: int) -> np.ndarray:
+    """`murmur_hash_bytes` of N rows of `row_len` bytes given as their
+    words: uint32 arithmetic in place (it wraps as Java's int does),
+    `_BLOCK_ROWS` rows at a time; leading all-zero words are mixed into
+    the starting state once."""
+    h0 = _SEED
+    while words and words[0] is None:
+        h0 = _mix_word(h0, 0)
+        words = words[1:]
+    out = np.empty(n, dtype=np.uint32)
+    k_buf = np.empty(min(n, _BLOCK_ROWS), dtype=np.uint32)
+    t_buf = np.empty_like(k_buf)
+    u32 = np.uint32
+    for s in range(0, n, _BLOCK_ROWS):
+        e = min(s + _BLOCK_ROWS, n)
+        h1, k1, tmp = out[s:e], k_buf[:e - s], t_buf[:e - s]
+        h1.fill(h0)
+        for load in words:
+            if load is not None:         # a zero word leaves h1 ^ 0
+                load(s, e, k1)
+                _rotl(k1, 15, tmp)
+                np.multiply(k1, u32(_C2), out=k1)
+                np.bitwise_xor(h1, k1, out=h1)
+            _rotl(h1, 13, tmp)
+            np.multiply(h1, u32(5), out=h1)
+            np.add(h1, u32(0xE6546B64), out=h1)
+        np.bitwise_xor(h1, u32(row_len), out=h1)     # _fmix
+        _xorshift(h1, 16, tmp)
+        np.multiply(h1, u32(0x85EBCA6B), out=h1)
+        _xorshift(h1, 13, tmp)
+        np.multiply(h1, u32(0xC2B2AE35), out=h1)
+        _xorshift(h1, 16, tmp)
+    return out
 
 
 class KeyHasher:
@@ -95,14 +205,14 @@ class KeyHasher:
                                 for t in self.types)
 
     def hashes(self, table: pa.Table) -> np.ndarray:
-        """uint64[N] murmur hashes (low 32 bits significant)."""
-        # the numpy path's fixed setup (byte matrix + casts) costs more
-        # than row-at-a-time hashing below ~10 rows — point-lookup
-        # batches take the scalar codec path, ingest batches the
-        # vectorized one; both produce identical reference hashes
+        """uint32[N] murmur hashes."""
+        # the numpy path's fixed setup (views, scratch, ~30 calls a
+        # block) costs more than row-at-a-time hashing below ~10 rows —
+        # point-lookup batches take the scalar codec path, ingest batches
+        # the vectorized one; both produce identical reference hashes
         if self._fixed_width and table.num_rows > 8:
             return self._hash_vectorized(table)
-        return self._hash_rows(table)
+        return self._hash_rows(table).astype(np.uint32)
 
     def _hash_rows(self, table: pa.Table) -> np.ndarray:
         cols = [table.column(n).to_pylist() for n in self.names]
@@ -114,69 +224,26 @@ class KeyHasher:
         return out
 
     def _hash_vectorized(self, table: pa.Table) -> np.ndarray:
-        """Build the BinaryRow byte matrix for all rows at once, then run
-        murmur word-mixing across rows with numpy."""
-        n = table.num_rows
+        """The BinaryRow's words — the null-bit words, then two a key
+        slot — without the rows' bytes: words that are zero in every
+        row of this batch (the null bits of a batch without nulls, the
+        high word of a narrow type) cost no pass over the rows."""
         arity = len(self.types)
-        null_bytes = ((arity + 63 + 8) // 64) * 8
-        row_len = null_bytes + arity * 8
-        mat = np.zeros((n, row_len), dtype=np.uint8)
+        null_words = ((arity + 63 + 8) // 64) * 2
+        null_bits: List[list] = [[] for _ in range(null_words)]
+        slots: List = []
         for i, (name, t) in enumerate(zip(self.names, self.types)):
-            col = table.column(name).combine_chunks()
-            null_mask = np.asarray(col.is_null())
-            slot = null_bytes + i * 8
-            if isinstance(t, (BooleanType,)):
-                vals = np.asarray(col.cast(pa.int8()).fill_null(0))
-                mat[:, slot] = vals.astype(np.uint8)
-            elif isinstance(t, TinyIntType):
-                v = np.asarray(col.fill_null(0)).astype(np.int8)
-                mat[:, slot:slot + 1] = v.view(np.uint8)[:, None]
-            elif isinstance(t, SmallIntType):
-                v = np.asarray(col.fill_null(0)).astype("<i2")
-                mat[:, slot:slot + 2] = v.view(np.uint8).reshape(n, 2)
-            elif isinstance(t, (IntType, DateType, TimeType)):
-                v = np.asarray(col.cast(pa.int32()).fill_null(0)) \
-                    .astype("<i4")
-                mat[:, slot:slot + 4] = v.view(np.uint8).reshape(n, 4)
-            elif isinstance(t, BigIntType):
-                v = np.asarray(col.cast(pa.int64()).fill_null(0)) \
-                    .astype("<i8")
-                mat[:, slot:slot + 8] = v.view(np.uint8).reshape(n, 8)
-            elif isinstance(t, FloatType):
-                v = np.asarray(col.fill_null(0)).astype("<f4")
-                mat[:, slot:slot + 4] = v.view(np.uint8).reshape(n, 4)
-            elif isinstance(t, DoubleType):
-                v = np.asarray(col.fill_null(0)).astype("<f8")
-                mat[:, slot:slot + 8] = v.view(np.uint8).reshape(n, 8)
-            if null_mask.any():
-                idx = i + 8
-                mat[null_mask, idx // 8] |= np.uint8(1 << (idx % 8))
-                mat[null_mask, slot:slot + 8] = 0
-        return self._murmur_rows(mat)
-
-    def _murmur_rows(self, mat: np.ndarray) -> np.ndarray:
-        n, row_len = mat.shape
-        if n == 0:
-            return np.empty(0, dtype=np.uint64)
-        words = mat[:, :row_len - (row_len % 4)] \
-            .reshape(n, -1, 4).view("<u4")[:, :, 0].astype(np.uint64)
-        h1 = np.full(n, _SEED, dtype=np.uint64)
-        m32 = np.uint64(_M32)
-        for w in range(words.shape[1]):
-            k1 = words[:, w]
-            k1 = (k1 * np.uint64(_C1)) & m32
-            k1 = ((k1 << np.uint64(15)) | (k1 >> np.uint64(17))) & m32
-            k1 = (k1 * np.uint64(_C2)) & m32
-            h1 = (h1 ^ k1) & m32
-            h1 = ((h1 << np.uint64(13)) | (h1 >> np.uint64(19))) & m32
-            h1 = (h1 * np.uint64(5) + np.uint64(0xE6546B64)) & m32
-        h1 = (h1 ^ np.uint64(row_len)) & m32
-        h1 ^= h1 >> np.uint64(16)
-        h1 = (h1 * np.uint64(0x85EBCA6B)) & m32
-        h1 ^= h1 >> np.uint64(13)
-        h1 = (h1 * np.uint64(0xC2B2AE35)) & m32
-        h1 ^= h1 >> np.uint64(16)
-        return h1
+            low, high, nulls = _slot_words(table.column(name), t)
+            if nulls is not None:
+                bit = i + 8              # after the 8 header bits
+                null_bits[bit // 32].append(
+                    (nulls, np.uint32(1 << (bit % 32))))
+            slots.append(_value_word(low, nulls))
+            slots.append(None if high is None
+                         else _value_word(high, nulls))
+        words = [_null_word(bits) if bits else None
+                 for bits in null_bits] + slots
+        return _murmur_words(words, table.num_rows, len(words) * 4)
 
 
 class FixedBucketAssigner:
@@ -192,5 +259,7 @@ class FixedBucketAssigner:
         self._hasher = KeyHasher(bucket_key_names, bucket_key_types)
 
     def assign(self, table: pa.Table) -> np.ndarray:
+        if self.num_buckets == 1:        # every hash lands in bucket 0
+            return np.zeros(table.num_rows, dtype=np.int32)
         return _bucket_from_hash(self._hasher.hashes(table),
                                  self.num_buckets)

@@ -59,32 +59,56 @@ class CommitMessage:
                     or self.compact_changelog or self.index_entries)
 
 
+# a combined (bucket, partition...) group code stays below this, so
+# every step of building it stays inside an int64
+_MAX_GROUP_CODE = 1 << 62
+
+
 def group_by_partition_bucket(table: pa.Table, buckets: np.ndarray,
                               partition_keys: Sequence[str]):
     """Split rows into (partition_tuple, bucket) groups.
     Returns [((part, bucket), row_indices)] — shared by the pk and
-    append write paths (reference RowKeyExtractor + ChannelComputer)."""
-    group_codes = [buckets]
-    part_dicts = []
+    append write paths (reference RowKeyExtractor + ChannelComputer).
+    Groups ascend by bucket, then by each partition key's order of
+    first appearance; a group's indices ascend.  A batch that is one
+    group gets `arange(N)`: its rows are the batch, in its order."""
+    n = len(buckets)
+    if n == 0:
+        return []
+    lo, hi = int(buckets.min()), int(buckets.max())
+    # one integer code a row: the bucket, then each partition key's
+    # dictionary index, most significant first
+    codes = buckets if lo == 0 else buckets.astype(np.int64) - lo
+    card = hi - lo + 1
+    parts = []
     for pk in partition_keys:
         enc = table.column(pk).combine_chunks().dictionary_encode()
-        part_dicts.append(enc.dictionary)
-        group_codes.append(np.asarray(enc.indices))
-    if len(group_codes) == 1:
-        uniq, inverse = np.unique(buckets, return_inverse=True)
-        groups = [((), int(b)) for b in uniq]
+        if enc.indices.null_count:
+            raise ValueError(f"partition key {pk!r} holds a null")
+        indices = np.asarray(enc.indices)
+        parts.append((enc.dictionary, indices))
+        if card * len(enc.dictionary) > _MAX_GROUP_CODE:
+            codes = np.unique(codes, return_inverse=True)[1]
+            card = int(codes.max()) + 1
+        codes = codes.astype(np.int64, copy=False) * len(enc.dictionary) \
+            + indices
+        card *= len(enc.dictionary)
+    if card == 1 or (parts and codes.min() == codes.max()):
+        order, starts = np.arange(n), [0, n]
     else:
-        stacked = np.stack(group_codes, axis=1)
-        uniq, inverse = np.unique(stacked, axis=0, return_inverse=True)
-        groups = []
-        for row in uniq:
-            part = tuple(part_dicts[i][int(row[i + 1])].as_py()
-                         for i in range(len(partition_keys)))
-            groups.append((part, int(row[0])))
-    order = np.argsort(inverse, kind="stable")
-    bounds = np.searchsorted(inverse[order], np.arange(len(groups) + 1))
-    return [(groups[gi], order[bounds[gi]:bounds[gi + 1]])
-            for gi in range(len(groups))]
+        # the narrowest unsigned type that holds the codes: numpy's
+        # stable argsort of 8- and 16-bit integers is a radix sort
+        codes = codes.astype(np.min_scalar_type(card - 1), copy=False)
+        order = np.argsort(codes, kind="stable")
+        ranked = codes[order]
+        starts = [0, *(np.flatnonzero(ranked[1:] != ranked[:-1]) + 1), n]
+    out = []
+    for start, end in zip(starts[:-1], starts[1:]):
+        first = order[start]
+        part = tuple(dictionary[int(indices[first])].as_py()
+                     for dictionary, indices in parts)
+        out.append(((part, int(buckets[first])), order[start:end]))
+    return out
 
 
 def build_kv_table(raw: pa.Table, schema: TableSchema,
@@ -797,20 +821,33 @@ class KeyValueFileStoreWrite:
         # previous batch routes — the "incoming batch's hash overlaps
         # bucket flushes" leg of the pipeline.  Routing (and therefore
         # sequence reservation) stays on this thread, in batch order.
-        def prep(table=table, kinds=row_kinds,
+        # The kinds are copied here, on the caller: prep may run after
+        # write_arrow has returned and the caller reuses its array.
+        def prep(table=table, kinds=row_kinds.copy(),
                  pre=precomputed_buckets):
-            from paimon_tpu.metrics import WRITE_ROUTE_MS
-            from paimon_tpu.obs.trace import span
+            from paimon_tpu.metrics import (
+                WRITE_ROUTE_MS, WRITE_ROUTE_NOCOPY_ROWS, global_registry,
+            )
+            from paimon_tpu.obs.trace import metrics_enabled, span
             with span("write.route", cat="write", group="write",
-                      metric=WRITE_ROUTE_MS, rows=table.num_rows):
+                      metric=WRITE_ROUTE_MS, rows=table.num_rows) as sp:
                 buckets = pre if pre is not None \
                     else self.bucket_assigner.assign(table)
-                out = []
-                for (part, bucket), idx in lpt_order(
-                        group_by_partition_bucket(
-                            table, buckets, self.partition_keys)):
-                    out.append(((part, bucket),
-                                table.take(pa.array(idx)), kinds[idx]))
+                groups = lpt_order(group_by_partition_bucket(
+                    table, buckets, self.partition_keys))
+                if len(groups) == 1:
+                    # the group is the batch: no take (an Arrow table
+                    # is immutable, the writer may keep the caller's)
+                    out, copied = [(groups[0][0], table, kinds)], 0
+                else:
+                    out = [(key, table.take(pa.array(idx)), kinds[idx])
+                           for key, idx in groups]
+                    copied = table.num_rows
+                sp.set(groups=len(groups), copied_rows=copied)
+                if metrics_enabled():
+                    global_registry().write_metrics().counter(
+                        WRITE_ROUTE_NOCOPY_ROWS).inc(
+                            table.num_rows - copied)
                 return out
 
         pool = self._prep_executor()
@@ -820,7 +857,8 @@ class KeyValueFileStoreWrite:
         from paimon_tpu.obs.trace import carry
         self._prep.append(pool.submit(carry(prep)))
         # bounded lookahead: at most 4 batches prepped ahead (each holds
-        # a batch-sized copy), routed strictly in submission order
+        # its batch, and a copy of it when it fell into several groups),
+        # routed strictly in submission order
         while len(self._prep) > 4:
             self._route(wait_future(self._prep.popleft(),
                                     "write prep backpressure"))

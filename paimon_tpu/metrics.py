@@ -34,7 +34,7 @@ __all__ = ["Counter", "Gauge", "Histogram", "MetricGroup",
            "COMMIT_DURATION_MS", "COMPACTION_DURATION_MS",
            "COMPACTION_TABLE_MS", "COMPACTION_CONCURRENT_TASKS_PEAK",
            "COMPACTION_MESH_STEPS", "COMPACTION_MESH_PADDED_ROWS",
-           "WRITE_ROUTE_MS",
+           "WRITE_ROUTE_MS", "WRITE_ROUTE_NOCOPY_ROWS",
            "MERGE_PREP_MS", "MERGE_DEVICE_MS", "MERGE_AGG_MS",
            "MERGE_SELECT_MS", "MERGE_GATHER_MS", "MERGE_GATHER_BYTES",
            "STREAM_EVENTS_INGESTED", "STREAM_CHECKPOINTS",
@@ -149,7 +149,11 @@ COMPACTION_MESH_PADDED_ROWS = "mesh_padded_rows"  # counter: lanes x n_pad
                                             # of those steps: every row slot
                                             # the chips sorted, padding and
                                             # drained lanes included
-WRITE_ROUTE_MS = "route_ms"                 # write: hash/group-by/take
+WRITE_ROUTE_MS = "route_ms"                 # write: bucket hash, group-by,
+                                            # hand-over to the bucket writers
+WRITE_ROUTE_NOCOPY_ROWS = "route_nocopy_rows"   # counter: rows of batches
+                                            # that were one group, handed on
+                                            # without a take
 # merge metric group: the stages of one sorted-run merge, whoever
 # called it (scan split, flush sort, compaction window) — producers
 # in ops/merge.py, ops/agg.py and compact/manager.py

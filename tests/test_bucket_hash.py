@@ -1,0 +1,224 @@
+"""The vectorized bucket hash against its scalar reference.
+
+`KeyHasher.hashes` (32-bit words read from the key columns' own values,
+mixed a block of rows at a time) must equal `KeyHasher._hash_rows` (the
+BinaryRow codec + `murmur_hash_bytes`, a row at a time) and
+`bucket_of` in every row; `_bucket_from_hash` must equal Java's
+`Math.abs(h % n)` computed in Python ints.
+"""
+
+import zlib
+
+import numpy as np
+import pyarrow as pa
+import pytest
+
+from paimon_tpu.core import bucket as bucket_mod
+from paimon_tpu.core.bucket import (
+    FixedBucketAssigner, KeyHasher, _bucket_from_hash, bucket_of,
+)
+from paimon_tpu.types import (
+    BigIntType, BooleanType, DateType, DoubleType, FloatType, IntType,
+    SmallIntType, TimeType, TinyIntType,
+)
+
+BLOCK = 64                     # a small block: the sizes below cross it
+REAL_BLOCK = bucket_mod._BLOCK_ROWS
+
+
+@pytest.fixture(autouse=True)
+def _small_blocks(monkeypatch):
+    monkeypatch.setattr(bucket_mod, "_BLOCK_ROWS", BLOCK)
+
+
+def _column(kind: str, n: int, rng) -> pa.Array:
+    """Random values of one fixed-width key type, extremes included."""
+    ints = {"tinyint": (np.int8, pa.int8()),
+            "smallint": (np.int16, pa.int16()),
+            "int": (np.int32, pa.int32()),
+            "bigint": (np.int64, pa.int64())}
+    if kind in ints:
+        dtype, arrow = ints[kind]
+        info = np.iinfo(dtype)
+        vals = rng.integers(info.min, info.max, n, dtype=dtype,
+                            endpoint=True)
+        vals[:3] = (info.min, info.max, -1)[:n]
+        return pa.array(vals, arrow)
+    if kind == "boolean":
+        return pa.array(rng.integers(0, 2, n).astype(bool))
+    if kind == "float":
+        vals = rng.standard_normal(n).astype(np.float32)
+        vals[:2] = (-0.0, np.float32(3.4e38))[:n]
+        return pa.array(vals, pa.float32())
+    if kind == "double":
+        vals = rng.standard_normal(n) * 1e200
+        vals[:2] = (-0.0, 1.7e308)[:n]
+        return pa.array(vals, pa.float64())
+    if kind == "date":
+        return pa.array(rng.integers(-30000, 60000, n).astype(np.int32),
+                        pa.int32()).cast(pa.date32())
+    assert kind == "time"
+    return pa.array(rng.integers(0, 86_400_000, n).astype(np.int32),
+                    pa.int32()).cast(pa.time32("ms"))
+
+
+TYPES = {"boolean": BooleanType, "tinyint": TinyIntType,
+         "smallint": SmallIntType, "int": IntType, "bigint": BigIntType,
+         "float": FloatType, "double": DoubleType, "date": DateType,
+         "time": TimeType}
+
+
+def _with_nulls(arr: pa.Array, nulls: str, rng) -> pa.Array:
+    if nulls == "none":
+        return arr
+    mask = np.ones(len(arr), dtype=bool) if nulls == "all" \
+        else rng.random(len(arr)) < 0.3
+    return pa.array([None if m else v
+                     for v, m in zip(arr.to_pylist(), mask)], type=arr.type)
+
+
+def _assert_equal_to_reference(table: pa.Table, kinds):
+    hasher = KeyHasher(table.column_names, [TYPES[k]() for k in kinds])
+    fast = hasher.hashes(table)
+    assert fast.dtype == np.uint32 and len(fast) == table.num_rows
+    assert np.array_equal(fast, hasher._hash_rows(table))
+    cols = [c.to_pylist() for c in table.columns]
+    for num_buckets in (7, 8):
+        got = _bucket_from_hash(fast, num_buckets)
+        for i in range(0, table.num_rows, max(1, table.num_rows // 25)):
+            values = [c[i] for c in cols]
+            assert got[i] == bucket_of(values, hasher.types, num_buckets)
+
+
+@pytest.mark.parametrize("nulls", ["none", "some", "all"])
+@pytest.mark.parametrize("kind", sorted(TYPES))
+def test_one_key_column_of_every_fixed_width_type(kind, nulls):
+    rng = np.random.default_rng(len(kind) * 31 + len(nulls))
+    col = _with_nulls(_column(kind, 3 * BLOCK + 5, rng), nulls, rng)
+    _assert_equal_to_reference(pa.table({"k": col}), [kind])
+
+
+@pytest.mark.parametrize("nulls", [
+    ("none", "none", "none"), ("some", "none", "none"),
+    ("none", "some", "all"), ("all", "all", "all"),
+    ("none", "none", "some")])
+@pytest.mark.parametrize("kinds", [
+    ("bigint", "int"), ("int", "bigint"), ("tinyint", "double"),
+    ("smallint", "boolean", "bigint"), ("date", "float", "time"),
+    ("double", "tinyint", "smallint")])
+def test_several_key_columns_of_mixed_widths(kinds, nulls):
+    rng = np.random.default_rng(zlib.crc32(repr((kinds, nulls)).encode()))
+    n = 2 * BLOCK + 9
+    table = pa.table({
+        f"k{i}": _with_nulls(_column(kind, n, rng), nl, rng)
+        for i, (kind, nl) in enumerate(zip(kinds, nulls))})
+    _assert_equal_to_reference(table, list(kinds))
+
+
+@pytest.mark.parametrize("n", [0, 1, 8, 9, BLOCK - 1, BLOCK, BLOCK + 1,
+                               4 * BLOCK + 17])
+@pytest.mark.parametrize("nulls", ["none", "some"])
+def test_row_counts_around_the_scalar_threshold_and_the_block(n, nulls):
+    rng = np.random.default_rng(n + len(nulls))
+    table = pa.table({
+        "a": _with_nulls(_column("bigint", n, rng), nulls, rng),
+        "b": _column("int", n, rng)})
+    _assert_equal_to_reference(table, ["bigint", "int"])
+
+
+@pytest.mark.parametrize("nulls", ["none", "some"])
+def test_chunked_columns_with_unaligned_chunks(nulls):
+    rng = np.random.default_rng(5)
+    n = 3 * BLOCK
+    a = _with_nulls(_column("bigint", n, rng), nulls, rng)
+    b = _with_nulls(_column("smallint", n, rng), nulls, rng)
+    table = pa.table({
+        "a": pa.chunked_array([a.slice(0, 10), a.slice(10, BLOCK),
+                               a.slice(10 + BLOCK)]),
+        "b": pa.chunked_array([b.slice(0, 2 * BLOCK + 1),
+                               b.slice(2 * BLOCK + 1)])})
+    _assert_equal_to_reference(table, ["bigint", "smallint"])
+    whole = KeyHasher(["a", "b"], [BigIntType(), SmallIntType()])
+    assert np.array_equal(whole.hashes(table),
+                          whole.hashes(pa.table({"a": a, "b": b})))
+
+
+@pytest.mark.parametrize("nulls", ["none", "some"])
+@pytest.mark.parametrize("kind", ["boolean", "tinyint", "int", "bigint",
+                                  "double"])
+def test_a_sliced_table_with_a_non_zero_offset(kind, nulls):
+    rng = np.random.default_rng(len(kind) + len(nulls))
+    n = 3 * BLOCK
+    whole = pa.table({"k": _with_nulls(_column(kind, n, rng), nulls, rng)})
+    part = whole.slice(BLOCK // 2 + 3, BLOCK + 11)
+    assert part.column("k").chunk(0).offset > 0
+    _assert_equal_to_reference(part, [kind])
+    hasher = KeyHasher(["k"], [TYPES[kind]()])
+    assert np.array_equal(
+        hasher.hashes(part),
+        hasher.hashes(whole)[BLOCK // 2 + 3:][:BLOCK + 11])
+
+
+def test_the_real_block_size_gives_the_same_hashes(monkeypatch):
+    rng = np.random.default_rng(9)
+    n = 2 * REAL_BLOCK + 77
+    table = pa.table({"a": _with_nulls(_column("bigint", n, rng), "some",
+                                       rng),
+                      "b": _column("int", n, rng)})
+    hasher = KeyHasher(["a", "b"], [BigIntType(), IntType()])
+    small = hasher.hashes(table)
+    monkeypatch.setattr(bucket_mod, "_BLOCK_ROWS", REAL_BLOCK)
+    real = hasher.hashes(table)
+    assert np.array_equal(real, small)
+    for start in (0, REAL_BLOCK - 20, 2 * REAL_BLOCK - 20, n - 40):
+        part = table.slice(start, 40)
+        assert np.array_equal(real[start:start + 40],
+                              hasher._hash_rows(part))
+
+
+def test_a_column_of_another_arrow_width_is_cast_to_the_key_type():
+    """A caller's int64 column under an INT key hashes as the INT."""
+    vals = np.arange(-20, 20, dtype=np.int64)
+    hasher = KeyHasher(["k"], [IntType()])
+    assert np.array_equal(
+        hasher.hashes(pa.table({"k": vals})),
+        hasher.hashes(pa.table({"k": vals.astype(np.int32)})))
+
+
+def _java_bucket(h: int, n: int) -> int:
+    """Math.abs(h % n) with h an int (two's complement), % truncating."""
+    signed = h - (1 << 32) if h >= 1 << 31 else h
+    rem = abs(signed) % n
+    java_rem = -rem if signed < 0 else rem     # the dividend's sign
+    return abs(java_rem)
+
+
+HASHES = [0, 1, 41, 0x7FFFFFFF, 0x80000000, 0x80000001, 0xFFFFFFFF,
+          0xDEADBEEF, 0x12345678, 0xCAFEBABE, 0x7FFFFFFE, 0xFFFFFFF9]
+
+
+@pytest.mark.parametrize("dtype", [np.uint32, np.uint64])
+@pytest.mark.parametrize("n", [1, 2, 7, 8, (1 << 31) - 1])
+def test_bucket_from_hash_is_javas_abs_of_the_remainder(n, dtype):
+    rng = np.random.default_rng(n % 1000)
+    hashes = HASHES + rng.integers(0, 1 << 32, 500).tolist()
+    got = _bucket_from_hash(np.array(hashes, dtype=dtype), n)
+    assert got.dtype == np.int32
+    assert got.tolist() == [_java_bucket(h, n) for h in hashes]
+    assert any(h >= 1 << 31 for h in hashes) and \
+        any(h < 1 << 31 for h in hashes)
+
+
+@pytest.mark.parametrize("num_buckets", [1, 2, 7, 8])
+def test_the_assigner_gives_every_row_bucket_ofs_bucket(num_buckets):
+    rng = np.random.default_rng(num_buckets)
+    n = BLOCK + 30
+    table = pa.table({"a": _column("bigint", n, rng),
+                      "b": _column("int", n, rng),
+                      "payload": rng.random(n)})
+    types = [BigIntType(False), IntType(False)]
+    got = FixedBucketAssigner(["a", "b"], types, num_buckets).assign(table)
+    assert got.dtype == np.int32
+    a, b = table.column("a").to_pylist(), table.column("b").to_pylist()
+    assert got.tolist() == [bucket_of([a[i], b[i]], types, num_buckets)
+                            for i in range(n)]
