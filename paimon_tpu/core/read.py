@@ -135,6 +135,15 @@ def evolve_table(table: pa.Table, file_schema_id: int, schema: TableSchema,
     return pa.table(cols)
 
 
+def _count_rows_in(rows: int) -> None:
+    """`scan` / `rows_in`: the rows a split's files held, before any
+    merge, filter or aggregate."""
+    from paimon_tpu.metrics import SCAN_ROWS_IN, global_registry
+    from paimon_tpu.obs.trace import metrics_enabled
+    if metrics_enabled():
+        global_registry().scan_metrics().counter(SCAN_ROWS_IN).inc(rows)
+
+
 def assemble_tables(tables: Sequence[pa.Table]) -> pa.Table:
     """The scan's last stage: the split tables as one (zero-copy, the
     result keeps the splits' chunks)."""
@@ -188,6 +197,8 @@ class MergeFileSplitRead:
         self._schema_cache: Dict[int, TableSchema] = {schema.id: schema}
         self._projection: Optional[List[str]] = None
         self._predicate: Optional[Predicate] = None
+        self._filter = None         # the predicate's Arrow expression
+        self._aggregate = None      # ops.scan_agg.ScanAggregate
 
     def with_projection(self, columns: Optional[List[str]]
                         ) -> "MergeFileSplitRead":
@@ -197,11 +208,21 @@ class MergeFileSplitRead:
     def with_filter(self, predicate: Optional[Predicate]
                     ) -> "MergeFileSplitRead":
         self._predicate = predicate
+        self._filter = None if predicate is None else predicate.to_arrow()
+        return self
+
+    def with_aggregate(self, aggregate) -> "MergeFileSplitRead":
+        """Return each split's partial aggregates (ops/scan_agg.py) in
+        place of its rows; the filter then runs below the merge too.
+        The caller has asked `aggregate.unsupported(...)` first."""
+        self._aggregate = aggregate
         return self
 
     # -- split read ----------------------------------------------------------
 
     def read_split(self, split: DataSplit) -> pa.Table:
+        if self._aggregate is not None:
+            return self._read_partials(split)
         value_cols = self._value_columns()
         if self.options.get(CoreOptions.TABLE_READ_SEQUENCE_NUMBER):
             # expose _SEQUENCE_NUMBER as a metadata column (reference
@@ -214,9 +235,48 @@ class MergeFileSplitRead:
         else:
             out = self._read_merged(split, read_cols, value_cols)
         out = record_level_expire_filter(self.options, out)
-        if self._predicate is not None:
-            out = out.filter(self._predicate.to_arrow())
+        if self._filter is not None:
+            out = out.filter(self._filter)
         return out
+
+    def _read_partials(self, split: Optional[DataSplit]) -> pa.Table:
+        """The split's files decoded as for a merge, but only the key
+        lanes' columns and the columns the aggregate and the filter
+        name; then merge and aggregate in one step.  No split: the
+        partials of no rows (the scan's plan was empty)."""
+        from paimon_tpu.ops.scan_agg import split_partials
+        agg = self._aggregate
+        wanted = agg.columns() + (self._predicate.fields()
+                                  if self._predicate is not None else [])
+        read_cols = list(dict.fromkeys(
+            self.key_cols + [SEQ_COL, KIND_COL]
+            + list(self.options.sequence_field) + wanted))
+        if split is not None and split.for_streaming:
+            raise ValueError("a streaming split has no pushed aggregate")
+        runs = [] if split is None else self._read_runs(
+            split, read_cols, whole=split.raw_convertible)
+        by_name = {f.name: data_type_to_arrow(f.type)
+                   for f in self.schema.fields}
+        fields = {c: by_name[c] for c in dict.fromkeys(wanted)}
+        merge = bool(runs) and not split.raw_convertible
+        if not runs:                # no split, or nothing readable in it
+            runs = [pa.table({c: pa.array([], by_name[c])
+                              for c in fields})]
+        engine = self.options.merge_engine
+        from paimon_tpu.metrics import SCAN_MERGE_MS
+        from paimon_tpu.obs.trace import span
+        with span("scan.merge", cat="scan", group="scan",
+                  metric=SCAN_MERGE_MS, engine=engine,
+                  partition=split.partition if split else None,
+                  bucket=split.bucket if split else None,
+                  runs=len(runs), rows=sum(r.num_rows for r in runs)):
+            return split_partials(
+                runs, self.key_cols, agg, self._predicate, fields,
+                self.key_encoder, merge=merge,
+                merge_engine="first-row"
+                if engine == MergeEngine.FIRST_ROW else "deduplicate",
+                seq_fields=self.options.sequence_field or None,
+                seq_desc=self.options.sequence_field_descending)
 
     def iter_splits(self, splits: Sequence[DataSplit], *,
                     ordered: bool = True
@@ -233,6 +293,8 @@ class MergeFileSplitRead:
         tables = [t for _, _, t in self.iter_splits(splits)
                   if t.num_rows > 0]
         if not tables:
+            if self._aggregate is not None:
+                return self._read_partials(None)
             if streaming is None:
                 streaming = any(s.for_streaming for s in splits)
             return self._empty_table(streaming)
@@ -292,6 +354,7 @@ class MergeFileSplitRead:
         if not tables:
             return self._empty_table(bool(split.for_streaming))
         merged = pa.concat_tables(tables, promote_options="none")
+        _count_rows_in(merged.num_rows)
         if split.for_streaming and split.is_delta:
             # changelog consumers observe every row with its kind
             # (reference streaming read preserves RowKind; -U/-D survive)
@@ -312,9 +375,12 @@ class MergeFileSplitRead:
                 pa.array(np.zeros(out.num_rows, np.int8), pa.int8()))
         return out
 
-    def _read_merged(self, split: DataSplit, read_cols: List[str],
-                     value_cols: List[str]) -> pa.Table:
-        runs_meta = assemble_runs(split.data_files)
+    def _read_runs(self, split: DataSplit, read_cols: List[str],
+                   whole: bool = False) -> List[pa.Table]:
+        """The split's sorted runs, oldest first, each decoded to one
+        table; `whole`: its files as one run (they do not overlap)."""
+        runs_meta = [sorted(split.data_files, key=lambda f: f.min_key)] \
+            if whole else assemble_runs(split.data_files)
         runs = []
         for run_files in runs_meta:
             tables = [t for t in (self._read_file(split, f, read_cols)
@@ -323,6 +389,12 @@ class MergeFileSplitRead:
                 continue                  # whole run corrupt + ignored
             runs.append(pa.concat_tables(tables, promote_options="none")
                         if len(tables) > 1 else tables[0])
+        _count_rows_in(sum(r.num_rows for r in runs))
+        return runs
+
+    def _read_merged(self, split: DataSplit, read_cols: List[str],
+                     value_cols: List[str]) -> pa.Table:
+        runs = self._read_runs(split, read_cols)
         if not runs:
             return self._empty_table(bool(split.for_streaming))
         engine = self.options.merge_engine

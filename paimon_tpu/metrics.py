@@ -37,6 +37,8 @@ __all__ = ["Counter", "Gauge", "Histogram", "MetricGroup",
            "WRITE_ROUTE_MS", "WRITE_ROUTE_NOCOPY_ROWS",
            "MERGE_PREP_MS", "MERGE_DEVICE_MS", "MERGE_AGG_MS",
            "MERGE_SELECT_MS", "MERGE_GATHER_MS", "MERGE_GATHER_BYTES",
+           "MERGE_RETURN_BYTES", "SCAN_AGG_MS", "SCAN_AGG_BELOW_ROWS",
+           "SCAN_ROWS_IN",
            "STREAM_EVENTS_INGESTED", "STREAM_CHECKPOINTS",
            "STREAM_CHECKPOINT_MS", "STREAM_LOOP_RESTARTS",
            "STREAM_FRESHNESS_MS", "STREAM_CHANGELOG_ROWS",
@@ -126,6 +128,11 @@ WRITE_RETRIES = "write_retries"             # transient flush retries
 # core/{read,write,commit}.py, parallel/mesh_engine.py, format/format.py)
 SCAN_SPLIT_MS = "split_ms"                  # scan: whole read_split
 SCAN_MERGE_MS = "merge_ms"                  # scan: merge kernel
+SCAN_AGG_MS = "agg_ms"                      # scan: pushed aggregate, host side
+SCAN_AGG_BELOW_ROWS = "agg_below_rows"      # counter: rows aggregated below
+#                                             the merge (ops/scan_agg.py)
+SCAN_ROWS_IN = "rows_in"                    # counter: rows the split reads
+#                                             of pk tables decoded
 WRITE_SORT_MS = "sort_ms"                   # write: buffer sort/dedup
 WRITE_FLUSH_TASK_MS = "flush_task_ms"       # write: whole flush task
 IO_READ_MS = "read_ms"                      # io: store -> bytes
@@ -163,6 +170,7 @@ MERGE_AGG_MS = "agg_ms"                     # aggregation epilogue, whole
 MERGE_SELECT_MS = "select_ms"               # its per-segment row selections
 MERGE_GATHER_MS = "gather_ms"               # Arrow take in merge order
 MERGE_GATHER_BYTES = "gather_bytes"         # counter: buffer bytes taken
+MERGE_RETURN_BYTES = "return_bytes"         # counter: bytes a merge handed back
 
 # streaming-daemon counter/gauge/histogram names (stream metric group;
 # producer is service/stream_daemon.py, consumers tests/soak_harness.py

@@ -49,7 +49,7 @@ import pyarrow as pa
 
 from paimon_tpu.metrics import (
     MERGE_DEVICE_MS, MERGE_GATHER_BYTES, MERGE_GATHER_MS, MERGE_PREP_MS,
-    global_registry,
+    MERGE_RETURN_BYTES, global_registry,
 )
 from paimon_tpu.obs.trace import metrics_enabled, span
 from paimon_tpu.ops.normkey import NormalizedKeyEncoder
@@ -58,7 +58,8 @@ from paimon_tpu.ops.ovc import (
 )
 from paimon_tpu.types import RowKind
 
-__all__ = ["merge_runs", "MergeResult", "device_sorted_winners",
+__all__ = ["merge_runs", "MergeResult", "MergeOperands", "merge_operands",
+           "device_sorted_winners", "route_to_host", "host_sorted_winners",
            "take_link_reading", "user_seq_order_lanes", "SEQ_COL",
            "KIND_COL"]
 
@@ -364,14 +365,18 @@ def _time_link() -> Tuple[float, float]:
 
 
 def _device_path_pays(n: int, num_lanes: int, winners_only: bool,
-                      host_fast: bool) -> bool:
+                      host_fast: bool, epilogue_h2d_bytes: int = 0,
+                      d2h_bytes: Optional[int] = None) -> bool:
     """Cost model: offload the sort only when transfer+compute beats
     the host sort.  The accelerator wins on wide links; a narrow link
-    loses on device->host alone and the merge stays host-side."""
+    loses on device->host alone and the merge stays host-side.  A merge
+    with an epilogue on the device (ops/scan_agg.py) says what its
+    value lanes add on the way up and what really comes back."""
     m = _pad_size(n)
     h2d, d2h = _measure_link_bandwidth()
-    bytes_in = m * (4 * num_lanes + 12)          # lanes + seq hi/lo + inv
-    bytes_out = m * (4 if winners_only else 9)   # packed vs perm+win+prev
+    bytes_in = m * (4 * num_lanes + 12) + epilogue_h2d_bytes
+    bytes_out = d2h_bytes if d2h_bytes is not None \
+        else m * (4 if winners_only else 9)      # packed vs perm+win+prev
     t_dev = bytes_in / h2d + bytes_out / d2h + m / _DEVICE_SORT_ROWS_PER_SEC
     host_rate = _host_fast_rate() if host_fast \
         else _HOST_GENERAL_ROWS_PER_SEC
@@ -488,6 +493,76 @@ def _host_sorted_winners(lanes: np.ndarray, seq: np.ndarray, keep: str,
     return _winner_epilogue(perm, eq, keep)
 
 
+def count_returned(nbytes: int) -> None:
+    """`merge` / `return_bytes`: the bytes a merge handed back to its
+    caller — the permutation and winner mask (the device's packed words
+    or full triple, the host route's arrays), or, where an epilogue ran
+    with the merge (ops/scan_agg.py), the partials alone."""
+    if metrics_enabled():
+        global_registry().group("merge").counter(MERGE_RETURN_BYTES) \
+            .inc(int(nbytes))
+
+
+def _no_user_order(order_lanes: Optional[np.ndarray]) -> bool:
+    return order_lanes is None or order_lanes.shape[1] == 0
+
+
+def route_to_host(n: int, num_key_lanes: int,
+                  order_lanes: Optional[np.ndarray], winners_only: bool,
+                  epilogue_h2d_bytes: int = 0,
+                  d2h_bytes: Optional[int] = None) -> bool:
+    """The router's decision for one merge of `n` rows, logged in
+    ROUTE_LOG: True = sort on the host.  The two pins first, then the
+    cpu backend (always the host), then the cost model."""
+    force_device = os.environ.get("PAIMON_FORCE_DEVICE_SORT") == "1"
+    force_host = os.environ.get("PAIMON_FORCE_HOST_SORT") == "1"
+    no_user_order = _no_user_order(order_lanes)
+    host_fast = num_key_lanes == 2 and winners_only and no_user_order
+    nl_total = num_key_lanes + (0 if no_user_order
+                                else order_lanes.shape[1])
+    use_host = force_host
+    pinned = force_host or force_device
+    if not pinned and n > 0:
+        use_host = jax.default_backend() == "cpu" \
+            or not _device_path_pays(n, nl_total, winners_only, host_fast,
+                                     epilogue_h2d_bytes, d2h_bytes)
+    if len(ROUTE_LOG) < _ROUTE_LOG_CAP:
+        ROUTE_LOG.append({
+            "rows": n, "lanes": nl_total, "winners_only": winners_only,
+            "host_fast": host_fast, "pinned": pinned,
+            "route": "host" if use_host else "device"})
+    return use_host
+
+
+def host_sorted_winners(lanes: np.ndarray, seq: np.ndarray, keep: str,
+                        order_lanes: Optional[np.ndarray],
+                        winners_only: bool,
+                        packed: Optional[np.ndarray],
+                        run_starts: Optional[np.ndarray]
+                        ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """The host route of `device_sorted_winners`: the offset-value
+    coded merge where the input is sorted runs, else the sort."""
+    n, num_key_lanes = lanes.shape
+    no_user_order = _no_user_order(order_lanes)
+    if run_starts is not None and no_user_order and len(run_starts) > 1:
+        # sorted-run inputs: offset-value coded merge replaces the
+        # sort (single-int compares, segment boundaries for free)
+        with _host_span("ovc", n):
+            res = ovc_sorted_winners(lanes, seq, keep, run_starts,
+                                     num_key_lanes, packed=packed)
+        if res is not None:
+            PATH_COUNTS["ovc"] += 1
+            return res
+    PATH_COUNTS["host"] += 1
+    with _host_span("host", n):
+        full = lanes if no_user_order \
+            else np.concatenate([lanes, order_lanes], axis=1)
+        return _host_sorted_winners(full, seq, keep, num_key_lanes,
+                                    need_prev=not winners_only,
+                                    packed=packed if no_user_order
+                                    else None)
+
+
 def device_sorted_winners(lanes: np.ndarray, seq: np.ndarray,
                           keep: str = "last",
                           order_lanes: Optional[np.ndarray] = None,
@@ -526,40 +601,11 @@ def device_sorted_winners(lanes: np.ndarray, seq: np.ndarray,
     backend, for padding and validity).
     """
     n, num_key_lanes = lanes.shape
-    force_device = os.environ.get("PAIMON_FORCE_DEVICE_SORT") == "1"
-    force_host = os.environ.get("PAIMON_FORCE_HOST_SORT") == "1"
-    no_user_order = order_lanes is None or order_lanes.shape[1] == 0
-    host_fast = num_key_lanes == 2 and winners_only and no_user_order
-    nl_total = num_key_lanes + (0 if no_user_order
-                                else order_lanes.shape[1])
-    use_host = force_host
-    pinned = force_host or force_device
-    if not pinned and n > 0:
-        use_host = jax.default_backend() == "cpu" \
-            or not _device_path_pays(n, nl_total, winners_only, host_fast)
-    if len(ROUTE_LOG) < _ROUTE_LOG_CAP:
-        ROUTE_LOG.append({
-            "rows": n, "lanes": nl_total, "winners_only": winners_only,
-            "host_fast": host_fast, "pinned": pinned,
-            "route": "host" if use_host else "device"})
-    if use_host:
-        if run_starts is not None and no_user_order and len(run_starts) > 1:
-            # sorted-run inputs: offset-value coded merge replaces the
-            # sort (single-int compares, segment boundaries for free)
-            with _host_span("ovc", n):
-                res = ovc_sorted_winners(lanes, seq, keep, run_starts,
-                                         num_key_lanes, packed=packed)
-            if res is not None:
-                PATH_COUNTS["ovc"] += 1
-                return res
-        PATH_COUNTS["host"] += 1
-        with _host_span("host", n):
-            full = lanes if no_user_order \
-                else np.concatenate([lanes, order_lanes], axis=1)
-            return _host_sorted_winners(full, seq, keep, num_key_lanes,
-                                        need_prev=not winners_only,
-                                        packed=packed if no_user_order
-                                        else None)
+    if route_to_host(n, num_key_lanes, order_lanes, winners_only):
+        res = host_sorted_winners(lanes, seq, keep, order_lanes,
+                                  winners_only, packed, run_starts)
+        count_returned(res[0].nbytes + res[1].nbytes)
+        return res
     PATH_COUNTS["device"] += 1
     lanes, lanes_p, seq_hi, seq_lo, invalid = _padded_operands(
         lanes, order_lanes, seq)
@@ -595,6 +641,7 @@ def device_sorted_winners(lanes: np.ndarray, seq: np.ndarray,
             packed = np.asarray(out)
         else:
             perm, winner, prev = (np.asarray(a) for a in out)
+    count_returned(4 * m if winners_only else 9 * m)
     if winners_only:
         perm = (packed & np.uint32(0x7FFFFFFF)).astype(np.int32)
         winner = (packed >> np.uint32(31)).astype(bool)
@@ -690,28 +737,40 @@ class _LazyLanes:
         return out
 
 
-def merge_runs(runs: Sequence[pa.Table], key_names: Sequence[str],
-               merge_engine: str = "deduplicate",
-               drop_deletes: bool = True,
-               key_encoder: Optional[NormalizedKeyEncoder] = None,
-               with_prev: bool = False,
-               seq_fields: Optional[Sequence[str]] = None,
-               seq_desc: bool = False,
-               encoded: Optional[Sequence[Tuple[np.ndarray, np.ndarray]]]
-               = None) -> MergeResult:
-    """Merge k sorted runs (oldest first) into the latest row per key.
+@dataclass
+class MergeOperands:
+    """What `merge_operands` readies for the router (under
+    `merge.prep`): the runs as one table (oldest first) and, unless it
+    is empty, its key lanes, sequence numbers and sorted-run bounds."""
+    table: pa.Table
+    keep: str = "last"
+    lanes: Optional[np.ndarray] = None
+    truncated: Optional[np.ndarray] = None
+    packed: Optional[np.ndarray] = None
+    seq: Optional[np.ndarray] = None
+    run_starts: Optional[np.ndarray] = None
+    order_lanes: Optional[np.ndarray] = None
 
-    Equivalent reference path: MergeTreeReaders.readerForMergeTree
-    (mergetree/MergeTreeReaders.java:44) + DeduplicateMergeFunction /
-    FirstRowMergeFunction + DropDeleteReader.
-    """
+
+def merge_operands(runs: Sequence[pa.Table], key_names: Sequence[str],
+                   merge_engine: str = "deduplicate",
+                   key_encoder: Optional[NormalizedKeyEncoder] = None,
+                   seq_fields: Optional[Sequence[str]] = None,
+                   seq_desc: bool = False,
+                   encoded: Optional[Sequence[Tuple[np.ndarray,
+                                                    np.ndarray]]] = None
+                   ) -> MergeOperands:
+    """Concat, key-lane encode and sequence of one merge's runs: the
+    host work ahead of `device_sorted_winners`, shared by `merge_runs`
+    and the scan's pushed aggregate (ops/scan_agg.py)."""
     if not runs:
         raise ValueError("No runs to merge")
+    keep = "first" if merge_engine == "first-row" else "last"
     with prep_span(sum(r.num_rows for r in runs)):
         table = pa.concat_tables(runs, promote_options="none")
         n = table.num_rows
         if n == 0:
-            return MergeResult(table, np.zeros(0, dtype=np.int64))
+            return MergeOperands(table, keep)
 
         if key_encoder is None:
             key_encoder = NormalizedKeyEncoder(
@@ -759,7 +818,6 @@ def merge_runs(runs: Sequence[pa.Table], key_names: Sequence[str],
             run_lens = [r.num_rows for r in runs]
         run_starts = np.concatenate(
             [[0], np.cumsum(run_lens)]).astype(np.int64)
-        keep = "first" if merge_engine == "first-row" else "last"
         if seq_fields and keep == "first":
             # reference forbids the combo: "first by user sequence" would
             # let later commits replace the retained first row
@@ -767,15 +825,40 @@ def merge_runs(runs: Sequence[pa.Table], key_names: Sequence[str],
                 "sequence.field cannot be used with merge-engine first-row")
         order_lanes = user_seq_order_lanes(table, seq_fields, seq_desc) \
             if seq_fields else None
+    return MergeOperands(table, keep, lanes, truncated, packed, seq,
+                         run_starts if order_lanes is None else None,
+                         order_lanes)
+
+
+def merge_runs(runs: Sequence[pa.Table], key_names: Sequence[str],
+               merge_engine: str = "deduplicate",
+               drop_deletes: bool = True,
+               key_encoder: Optional[NormalizedKeyEncoder] = None,
+               with_prev: bool = False,
+               seq_fields: Optional[Sequence[str]] = None,
+               seq_desc: bool = False,
+               encoded: Optional[Sequence[Tuple[np.ndarray, np.ndarray]]]
+               = None) -> MergeResult:
+    """Merge k sorted runs (oldest first) into the latest row per key.
+
+    Equivalent reference path: MergeTreeReaders.readerForMergeTree
+    (mergetree/MergeTreeReaders.java:44) + DeduplicateMergeFunction /
+    FirstRowMergeFunction + DropDeleteReader.
+    """
+    op = merge_operands(runs, key_names, merge_engine, key_encoder,
+                        seq_fields, seq_desc, encoded)
+    table, keep, truncated, seq = op.table, op.keep, op.truncated, op.seq
+    n = table.num_rows
+    if n == 0:
+        return MergeResult(table, np.zeros(0, dtype=np.int64))
     # without changelog derivation the caller consumes only winner
     # rows, so the packed-key fast path is admissible — unless any key
     # was prefix-truncated: _refine_truncated needs the full path's
     # seq-ordered segments with winners at segment boundaries
     perm, winner, prev = device_sorted_winners(
-        lanes, seq, keep, order_lanes,
+        op.lanes, seq, keep, op.order_lanes,
         winners_only=not with_prev and not truncated.any(),
-        packed=packed,
-        run_starts=run_starts if order_lanes is None else None)
+        packed=op.packed, run_starts=op.run_starts)
 
     win_pos = np.flatnonzero(winner)
     indices = perm[win_pos].astype(np.int64)

@@ -9,6 +9,7 @@ index pruning), with the full WHERE re-applied on the decoded batch so
 pushdown is purely an optimization.
 """
 
+import decimal
 import re
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -21,7 +22,9 @@ from paimon_tpu.schema import Schema
 from paimon_tpu.schema.schema_manager import SchemaChange
 from paimon_tpu.sql import parser as ast
 from paimon_tpu.sql.parser import SQLError, parse
-from paimon_tpu.types import RowKind, parse_data_type
+from paimon_tpu.types import (
+    DecimalType, RowKind, data_type_to_arrow, parse_data_type,
+)
 
 _AGG_FUNCS = {"count", "sum", "min", "max", "avg"}
 
@@ -149,9 +152,12 @@ class Compiler:
             return pc.invert(res) if e.negated else res
         if isinstance(e, ast.BetweenExpr):
             v = self.compile(e.expr)
-            res = pc.and_kleene(
-                pc.greater_equal(v, self.compile(e.lo)),
-                pc.less_equal(v, self.compile(e.hi)))
+            lo = _decimal_operands(">=", e.expr, v, e.lo,
+                                   self.compile(e.lo))[1]
+            hi = _decimal_operands("<=", e.expr, v, e.hi,
+                                   self.compile(e.hi))[1]
+            res = pc.and_kleene(pc.greater_equal(v, lo),
+                                pc.less_equal(v, hi))
             return pc.invert(res) if e.negated else res
         if isinstance(e, ast.LikeExpr):
             res = pc.match_like(self.as_array(e.expr), e.pattern)
@@ -190,6 +196,7 @@ class Compiler:
             return pc.binary_join_element_wise(
                 pc.cast(l_, pa.string()), pc.cast(r_, pa.string()), "")
         l_, r_ = self.compile(e.left), self.compile(e.right)
+        l_, r_ = _decimal_operands(op, e.left, l_, e.right, r_)
         fn = {"+": pc.add, "-": pc.subtract, "*": pc.multiply,
               "/": pc.divide, "%": lambda a, b: pc.subtract(
                   a, pc.multiply(pc.cast(pc.divide(a, b), pa.int64()), b)),
@@ -304,6 +311,66 @@ class Compiler:
                                           pa.array(cols[1]).type if cols
                                           else pa.string()))
         raise SQLError(f"unknown function {name}()")
+
+
+def _literal_number(e):
+    """The Python number of a numeric literal (`-x` too), else None."""
+    if isinstance(e, ast.Unary) and e.op == "NEG":
+        v = _literal_number(e.operand)
+        return None if v is None else -v
+    if isinstance(e, ast.Literal) and not isinstance(e.value, bool) and \
+            isinstance(e.value, (int, float)):
+        return e.value
+    return None
+
+
+def _decimal_scalar(v) -> pa.Scalar:
+    """A numeric literal as the narrowest decimal that holds it as
+    written: 1 is decimal(1, 0), 0.05 is decimal(2, 2)."""
+    d = decimal.Decimal(v if isinstance(v, int) else repr(v))
+    scale = max(0, -d.as_tuple().exponent)
+    digits = max(len(d.as_tuple().digits), scale, 1)
+    return pa.scalar(d, pa.decimal128(digits, scale))
+
+
+def _decimal_operands(op: str, le, l_, re_, r_):
+    """Arithmetic and comparisons that involve a DECIMAL stay exact:
+
+    * a numeric literal beside a decimal operand becomes a decimal
+      itself — an integer under any operator (so `1 - l_discount` is
+      decimal(16, 2), not the decimal(22, 2) of an int64), a literal
+      with a fraction under comparisons only (`d BETWEEN 0.05 AND 0.07`
+      compares decimals; `d * 1.5` stays a DOUBLE as before);
+    * `+ - *` whose result needs more than 38 digits run in decimal256
+      (Arrow refuses the decimal128 form), up to its 76."""
+    def is_dec(x):
+        return pa.types.is_decimal(x.type)
+
+    comparison = op in ("=", "<>", "<", "<=", ">", ">=")
+    if op not in ("+", "-", "*") and not comparison:
+        return l_, r_
+    for mine, other, node, left in ((l_, r_, le, True),
+                                    (r_, l_, re_, False)):
+        v = _literal_number(node)
+        if v is not None and is_dec(other) and \
+                (isinstance(v, int) or comparison):
+            if left:
+                l_ = _decimal_scalar(v)
+            else:
+                r_ = _decimal_scalar(v)
+    if comparison or not (is_dec(l_) and is_dec(r_)):
+        return l_, r_
+    lt, rt = l_.type, r_.type
+    if op == "*":
+        digits = lt.precision + rt.precision + 1
+    else:
+        scale = max(lt.scale, rt.scale)
+        digits = max(lt.precision - lt.scale,
+                     rt.precision - rt.scale) + scale + 1
+    if 38 < digits <= 76:
+        l_ = pc.cast(l_, pa.decimal256(lt.precision, lt.scale))
+        r_ = pc.cast(r_, pa.decimal256(rt.precision, rt.scale))
+    return l_, r_
 
 
 # ---------------------------------------------------------------------------
@@ -555,13 +622,56 @@ class SQLContext:
             self.database = prev_db
             self._view_stack.pop()
 
-    def _pushed_predicate(self, table, alias: str, select: ast.Select):
-        """WHERE -> pruning predicate, resolution-only (no I/O)."""
+    def _pushed_predicate(self, table, alias: str, select: ast.Select,
+                          exact: bool = False):
+        """WHERE -> pruning predicate, resolution-only (no I/O).
+        `exact`: the whole WHERE or nothing (the pushed aggregate runs
+        no second filter)."""
         if select.where is None or select.joins:
             return None
-        cols = [f.name for f in table.row_type().fields]
-        return expr_to_predicate(select.where, _probe_scope(cols, alias),
-                                 alias)
+        fields = table.row_type().fields
+        pred = expr_to_predicate(
+            select.where, _probe_scope([f.name for f in fields], alias),
+            alias, exact=exact)
+        return None if pred is None else _decimal_literals(
+            pred, {f.name for f in fields
+                   if isinstance(f.type, DecimalType)})
+
+    @staticmethod
+    def _pushed_projection(table, alias: str, select: ast.Select
+                           ) -> Optional[List[str]]:
+        """The columns a single-table SELECT names (select list, WHERE,
+        GROUP BY, HAVING, ORDER BY), in the table's order; None = all
+        (a `*`, a join, a subquery left in an expression)."""
+        if select.joins:
+            return None
+        names = [f.name for f in table.row_type().fields]
+        used, whole = set(), []
+
+        def visit(node):
+            if isinstance(node, ast.Star) or isinstance(
+                    node, (ast.InSubquery, ast.ScalarSubquery,
+                           ast.ExistsSubquery)):
+                whole.append(node)
+            elif isinstance(node, ast.Column) and \
+                    node.qualifier in (None, alias):
+                used.add(node.name)
+            elif isinstance(node, ast.Func) and node.name == "count":
+                for a in node.args:         # count(*) names no column
+                    if isinstance(a, ast.Star):
+                        whole.remove(a)
+            return node
+
+        exprs = [i.expr for i in select.items] + list(select.group_by) \
+            + [e for e, _, _ in select.order_by]
+        for e in exprs + [select.where, select.having]:
+            if e is not None:
+                _transform(e, visit)
+        if whole:
+            return None
+        # count(*) alone names nothing: the narrowest read is one column
+        return [n for n in names if n in used] or \
+            list(table.primary_keys[:1]) or names[:1]
 
     @staticmethod
     def _pushed_limit(select: ast.Select):
@@ -596,17 +706,17 @@ class SQLContext:
             else:
                 from paimon_tpu.table.table import FileStoreTable
                 pushed = self._pushed_predicate(rel, alias, select)
-                pushed_limit = self._pushed_limit(select) \
-                    if isinstance(rel, FileStoreTable) else None
+                kwargs = {}
+                if isinstance(rel, FileStoreTable):
+                    kwargs = {"limit": self._pushed_limit(select),
+                              "projection": self._pushed_projection(
+                                  rel, alias, select)}
                 if collect_plan is not None:
                     collect_plan["pushed"] = repr(pushed) \
                         if pushed is not None else None
-                    collect_plan["pushed_limit"] = pushed_limit
-                if pushed_limit is not None:
-                    out = rel.to_arrow(predicate=pushed,
-                                       limit=pushed_limit)
-                else:
-                    out = rel.to_arrow(predicate=pushed)
+                    collect_plan["pushed_limit"] = kwargs.get("limit")
+                    collect_plan["projection"] = kwargs.get("projection")
+                out = rel.to_arrow(predicate=pushed, **kwargs)
             q = out.rename_columns(
                 [f"{alias}.{c}" for c in out.column_names])
             return Scope(q, list(q.column_names))
@@ -889,6 +999,24 @@ class SQLContext:
                 cols.append(comp.as_array(item.expr))
             return pa.table(dict(zip(names, cols)))
 
+        has_agg = any(_find_aggs(i.expr) for i in s.items) or \
+            (s.having is not None and _find_aggs(s.having)) or s.group_by
+        windowed = any(_find_windows(e) for e in
+                       [i.expr for i in s.items]
+                       + [e for e, _, _ in s.order_by])
+        if has_agg and not windowed:
+            plan, why = self._plan_pushed_aggregate(s)
+            if collect_plan is not None:
+                collect_plan["aggregate"] = \
+                    repr(plan.aggregate) if plan else None
+                collect_plan["aggregate_declined"] = why
+            if plan is not None:
+                if collect_plan is not None:
+                    collect_plan["pushed"] = repr(plan.predicate) \
+                        if plan.predicate is not None else None
+                return self._limited(self._distinct(
+                    self._pushed_aggregate(plan, s), s), s)
+
         scope = self._relation_scope(s.from_, s, collect_plan)
         for j in s.joins:
             scope = self._join(scope, j, s)
@@ -898,8 +1026,6 @@ class SQLContext:
             scope = Scope(scope.table.filter(pc.fill_null(mask, False)),
                           scope.order)
 
-        has_agg = any(_find_aggs(i.expr) for i in s.items) or \
-            (s.having is not None and _find_aggs(s.having)) or s.group_by
         if s.having is not None and not has_agg:
             raise SQLError("HAVING requires GROUP BY or an aggregate; "
                            "use WHERE for row filters")
@@ -921,15 +1047,20 @@ class SQLContext:
             out = self._project(scope, s, subst=win_subst)
         else:
             out = self._project(scope, s, subst=None)
+        return self._limited(self._distinct(out, s), s)
+
+    @staticmethod
+    def _distinct(out: pa.Table, s: ast.Select) -> pa.Table:
         if s.distinct:
             out = out.group_by(out.column_names,
                                use_threads=False).aggregate([])
-        if s.limit is not None:
-            off = s.offset or 0
-            out = out.slice(off, s.limit)
-        elif s.offset:
-            out = out.slice(s.offset)
         return out
+
+    @staticmethod
+    def _limited(out: pa.Table, s: ast.Select) -> pa.Table:
+        if s.limit is not None:
+            return out.slice(s.offset or 0, s.limit)
+        return out.slice(s.offset) if s.offset else out
 
     def _join(self, left: Scope, j: ast.JoinClause, s: ast.Select) -> Scope:
         right = self._relation_scope(j.right, s)
@@ -1061,31 +1192,48 @@ class SQLContext:
         return tmp.take(idxs).drop_columns(sort_cols) if sort_cols \
             else tmp.take(idxs)
 
-    def _aggregate(self, scope: Scope, s: ast.Select) -> pa.Table:
+    @staticmethod
+    def _agg_calls(s: ast.Select) -> Dict[str, ast.Func]:
+        """The statement's distinct aggregate calls by structural repr,
+        in first-use order."""
         aggs: Dict[str, ast.Func] = {}
-        for item in s.items:
-            for f in _find_aggs(item.expr):
-                aggs.setdefault(repr(f), f)
-        if s.having is not None:
-            for f in _find_aggs(s.having):
-                aggs.setdefault(repr(f), f)
-        for e, _, _ in s.order_by:
-            for f in _find_aggs(e):
-                aggs.setdefault(repr(f), f)
+        for e in [i.expr for i in s.items] + [s.having] \
+                + [e for e, _, _ in s.order_by]:
+            if e is not None:
+                for f in _find_aggs(e):
+                    aggs.setdefault(repr(f), f)
+        return aggs
+
+    @staticmethod
+    def _group_target(s: ast.Select, ge):
+        """What a GROUP BY item means: itself, a select alias or a
+        position."""
+        if isinstance(ge, ast.Literal) and isinstance(ge.value, int):
+            return s.items[_ordinal(ge.value, len(s.items)) - 1].expr
+        if isinstance(ge, ast.Column) and ge.qualifier is None:
+            for item in s.items:
+                if item.alias == ge.name:
+                    return item.expr
+        return ge
+
+    def _aggregate(self, scope: Scope, s: ast.Select) -> pa.Table:
+        aggs = self._agg_calls(s)
+        gtable, agg_subst = self._grouped(scope, s, aggs)
+        return self._aggregated(gtable, agg_subst, aggs, s)
+
+    def _grouped(self, scope: Scope, s: ast.Select,
+                 aggs: Dict[str, ast.Func]
+                 ) -> Tuple[pa.Table, Dict[str, str]]:
+        """The grouped table (`__g<i>` keys, `__a<k>_<fn>` results) and
+        the map from a grouped or aggregate expression's repr to its
+        column.  The pushed aggregate (`_pushed_aggregate`) builds the
+        same table from the scan's partials."""
         comp = Compiler(scope)
         work = scope.table
         subst: Dict[str, str] = {}
         for i, ge in enumerate(s.group_by):
             cn = f"__g{i}"
-            # GROUP BY may name a select alias or a position
-            target = ge
-            if isinstance(ge, ast.Literal) and isinstance(ge.value, int):
-                target = s.items[_ordinal(ge.value, len(s.items)) - 1].expr
-            elif isinstance(ge, ast.Column) and ge.qualifier is None:
-                for item in s.items:
-                    if item.alias == ge.name:
-                        target = item.expr
-                        break
+            target = self._group_target(s, ge)
             work = work.append_column(cn, comp.as_array(target))
             subst[repr(target)] = cn
             if repr(ge) != repr(target):
@@ -1119,16 +1267,21 @@ class SQLContext:
         else:
             keys = [f"__g{i}" for i in range(len(s.group_by))]
         gtable = work.group_by(keys, use_threads=False).aggregate(specs)
-        order = list(gtable.column_names)
         if not s.group_by and gtable.num_rows == 0:
             # a global aggregate over empty input still yields one row
             # (counts become 0 below, other aggregates NULL)
             gtable = pa.table({name: pa.nulls(1, gtable.column(name).type)
-                               for name in order})
+                               for name in gtable.column_names})
         # substitution: each aggregate expression (by structural repr)
         # resolves to its arrow result column (e.g. "__a0_sum")
         agg_subst = {key: name for name, key in out_names}
         agg_subst.update(subst)
+        return gtable, agg_subst
+
+    def _aggregated(self, gtable: pa.Table, agg_subst: Dict[str, str],
+                    aggs: Dict[str, ast.Func], s: ast.Select) -> pa.Table:
+        """HAVING and the select list over the grouped table."""
+        order = list(gtable.column_names)
         # count()/count(*) never return NULL — fill empty groups with 0
         for key, f in aggs.items():
             cn = agg_subst[key]
@@ -1143,6 +1296,141 @@ class SQLContext:
             gtable = gtable.filter(pc.fill_null(mask, False))
             gscope = Scope(gtable, order)
         return self._project(gscope, s, subst=agg_subst)
+
+    # -- aggregate pushed below the merge ------------------------------------
+    def _plan_pushed_aggregate(self, s: ast.Select):
+        """(`_PushedAggregate`, None) when the statement's aggregate can
+        run below the merge-on-read merge (ops/scan_agg.py), else
+        (None, why not).  Decided from the statement and the schema: one
+        primary-key table of the deduplicate or first-row engine, no
+        join, window, DISTINCT or set operation; a WHERE that converts
+        whole to a predicate over integer, DECIMAL(<= 18 digits) and
+        DATE columns; GROUP BY over columns of those types or text;
+        sum / count / min / max / avg over `+ - *` of such columns and
+        numeric literals with an exact result type (a DOUBLE one is not:
+        avg of an integer, a literal with a fraction in arithmetic)."""
+        from paimon_tpu.ops.scan_agg import Measure, ScanAggregate
+        from paimon_tpu.table.table import FileStoreTable
+        if not isinstance(s.from_, ast.TableRef) or s.joins or \
+                s.union_all is not None:
+            return None, "not a single-table SELECT"
+        rel, alias = self._load_relation(s.from_)
+        if not isinstance(rel, FileStoreTable) or \
+                not rel.new_read_builder().supports_aggregate():
+            return None, "not a deduplicate / first-row primary-key table"
+        fields = {f.name: data_type_to_arrow(f.type)
+                  for f in rel.row_type().fields}
+
+        def column(e) -> Optional[str]:
+            if isinstance(e, ast.Column) and e.name in fields and \
+                    e.qualifier in (None, alias):
+                return e.name
+            return None
+
+        def lane_expr(e) -> Optional[tuple]:
+            if column(e) is not None:
+                return ("col", e.name)
+            if _literal_number(e) is not None:
+                return ("lit", _literal_number(e))
+            if isinstance(e, ast.Unary) and e.op == "NEG":
+                x = lane_expr(e.operand)
+                return None if x is None else ("neg", x)
+            if isinstance(e, ast.Binary) and e.op in ("+", "-", "*"):
+                a, b = lane_expr(e.left), lane_expr(e.right)
+                return None if a is None or b is None else (e.op, a, b)
+            return None
+
+        aggs = self._agg_calls(s)
+        group_by, measures = [], []
+        for ge in s.group_by:
+            name = column(self._group_target(s, ge))
+            if name is None:
+                return None, f"GROUP BY {ge!r} is not a column"
+            group_by.append(name)
+        for f in aggs.values():
+            star = f.name == "count" and (
+                not f.args or isinstance(f.args[0], ast.Star))
+            expr = None if star else lane_expr(f.args[0])
+            if f.distinct or f.over is not None or \
+                    (expr is None and not star):
+                return None, f"{f.name}() is not over + - * of columns"
+            measures.append(Measure("sum" if f.name == "avg" else f.name,
+                                    expr))
+        pred = self._pushed_predicate(rel, alias, s, exact=True)
+        if s.where is not None and pred is None:
+            return None, "WHERE does not convert to a predicate"
+        agg = ScanAggregate(tuple(group_by), tuple(measures))
+        why = agg.unsupported(fields, pred)
+        if why is not None:
+            return None, why
+        # the result's types are the materialising path's: group the
+        # empty relation
+        empty = Scope(pa.table({f"{alias}.{c}": pa.array([], t)
+                                for c, t in fields.items()}),
+                      [f"{alias}.{c}" for c in fields])
+        probe, agg_subst = self._grouped(empty, s, aggs)
+        scales = [agg.measure_scale(k, fields) for k in range(len(aggs))]
+        for (key, f), scale in zip(aggs.items(), scales):
+            t = probe.column(agg_subst[key]).type
+            exact = (pa.types.is_decimal(t) and t.scale == scale) or (
+                f.name != "avg" and (scale == 0 or f.name == "count") and
+                (pa.types.is_integer(t) or pa.types.is_date32(t)))
+            if not exact:
+                return None, f"{f.name}() returns {t}"
+        return _PushedAggregate(rel, pred, agg, aggs, agg_subst,
+                                probe.schema, scales), None
+
+    def _pushed_aggregate(self, plan: "_PushedAggregate",
+                          s: ast.Select) -> pa.Table:
+        """Run the scan with the aggregate below the merge, add the
+        splits' partials by group, and hand the grouped table to HAVING
+        and the select list as `_aggregate` does."""
+        from paimon_tpu.ops.scan_agg import ROWS_COL, count_col, value_col
+        partials = plan.table.to_arrow(predicate=plan.predicate,
+                                       aggregate=plan.aggregate)
+        measures = plan.aggregate.measures
+        specs = [(ROWS_COL, "sum")]
+        for k, m in enumerate(measures):
+            specs.append((count_col(k), "sum"))
+            if m.func != "count":
+                specs.append((value_col(k), m.func))
+        keys = list(plan.aggregate.group_by)
+        if not keys:
+            partials = partials.append_column(
+                "__gall", pa.array([1] * partials.num_rows, pa.int64()))
+        total = partials.group_by(keys or ["__gall"], use_threads=False) \
+            .aggregate(specs)
+        n = total.num_rows
+        cols = {}
+        for i, name in enumerate(keys):
+            cols[f"__g{i}"] = total.column(name)
+        if not keys:
+            if n == 0:                  # no split: one row of nothing
+                n = 1
+                total = pa.table({f.name: pa.nulls(1, f.type)
+                                  for f in total.schema})
+            cols["__gall"] = pa.array([1], pa.int64())
+        for k, (key, f) in enumerate(plan.calls.items()):
+            cn = plan.subst[key]
+            t = plan.schema.field(cn).type
+            counts = [c or 0 for c in total.column(
+                f"{ROWS_COL if measures[k].expr is None else count_col(k)}"
+                f"_sum").to_pylist()]
+            if f.name == "count":
+                cols[cn] = pa.array(counts, t)
+                continue
+            fn = measures[k].func
+            values = [None if v is None else int(v) for v in total.column(
+                f"{value_col(k)}_{fn}").to_pylist()]
+            if f.name == "avg":
+                # Arrow's mean of a decimal: the exact sum over the
+                # count, rounded half away from zero at the input's scale
+                values = [None if v is None else
+                          (2 * abs(v) + c) // (2 * c) * (1 if v >= 0 else -1)
+                          for v, c in zip(values, counts)]
+            cols[cn] = _lane_values(values, t, plan.scales[k])
+        gtable = pa.table({name: cols[name] for name in plan.schema.names})
+        return self._aggregated(gtable, plan.subst, plan.calls, s)
 
     # -- window functions ----------------------------------------------------
     def _apply_windows(self, scope: Scope,
@@ -1302,6 +1590,15 @@ class SQLContext:
                 if isinstance(rel, FileStoreTable) and \
                         self._pushed_limit(s) is not None:
                     lines.append(f"  pushed limit: {s.limit}")
+                if isinstance(rel, FileStoreTable):
+                    cols = self._pushed_projection(rel, alias, s)
+                    if cols is not None:
+                        lines.append(f"  pushed projection: {cols}")
+            if s.group_by or any(_find_aggs(i.expr) for i in s.items):
+                plan, why = self._plan_pushed_aggregate(s)
+                lines.append(f"  pushed aggregate: {plan.aggregate!r}"
+                             if plan else
+                             f"  pushed aggregate: none ({why})")
         if s.where is not None:
             lines.append(f"Filter: {s.where!r}")
         for j in s.joins:
@@ -2136,6 +2433,56 @@ def _ordinal(v: int, n: int) -> int:
     if not 1 <= v <= n:
         raise SQLError(f"positional reference {v} out of range 1..{n}")
     return v
+
+
+class _PushedAggregate:
+    """A statement whose aggregate runs below the merge: the table, the
+    exact filter, the scan's description, and what `_grouped` would
+    name and type its results."""
+
+    def __init__(self, table, predicate, aggregate, calls, subst, schema,
+                 scales):
+        self.table = table
+        self.predicate = predicate
+        self.aggregate = aggregate        # ops.scan_agg.ScanAggregate
+        self.calls = calls                # repr -> ast.Func
+        self.subst = subst                # repr -> grouped column
+        self.schema = schema              # the grouped table's
+        self.scales = scales              # a call's decimal scale
+
+
+def _lane_values(values: List[Optional[int]], t: pa.DataType,
+                 scale: int) -> pa.Array:
+    """Integers of a lane domain (unscaled decimals, days) as an Arrow
+    array of the result type."""
+    if pa.types.is_decimal(t):
+        wide = decimal.Context(prec=80)     # the default rounds at 28
+        return pa.array([None if v is None else
+                         decimal.Decimal(v).scaleb(-scale, context=wide)
+                         for v in values], t)
+    if pa.types.is_date32(t):
+        return pa.array(values, pa.int32()).cast(t)
+    return pa.array(values, t)
+
+
+def _decimal_literals(pred: P.Predicate, decimal_fields) -> P.Predicate:
+    """Numeric literals compared with DECIMAL columns as the decimals
+    the user wrote (Arrow would compare the column as a double, and
+    refuses an int64 beside a decimal of more than 19 - scale digits)."""
+    def exact(v):
+        if isinstance(v, bool) or not isinstance(v, (int, float)):
+            return v
+        return decimal.Decimal(v if isinstance(v, int) else repr(v))
+
+    if isinstance(pred, P.Compound):
+        return P.Compound(pred.op, [_decimal_literals(c, decimal_fields)
+                                    for c in pred.children])
+    if pred.field not in decimal_fields:
+        return pred
+    lit = pred.literal
+    if isinstance(lit, (list, tuple)):
+        lit = type(lit)(exact(v) for v in lit)
+    return P.Leaf(pred.op, pred.field, exact(lit))
 
 
 def _probe_scope(cols: List[str], alias: str) -> Scope:

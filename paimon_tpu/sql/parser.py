@@ -1050,6 +1050,10 @@ class Parser:
                 "COMMENT", "KEY", "VERSION", "FIRST", "LAST",
                 "TRUNCATE", "MERGE", "USING", "MATCHED")):
             name = self.ident()
+            if name.upper() == "DATE" and self.peek().kind == "STRING":
+                # DATE '1998-09-02', beside TIMESTAMP '...'
+                import datetime as _dt
+                return Literal(_dt.date.fromisoformat(self.next().value))
             if name.upper() in ("ARRAY", "MAP") and \
                     self.peek().kind == "OP" and self.peek().value == "[":
                 # ARRAY[e1, ...] / MAP[k1, v1, ...] constructors

@@ -178,7 +178,10 @@ class FileStoreTable:
     def to_arrow(self, projection: Optional[List[str]] = None,
                  predicate: Optional[Predicate] = None,
                  with_row_ids: bool = False,
-                 limit: Optional[int] = None) -> pa.Table:
+                 limit: Optional[int] = None,
+                 aggregate=None) -> pa.Table:
+        """`aggregate` (ops.scan_agg.ScanAggregate): the splits' partial
+        aggregates in place of rows, see `ReadBuilder.with_aggregate`."""
         # request.timeout entry point covering the PLAN too: the
         # manifest walk is store IO and must ride the same deadline
         # as the read (TableRead.to_arrow's own entry scope only
@@ -201,6 +204,8 @@ class FileStoreTable:
                 # pushed LIMIT: the pipelined read stops admitting
                 # splits once enough rows are buffered
                 rb = rb.with_limit(limit)
+            if aggregate is not None:
+                rb = rb.with_aggregate(aggregate)
             with span("scan.plan", cat="scan"):
                 splits = rb.new_scan().plan().splits
             return rb.new_read().to_arrow(splits)
@@ -730,6 +735,7 @@ class ReadBuilder:
         self._partition_filter: Optional[dict] = None
         self._buckets: Optional[List[int]] = None
         self._limit: Optional[int] = None
+        self._aggregate = None
 
     def with_projection(self, columns: List[str]) -> "ReadBuilder":
         self._projection = list(columns)
@@ -750,6 +756,33 @@ class ReadBuilder:
     def with_limit(self, limit: int) -> "ReadBuilder":
         self._limit = limit
         return self
+
+    def with_aggregate(self, aggregate) -> "ReadBuilder":
+        """Aggregate below the merge: the read returns, per split, one
+        row a group of partial sums / counts / minima / maxima
+        (`ops.scan_agg.ScanAggregate` says which; its module says what
+        the partial columns are) in place of the split's rows, with the
+        filter applied exactly.  Buckets are key-disjoint, so the caller
+        adds the splits' partials by group.  For a primary-key table of
+        the deduplicate or first-row engine (`supports_aggregate`; else
+        this raises), after `aggregate.unsupported(...)` said None."""
+        if not self.supports_aggregate():
+            raise ValueError(
+                "with_aggregate needs a primary-key table of the "
+                "deduplicate or first-row engine, read without "
+                "record-level expiry or the sequence-number column")
+        self._aggregate = aggregate
+        return self
+
+    def supports_aggregate(self) -> bool:
+        """Whether this table's merge keeps one whole row a key and the
+        read has no option that works on the merged rows."""
+        from paimon_tpu.options import MergeEngine
+        opts = self.table.options
+        return bool(self.table.primary_keys) and opts.merge_engine in (
+            MergeEngine.DEDUPLICATE, MergeEngine.FIRST_ROW) \
+            and not opts.record_level_expire_time_ms \
+            and not opts.get(CoreOptions.TABLE_READ_SEQUENCE_NUMBER)
 
     def with_row_ids(self, flag: bool = True) -> "ReadBuilder":
         """Materialize `_ROW_ID` on append-table reads (row tracking)."""
@@ -960,6 +993,8 @@ class TableRead:
             self._read.with_projection(builder._projection)
         if builder._predicate is not None:
             self._read.with_filter(builder._predicate)
+        if builder._aggregate is not None:
+            self._read.with_aggregate(builder._aggregate)
 
     def read_split(self, split: DataSplit) -> pa.Table:
         t = self._read.read_split(split)
@@ -1015,6 +1050,8 @@ class TableRead:
 
     def _finalize(self, t: pa.Table,
                   apply_limit: bool = True) -> pa.Table:
+        if self.builder._aggregate is not None:
+            return t                    # partials: no rows to shape
         if self.builder._projection:
             from paimon_tpu.core.read import ROW_KIND_COL
             from paimon_tpu.core.row_tracking import ROW_ID_COL

@@ -391,7 +391,13 @@ def _spill_dirs():
 
 class TestSpillableWriteBuffer:
     @pytest.fixture(autouse=True)
-    def _snapshot_tmp(self):
+    def _snapshot_tmp(self, tmp_path, monkeypatch):
+        # a temp dir of its own: another worker's spill test running at
+        # the same moment must not show up in this one's listing
+        import tempfile
+        own = tmp_path / "tmp"
+        own.mkdir()
+        monkeypatch.setattr(tempfile, "tempdir", str(own))
         self._before = _spill_dirs()
 
     def _write_many(self, t, batches=6, per=500):

@@ -417,12 +417,18 @@ def test_aggressive_spill_folding_exact_counts(tmp_path, par):
     assert snap.changelog_record_count == 2000
 
 
-def test_spill_dirs_cleaned_on_pipelined_abort(tmp_path):
+def test_spill_dirs_cleaned_on_pipelined_abort(tmp_path, monkeypatch):
     """close() without prepare_commit joins the pool workers and then
     removes every spill temp dir the async spill tasks created."""
     import glob
     import os
     import tempfile as _tempfile
+
+    # a temp dir of its own: another worker's spill test running at the
+    # same moment must not show up in this one's listing
+    own = tmp_path / "tmp"
+    own.mkdir()
+    monkeypatch.setattr(_tempfile, "tempdir", str(own))
 
     def spill_dirs():
         return set(glob.glob(
