@@ -37,7 +37,8 @@ __all__ = ["Counter", "Gauge", "Histogram", "MetricGroup",
            "WRITE_ROUTE_MS", "WRITE_ROUTE_NOCOPY_ROWS",
            "MERGE_PREP_MS", "MERGE_DEVICE_MS", "MERGE_AGG_MS",
            "MERGE_SELECT_MS", "MERGE_GATHER_MS", "MERGE_GATHER_BYTES",
-           "MERGE_RETURN_BYTES", "SCAN_AGG_MS", "SCAN_AGG_BELOW_ROWS",
+           "MERGE_RETURN_BYTES", "MERGE_PREP_PLANAR_ROWS", "SCAN_AGG_MS",
+           "SCAN_AGG_BELOW_ROWS",
            "SCAN_ROWS_IN",
            "STREAM_EVENTS_INGESTED", "STREAM_CHECKPOINTS",
            "STREAM_CHECKPOINT_MS", "STREAM_LOOP_RESTARTS",
@@ -171,6 +172,9 @@ MERGE_SELECT_MS = "select_ms"               # its per-segment row selections
 MERGE_GATHER_MS = "gather_ms"               # Arrow take in merge order
 MERGE_GATHER_BYTES = "gather_bytes"         # counter: buffer bytes taken
 MERGE_RETURN_BYTES = "return_bytes"         # counter: bytes a merge handed back
+MERGE_PREP_PLANAR_ROWS = "prep_planar_rows"  # counter: rows whose device
+                                            # operands went straight from
+                                            # the Arrow chunks to planes
 
 # streaming-daemon counter/gauge/histogram names (stream metric group;
 # producer is service/stream_daemon.py, consumers tests/soak_harness.py

@@ -18,6 +18,11 @@ and merge functions with one data-parallel plan:
 
 Static shapes: inputs are padded to the next power of two; padding rows
 carry validity=1 which sorts after all real rows and never joins a segment.
+The operands are planes, one contiguous uint32[m] array each
+(`_new_planes`).  `merge_runs` decides the route before it encodes
+(`sorted_winners`), so a device merge of fixed-width keys writes each
+operand once, straight from the Arrow chunks (`device_planes`); callers
+that hold a lane matrix reach the planes through `_padded_operands`.
 
 The router (`device_sorted_winners`) sends each merge one of two ways:
 to the host (offset-value coded merge of sorted runs, C radix sort of a
@@ -49,17 +54,21 @@ import pyarrow as pa
 
 from paimon_tpu.metrics import (
     MERGE_DEVICE_MS, MERGE_GATHER_BYTES, MERGE_GATHER_MS, MERGE_PREP_MS,
-    MERGE_RETURN_BYTES, global_registry,
+    MERGE_PREP_PLANAR_ROWS, MERGE_RETURN_BYTES, global_registry,
 )
 from paimon_tpu.obs.trace import metrics_enabled, span
-from paimon_tpu.ops.normkey import NormalizedKeyEncoder
+from paimon_tpu.ops.normkey import (
+    LazyPackedLanes, NormalizedKeyEncoder, words64, write_int64_column,
+    write_words,
+)
 from paimon_tpu.ops.ovc import (
     OVC_OFF_SENTINEL, ovc_sorted_winners, run_ovc_offsets,
 )
 from paimon_tpu.types import RowKind
 
 __all__ = ["merge_runs", "MergeResult", "MergeOperands", "merge_operands",
-           "device_sorted_winners", "route_to_host", "host_sorted_winners",
+           "device_sorted_winners", "sorted_winners", "route_to_host",
+           "host_sorted_winners",
            "take_link_reading", "user_seq_order_lanes", "SEQ_COL",
            "KIND_COL"]
 
@@ -118,35 +127,62 @@ def gather_values(values: np.ndarray, indices: np.ndarray) -> np.ndarray:
     return _gathered(len(indices), 1, lambda: values[indices])
 
 
-def prep_span(rows: int):
+def prep_span(rows: int, form: Optional[str] = None):
     """`merge.prep`: host work that readies a merge's operands —
     concat, key-lane encode, sequence split, pad to the program's
-    size.  Shared by every caller of `device_sorted_winners`."""
+    size.  Shared by every caller of `device_sorted_winners`.  A span
+    that writes the device's operands says in `form` how: `planes`
+    (key and sequence words straight from the Arrow chunks) or `matrix`
+    (an encoded lane matrix transposed into them)."""
+    attrs = {} if form is None else {"form": form}
     return span("merge.prep", cat="merge", group="merge",
-                metric=MERGE_PREP_MS, rows=rows)
+                metric=MERGE_PREP_MS, rows=rows, **attrs)
+
+
+def _new_planes(num_lanes: int, n: int) -> List[np.ndarray]:
+    """The device programs' operands for `n` rows of `num_lanes` lanes,
+    still empty: num_lanes + 3 planes of uint32[m], m the program's
+    size, each contiguous, so it uploads as it lies: the lanes, the
+    sequence's high and low words, and the validity word that sorts
+    padding last (zero in a real row, one in the pad).  An array each,
+    not one block: a plane of a window under 8Mi rows is small enough
+    for the allocator to hand back a block it has kept, where one block
+    of them all would be mapped and faulted in afresh every merge."""
+    planes = [np.zeros(_pad_size(n), dtype=np.uint32)
+              for _ in range(num_lanes + 3)]
+    planes[-1][n:] = 1
+    return planes
+
+
+def _write_lanes(planes: List[np.ndarray], row: int, lanes: np.ndarray
+                 ) -> None:
+    """A lane matrix uint32[n, L] transposed into planes row..row+L."""
+    for j in range(lanes.shape[1]):
+        planes[row + j][:len(lanes)] = lanes[:, j]
 
 
 def _padded_operands(lanes, order_lanes: Optional[np.ndarray],
-                     seq: np.ndarray):
-    """The device programs' operands, padded to the program's size
-    (under `merge.prep`): the lane matrix (key lanes, then any user
-    order lanes) as given and padded, the sequence split in two
-    words, and the validity word that sorts padding last."""
-    with prep_span(len(seq)):
-        lanes = np.asarray(lanes)    # materialize if lazily concatenated
-        if order_lanes is not None and order_lanes.shape[1] > 0:
-            lanes = np.concatenate([lanes, order_lanes], axis=1)
-        n, m = len(seq), _pad_size(len(seq))
-        lanes_p = np.zeros((m, lanes.shape[1]), dtype=np.uint32)
-        lanes_p[:n] = lanes
-        useq = seq.astype(np.int64, copy=False).view(np.uint64)
-        seq_hi = np.zeros(m, dtype=np.uint32)
-        seq_lo = np.zeros(m, dtype=np.uint32)
-        seq_hi[:n] = (useq >> np.uint64(32)).astype(np.uint32)
-        seq_lo[:n] = (useq & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-        invalid = np.ones(m, dtype=np.uint32)
-        invalid[:n] = 0
-    return lanes, lanes_p, seq_hi, seq_lo, invalid
+                     seq: np.ndarray) -> List[np.ndarray]:
+    """The operand planes (`_new_planes`) of a caller that hands lane
+    matrices — key lanes, then any user order lanes — and the sequence
+    as an array: one transposing copy, under `merge.prep`."""
+    n = len(seq)
+    with prep_span(n, form="matrix"):
+        parts = [lanes] if _no_user_order(order_lanes) \
+            else [lanes, order_lanes]
+        planes = _new_planes(sum(p.shape[1] for p in parts), n)
+        row = 0
+        for part in parts:
+            if isinstance(part, LazyPackedLanes):
+                # a packed u64 key: its words, without the [n, 2] matrix
+                write_words(words64(part.packed), planes[row][:n],
+                            planes[row + 1][:n])
+            else:
+                _write_lanes(planes, row, np.asarray(part))
+            row += part.shape[1]
+        write_words(words64(np.ascontiguousarray(seq, dtype=np.int64)),
+                    planes[row][:n], planes[row + 1][:n])
+    return planes
 
 
 def device_span(route: str, rows: int, padded_rows: int,
@@ -504,22 +540,26 @@ def count_returned(nbytes: int) -> None:
 
 
 def _no_user_order(order_lanes: Optional[np.ndarray]) -> bool:
-    return order_lanes is None or order_lanes.shape[1] == 0
+    return _num_order_lanes(order_lanes) == 0
 
 
-def route_to_host(n: int, num_key_lanes: int,
-                  order_lanes: Optional[np.ndarray], winners_only: bool,
-                  epilogue_h2d_bytes: int = 0,
+def _num_order_lanes(order_lanes: Optional[np.ndarray]) -> int:
+    return 0 if order_lanes is None else order_lanes.shape[1]
+
+
+def route_to_host(n: int, num_key_lanes: int, num_order_lanes: int,
+                  winners_only: bool, epilogue_h2d_bytes: int = 0,
                   d2h_bytes: Optional[int] = None) -> bool:
     """The router's decision for one merge of `n` rows, logged in
     ROUTE_LOG: True = sort on the host.  The two pins first, then the
-    cpu backend (always the host), then the cost model."""
+    cpu backend (always the host), then the cost model.  It reads the
+    merge's shape alone, so it is taken before the encode, which then
+    writes the form the route reads."""
     force_device = os.environ.get("PAIMON_FORCE_DEVICE_SORT") == "1"
     force_host = os.environ.get("PAIMON_FORCE_HOST_SORT") == "1"
-    no_user_order = _no_user_order(order_lanes)
-    host_fast = num_key_lanes == 2 and winners_only and no_user_order
-    nl_total = num_key_lanes + (0 if no_user_order
-                                else order_lanes.shape[1])
+    host_fast = num_key_lanes == 2 and winners_only \
+        and num_order_lanes == 0
+    nl_total = num_key_lanes + num_order_lanes
     use_host = force_host
     pinned = force_host or force_device
     if not pinned and n > 0:
@@ -601,23 +641,36 @@ def device_sorted_winners(lanes: np.ndarray, seq: np.ndarray,
     backend, for padding and validity).
     """
     n, num_key_lanes = lanes.shape
-    if route_to_host(n, num_key_lanes, order_lanes, winners_only):
+    if route_to_host(n, num_key_lanes, _num_order_lanes(order_lanes),
+                     winners_only):
         res = host_sorted_winners(lanes, seq, keep, order_lanes,
                                   winners_only, packed, run_starts)
         count_returned(res[0].nbytes + res[1].nbytes)
         return res
-    PATH_COUNTS["device"] += 1
-    lanes, lanes_p, seq_hi, seq_lo, invalid = _padded_operands(
-        lanes, order_lanes, seq)
-    m, num_lanes = lanes_p.shape
+    ovc = None
+    if run_starts is not None and not winners_only:
+        ovc = (lanes if _no_user_order(order_lanes) else np.concatenate(
+            [np.asarray(lanes), order_lanes], axis=1), run_starts)
+    return _device_winners(_padded_operands(lanes, order_lanes, seq), n,
+                           num_key_lanes, keep, winners_only, ovc)
 
-    with_ovc = run_starts is not None and not winners_only
+
+def _device_winners(planes: List[np.ndarray], n: int, num_key_lanes: int,
+                    keep: str, winners_only: bool,
+                    ovc: Optional[Tuple[np.ndarray, np.ndarray]] = None
+                    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """The device route: the operand planes (`_new_planes`) of `n` real
+    rows up, the program, the result down.  `ovc`: the unpadded lane
+    matrix and the bounds of the sorted runs it holds, for the full
+    return's offset-value codes."""
+    PATH_COUNTS["device"] += 1
+    num_lanes, m = len(planes) - 3, len(planes[0])
+    with_ovc = ovc is not None
     with device_span("packed" if winners_only
                      else "full_ovc" if with_ovc else "full", n, m,
                      4 * m * (num_lanes + 3 + with_ovc),
                      4 * m if winners_only else 9 * m):
-        lane_list = tuple(jnp.asarray(lanes_p[:, i])
-                          for i in range(num_lanes))
+        operands = [jnp.asarray(plane) for plane in planes]
         # sorted-run inputs ship their offset-value codes to the device:
         # the winner-select consumes the single-int offsets first and
         # only lane-compares pairs the codes cannot decide (full variant
@@ -627,15 +680,15 @@ def device_sorted_winners(lanes: np.ndarray, seq: np.ndarray,
         ovc_args = ()
         if with_ovc:
             off = np.full(m, OVC_OFF_SENTINEL, dtype=np.uint32)
-            off[:n] = run_ovc_offsets(lanes, run_starts)
+            off[:n] = run_ovc_offsets(np.asarray(ovc[0]), ovc[1])
             ovc_args = (jnp.asarray(off),)
         # a kernel the compiler refuses raises here: there is no second,
         # quieter program to fall back to
         fn = _merge_fn_packed(num_lanes, keep, num_key_lanes) \
             if winners_only \
             else _merge_fn(num_lanes, keep, num_key_lanes, with_ovc)
-        out = fn(lane_list, jnp.asarray(seq_hi),
-                 jnp.asarray(seq_lo), jnp.asarray(invalid), *ovc_args)
+        out = fn(tuple(operands[:num_lanes]), *operands[num_lanes:],
+                 *ovc_args)
         if winners_only:
             # one 4-byte word/row off the device: perm | (winner << 31)
             packed = np.asarray(out)
@@ -649,6 +702,21 @@ def device_sorted_winners(lanes: np.ndarray, seq: np.ndarray,
     return perm, winner, prev
 
 
+def _user_seq_encoder(schema: pa.Schema, seq_fields: Sequence[str]
+                      ) -> NormalizedKeyEncoder:
+    """The encoder of the user-defined sequence columns' order lanes."""
+    for f in seq_fields:
+        t = schema.field(f).type
+        if pa.types.is_string(t) or pa.types.is_large_string(t) or \
+                pa.types.is_binary(t) or pa.types.is_large_binary(t):
+            raise ValueError(
+                f"sequence.field {f!r} must be numeric/temporal; string "
+                f"sequences would compare only by a fixed-width prefix")
+    return NormalizedKeyEncoder(
+        [schema.field(f).type for f in seq_fields],
+        nullable=[True] * len(seq_fields))
+
+
 def user_seq_order_lanes(table: pa.Table,
                          seq_fields: Sequence[str],
                          descending: bool = False) -> np.ndarray:
@@ -658,16 +726,7 @@ def user_seq_order_lanes(table: pa.Table,
     sort order).  `descending` implements
     sequence.field.sort-order=descending: the SMALLER user sequence
     wins, via bitwise inversion of the value lanes."""
-    for f in seq_fields:
-        t = table.schema.field(f).type
-        if pa.types.is_string(t) or pa.types.is_large_string(t) or \
-                pa.types.is_binary(t) or pa.types.is_large_binary(t):
-            raise ValueError(
-                f"sequence.field {f!r} must be numeric/temporal; string "
-                f"sequences would compare only by a fixed-width prefix")
-    enc = NormalizedKeyEncoder(
-        [table.schema.field(f).type for f in seq_fields],
-        nullable=[True] * len(seq_fields))
+    enc = _user_seq_encoder(table.schema, seq_fields)
     lanes, _ = enc.encode_table(table, seq_fields)
     pos = 0
     for nl in enc.lanes_per_col:
@@ -739,45 +798,47 @@ class _LazyLanes:
 
 @dataclass
 class MergeOperands:
-    """What `merge_operands` readies for the router (under
-    `merge.prep`): the runs as one table (oldest first) and, unless it
-    is empty, its key lanes, sequence numbers and sorted-run bounds."""
+    """One merge's operands.  `merge_operands` concatenates the runs
+    (oldest first) and says what the router reads — the rows, the lane
+    counts, whether any key was cut to a prefix; the route taken, the
+    caller asks for the form it reads: `encode_host` fills the host
+    form (lanes, packed key, sequence numbers, user order lanes),
+    `device_planes` gives the device programs' operands.
+    A key that can be cut and pre-encoded runs (`encoded=`) are in the
+    host form from the start."""
     table: pa.Table
     keep: str = "last"
+    key_names: Sequence[str] = ()
+    key_encoder: Optional[NormalizedKeyEncoder] = None
+    seq_fields: Optional[Sequence[str]] = None
+    seq_desc: bool = False
+    num_order_lanes: int = 0
+    run_starts: Optional[np.ndarray] = None
     lanes: Optional[np.ndarray] = None
     truncated: Optional[np.ndarray] = None
     packed: Optional[np.ndarray] = None
     seq: Optional[np.ndarray] = None
-    run_starts: Optional[np.ndarray] = None
     order_lanes: Optional[np.ndarray] = None
 
+    @property
+    def n(self) -> int:
+        return self.table.num_rows
 
-def merge_operands(runs: Sequence[pa.Table], key_names: Sequence[str],
-                   merge_engine: str = "deduplicate",
-                   key_encoder: Optional[NormalizedKeyEncoder] = None,
-                   seq_fields: Optional[Sequence[str]] = None,
-                   seq_desc: bool = False,
-                   encoded: Optional[Sequence[Tuple[np.ndarray,
-                                                    np.ndarray]]] = None
-                   ) -> MergeOperands:
-    """Concat, key-lane encode and sequence of one merge's runs: the
-    host work ahead of `device_sorted_winners`, shared by `merge_runs`
-    and the scan's pushed aggregate (ops/scan_agg.py)."""
-    if not runs:
-        raise ValueError("No runs to merge")
-    keep = "first" if merge_engine == "first-row" else "last"
-    with prep_span(sum(r.num_rows for r in runs)):
-        table = pa.concat_tables(runs, promote_options="none")
-        n = table.num_rows
-        if n == 0:
-            return MergeOperands(table, keep)
+    @property
+    def num_key_lanes(self) -> int:
+        return self.key_encoder.num_lanes
 
-        if key_encoder is None:
-            key_encoder = NormalizedKeyEncoder(
-                [table.schema.field(k).type for k in key_names],
-                nullable=[table.schema.field(k).nullable
-                          for k in key_names])
-        packed = None
+    @property
+    def any_truncated(self) -> bool:
+        return self.truncated is not None and bool(self.truncated.any())
+
+    def _order_lanes(self) -> Optional[np.ndarray]:
+        return user_seq_order_lanes(self.table, self.seq_fields,
+                                    self.seq_desc) \
+            if self.seq_fields else None
+
+    def _encode_host(self, encoded=None) -> None:
+        table, packed = self.table, None
         if encoded is not None:
             # caller already lane-encoded each run (streamed windows
             # encode once for the window cut — don't pay the encode
@@ -801,33 +862,117 @@ def merge_operands(runs: Sequence[pa.Table], key_names: Sequence[str],
                          if len(encoded) > 1
                          else np.asarray(encoded[0][0]))
         else:
-            lanes, truncated, packed = key_encoder.encode_table_ex(
-                table, key_names)
-        seq = np.asarray(
+            lanes, truncated, packed = self.key_encoder.encode_table_ex(
+                table, self.key_names)
+        self.lanes, self.truncated, self.packed = lanes, truncated, packed
+        self.seq = np.asarray(
             table.column(SEQ_COL).combine_chunks().cast(pa.int64()))
+        self.order_lanes = self._order_lanes()
 
-        # sorted-run boundaries for the OVC merge path: every input run
-        # (or pre-cut window chunk — chunks of one run arrive in run
-        # order, so treating each as its own run preserves arrival
-        # order) is individually (key, seq)-sorted by the write/compact
-        # invariants; the OVC path re-verifies and falls back if a
-        # caller violates that
-        if encoded is not None:
-            run_lens = [e[0].shape[0] for e in encoded]
-        else:
-            run_lens = [r.num_rows for r in runs]
-        run_starts = np.concatenate(
-            [[0], np.cumsum(run_lens)]).astype(np.int64)
+    def encode_host(self) -> None:
+        """The host form, under `merge.prep`, unless it is there."""
+        if self.lanes is None:
+            with prep_span(self.n):
+                self._encode_host()
+
+    def device_planes(self) -> List[np.ndarray]:
+        """The device programs' operands (`_new_planes`), under
+        `merge.prep`.  From the host form where that is there, through
+        one transposing copy (`form` matrix).  Else each is written
+        once (`form` planes): the key's and the sequence's words go
+        from the Arrow chunks' own buffers to their place in the planes
+        and nothing the size of the merge exists in between; only user
+        order lanes pass through their matrix."""
+        if self.lanes is not None:
+            return _padded_operands(self.lanes, self.order_lanes, self.seq)
+        n, table = self.n, self.table
+        with prep_span(n, form="planes"):
+            num_key_lanes = self.num_key_lanes
+            planes = _new_planes(num_key_lanes + self.num_order_lanes, n)
+            self.key_encoder.encode_planes(
+                [table.column(k) for k in self.key_names], planes)
+            row = num_key_lanes
+            if self.num_order_lanes:
+                _write_lanes(planes, row, self._order_lanes())
+                row += self.num_order_lanes
+            seq = table.column(SEQ_COL)
+            if not pa.types.is_int64(seq.type):
+                seq = seq.cast(pa.int64())
+            write_int64_column(seq, planes[row][:n], planes[row + 1][:n])
+        if metrics_enabled():
+            global_registry().group("merge") \
+                .counter(MERGE_PREP_PLANAR_ROWS).inc(n)
+        return planes
+
+
+def merge_operands(runs: Sequence[pa.Table], key_names: Sequence[str],
+                   merge_engine: str = "deduplicate",
+                   key_encoder: Optional[NormalizedKeyEncoder] = None,
+                   seq_fields: Optional[Sequence[str]] = None,
+                   seq_desc: bool = False,
+                   encoded: Optional[Sequence[Tuple[np.ndarray,
+                                                    np.ndarray]]] = None
+                   ) -> MergeOperands:
+    """The concat of one merge's runs and what its router reads: the
+    host work ahead of the route decision, shared by `merge_runs` and
+    the scan's pushed aggregate (ops/scan_agg.py).  The encode waits
+    for the decision where nothing needs it sooner (`MergeOperands`)."""
+    if not runs:
+        raise ValueError("No runs to merge")
+    keep = "first" if merge_engine == "first-row" else "last"
+    with prep_span(sum(r.num_rows for r in runs)):
+        table = pa.concat_tables(runs, promote_options="none")
+        if table.num_rows == 0:
+            return MergeOperands(table, keep)
+        if key_encoder is None:
+            key_encoder = NormalizedKeyEncoder(
+                [table.schema.field(k).type for k in key_names],
+                nullable=[table.schema.field(k).nullable
+                          for k in key_names])
         if seq_fields and keep == "first":
             # reference forbids the combo: "first by user sequence" would
             # let later commits replace the retained first row
             raise ValueError(
                 "sequence.field cannot be used with merge-engine first-row")
-        order_lanes = user_seq_order_lanes(table, seq_fields, seq_desc) \
-            if seq_fields else None
-    return MergeOperands(table, keep, lanes, truncated, packed, seq,
-                         run_starts if order_lanes is None else None,
-                         order_lanes)
+        # sorted-run boundaries for the OVC merge path: every input run
+        # (or pre-cut window chunk — chunks of one run arrive in run
+        # order, so treating each as its own run preserves arrival
+        # order) is individually (key, seq)-sorted by the write/compact
+        # invariants; the OVC path re-verifies and falls back if a
+        # caller violates that.  Not where a user sequence orders a key.
+        run_lens = [e[0].shape[0] for e in encoded] \
+            if encoded is not None else [r.num_rows for r in runs]
+        op = MergeOperands(
+            table, keep, key_names, key_encoder, seq_fields, seq_desc,
+            _user_seq_encoder(table.schema, seq_fields).num_lanes
+            if seq_fields else 0,
+            None if seq_fields else np.concatenate(
+                [[0], np.cumsum(run_lens)]).astype(np.int64))
+        if encoded is not None or not key_encoder.fixed_width:
+            op._encode_host(encoded)
+    return op
+
+
+def sorted_winners(op: MergeOperands, winners_only: bool
+                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """`device_sorted_winners` of a merge's operands, the route decided
+    ahead of the encode: one decision, then the one form it reads."""
+    n = op.n
+    if route_to_host(n, op.num_key_lanes, op.num_order_lanes,
+                     winners_only):
+        op.encode_host()
+        res = host_sorted_winners(op.lanes, op.seq, op.keep,
+                                  op.order_lanes, winners_only, op.packed,
+                                  op.run_starts)
+        count_returned(res[0].nbytes + res[1].nbytes)
+        return res
+    ovc = None
+    if not winners_only and op.run_starts is not None:
+        # the full return's offset-value codes read the lane matrix
+        op.encode_host()
+        ovc = (op.lanes, op.run_starts)
+    return _device_winners(op.device_planes(), n, op.num_key_lanes,
+                           op.keep, winners_only, ovc)
 
 
 def merge_runs(runs: Sequence[pa.Table], key_names: Sequence[str],
@@ -847,7 +992,7 @@ def merge_runs(runs: Sequence[pa.Table], key_names: Sequence[str],
     """
     op = merge_operands(runs, key_names, merge_engine, key_encoder,
                         seq_fields, seq_desc, encoded)
-    table, keep, truncated, seq = op.table, op.keep, op.truncated, op.seq
+    table, keep = op.table, op.keep
     n = table.num_rows
     if n == 0:
         return MergeResult(table, np.zeros(0, dtype=np.int64))
@@ -855,18 +1000,17 @@ def merge_runs(runs: Sequence[pa.Table], key_names: Sequence[str],
     # rows, so the packed-key fast path is admissible — unless any key
     # was prefix-truncated: _refine_truncated needs the full path's
     # seq-ordered segments with winners at segment boundaries
-    perm, winner, prev = device_sorted_winners(
-        op.lanes, seq, keep, op.order_lanes,
-        winners_only=not with_prev and not truncated.any(),
-        packed=op.packed, run_starts=op.run_starts)
+    truncated = op.any_truncated
+    perm, winner, prev = sorted_winners(
+        op, winners_only=not with_prev and not truncated)
 
     win_pos = np.flatnonzero(winner)
     indices = perm[win_pos].astype(np.int64)
     prev_idx = prev[win_pos].astype(np.int64) if with_prev else None
 
-    if truncated.any():
+    if truncated:
         indices, prev_idx = _refine_truncated(
-            table, key_names, perm, winner, truncated, seq, keep,
+            table, key_names, perm, winner, op.truncated, op.seq, keep,
             with_prev, prev)
 
     if drop_deletes and KIND_COL in table.column_names:

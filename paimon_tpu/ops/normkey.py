@@ -23,6 +23,7 @@ non-nullable (primary keys) skip the lane.
 
 from __future__ import annotations
 
+import sys
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -49,6 +50,75 @@ def _floats_to_u64(arr: np.ndarray) -> np.ndarray:
 def _split_u64(x: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     return ((x >> np.uint64(32)).astype(np.uint32),
             (x & np.uint64(0xFFFFFFFF)).astype(np.uint32))
+
+
+def _int64_values(arr: pa.Array) -> pa.Array:
+    """An "int" kind column as int64.  Arrow casts date32 and time32
+    to their 32-bit storage only, so those go through it."""
+    if pa.types.is_date32(arr.type) or pa.types.is_time32(arr.type):
+        arr = arr.cast(pa.int32())
+    return arr.cast(pa.int64())
+
+
+def _fixed_u64(arr: pa.Array, kind: str) -> np.ndarray:
+    """An "int" or "float" kind array's order-preserving uint64; a null
+    reads as the value 0's."""
+    cast = _int64_values(arr) if kind == "int" else arr.cast(pa.float64())
+    # fill_null is a full copy at millions of rows: skip it for
+    # null-free columns (the common pk case)
+    if cast.null_count:
+        cast = cast.fill_null(0)
+    vals = np.asarray(cast)
+    return _ints_to_u64(vals) if kind == "int" else _floats_to_u64(vals)
+
+
+# where a 64-bit value's low and high 32-bit words lie in memory
+_LO, _HI = (0, 1) if sys.byteorder == "little" else (1, 0)
+_SIGN32 = np.uint32(1 << 31)
+
+
+def _chunks(col) -> List[pa.Array]:
+    return col.chunks if isinstance(col, pa.ChunkedArray) else [col]
+
+
+def words64(values: np.ndarray) -> np.ndarray:
+    """uint32[k, 2] view of k contiguous 64-bit values: no copy."""
+    return values.view(np.uint32).reshape(-1, 2)
+
+
+def _value_words(chunk: pa.Array) -> np.ndarray:
+    """uint32[k, 2] view of a 64-bit-wide chunk's own values buffer,
+    its offset honoured.  What lies under a null slot is arbitrary."""
+    return np.frombuffer(chunk.buffers()[1], dtype=np.uint32,
+                         count=2 * len(chunk),
+                         offset=8 * chunk.offset).reshape(-1, 2)
+
+
+def write_words(words: np.ndarray, hi: np.ndarray, lo: np.ndarray,
+                flip_sign: bool = False) -> None:
+    """The high and low words of `words` (uint32[k, 2], as `words64`
+    gives them) into the planes `hi` and `lo` (uint32[k] each): one read
+    and one write a word, no 64-bit temporary.  `flip_sign`: a signed
+    value's order-preserving form (`_ints_to_u64`) is its high word with
+    the sign bit flipped."""
+    if flip_sign:
+        np.bitwise_xor(words[:, _HI], _SIGN32, out=hi)
+    else:
+        hi[:] = words[:, _HI]
+    lo[:] = words[:, _LO]
+
+
+def write_int64_column(col, hi: np.ndarray, lo: np.ndarray,
+                       flip_sign: bool = False) -> None:
+    """A null-free int64 column's words into two planes, chunk by chunk
+    from the chunks' own buffers (no `combine_chunks`)."""
+    off = 0
+    for chunk in _chunks(col):
+        k = len(chunk)
+        if k:
+            write_words(_value_words(chunk), hi[off:off + k],
+                        lo[off:off + k], flip_sign)
+        off += k
 
 
 class LazyPackedLanes:
@@ -130,6 +200,13 @@ class NormalizedKeyEncoder:
         return sum(self.lanes_per_col)
 
     @property
+    def fixed_width(self) -> bool:
+        """Every key column is an integer, temporal, boolean or float:
+        no key is ever cut to a prefix (`truncated` is all false by
+        construction) and `encode_planes` can write the lanes."""
+        return all(k in ("int", "float") for k in self._kinds)
+
+    @property
     def packs_single_key(self) -> bool:
         """True when this encoder's keys pack into ONE u64 (single
         non-null fixed-width column — the hot pk shape): encode_*_ex
@@ -164,10 +241,7 @@ class NormalizedKeyEncoder:
             if arr.null_count:
                 raise ValueError(
                     "null value in a key column declared NOT NULL")
-            if self._kinds[0] == "int":
-                u = _ints_to_u64(np.asarray(arr.cast(pa.int64())))
-            else:
-                u = _floats_to_u64(np.asarray(arr.cast(pa.float64())))
+            u = _fixed_u64(arr, self._kinds[0])
             return LazyPackedLanes(u), np.zeros(n, dtype=bool), u
         lanes = np.zeros((n, self.num_lanes), dtype=np.uint32)
         truncated = np.zeros(n, dtype=bool)
@@ -196,25 +270,8 @@ class NormalizedKeyEncoder:
                     raise ValueError(
                         "null value in a key column declared NOT NULL")
                 nl = total_nl
-            if kind == "int":
-                cast = arr.cast(pa.int64())
-                # fill_null is a full copy at millions of rows: skip it
-                # for null-free columns (the common pk case)
-                if cast.null_count:
-                    cast = cast.fill_null(0)
-                vals = np.asarray(cast)
-                u = _ints_to_u64(vals)
-                if want_packed:
-                    packed = u
-                hi, lo = _split_u64(u)
-                lanes[:, lane_pos] = hi
-                lanes[:, lane_pos + 1] = lo
-            elif kind == "float":
-                cast = arr.cast(pa.float64())
-                if cast.null_count:
-                    cast = cast.fill_null(0)
-                vals = np.asarray(cast)
-                u = _floats_to_u64(vals)
+            if kind in ("int", "float"):
+                u = _fixed_u64(arr, kind)
                 if want_packed:
                     packed = u
                 hi, lo = _split_u64(u)
@@ -241,6 +298,52 @@ class NormalizedKeyEncoder:
                 lanes[null_mask, lane_pos:lane_pos + nl] = np.uint32(0)
             lane_pos += nl
         return lanes, truncated, packed
+
+    def encode_planes(self, columns: Sequence[pa.ChunkedArray],
+                      planes: Sequence[np.ndarray]) -> None:
+        """The lanes of `encode_columns`, transposed and written once:
+        lane i of row r into `planes[i][r]` (num_lanes arrays of
+        uint32[>= N], zero on entry), chunk by chunk from the chunks'
+        own buffers.
+        `fixed_width` encoders only.  A 64-bit integer or temporal
+        chunk goes word by word from its values buffer; any other gets
+        a chunk-sized temporary of its order-preserving uint64."""
+        assert self.fixed_width and len(columns) == len(self.key_types)
+        lane = 0
+        for col, kind, nul in zip(columns, self._kinds, self.nullable):
+            presence = None
+            if nul:
+                presence = planes[lane]
+                lane += 1
+            hi, lo = planes[lane], planes[lane + 1]
+            lane += 2
+            off = 0
+            for chunk in _chunks(col):
+                k = len(chunk)
+                if k == 0:
+                    continue
+                end = off + k
+                has_nulls = bool(chunk.null_count)
+                if has_nulls and not nul:
+                    raise ValueError(
+                        "null value in a key column declared NOT NULL")
+                ct = chunk.type
+                if kind == "int" and (
+                        pa.types.is_int64(ct) or pa.types.is_timestamp(ct)
+                        or pa.types.is_date64(ct) or pa.types.is_time64(ct)):
+                    write_words(_value_words(chunk), hi[off:end],
+                                lo[off:end], flip_sign=True)
+                else:
+                    write_words(words64(_fixed_u64(chunk, kind)),
+                                hi[off:end], lo[off:end])
+                if has_nulls:
+                    # as encode_columns_ex: the presence lane alone
+                    # orders a null, its value words are zero
+                    null_mask = np.asarray(chunk.is_null())
+                    presence[off:end] = null_mask
+                    hi[off:end][null_mask] = 0
+                    lo[off:end][null_mask] = 0
+                off = end
 
     def _encode_bytes(self, arr: pa.Array, lanes: np.ndarray, lane_pos: int,
                       nl: int) -> np.ndarray:

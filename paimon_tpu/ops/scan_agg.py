@@ -666,9 +666,9 @@ def split_partials(runs: Sequence[pa.Table], key_names: Sequence[str],
     # repair; else the router's terms: a raw-convertible split has no
     # sort to weigh, so it is routed as the merge of its rows would be
     to_host = not proved or groups > MAX_DEVICE_GROUPS or n == 0 \
-        or (merge and bool(op.truncated.any())) \
+        or (merge and op.any_truncated) \
         or M.route_to_host(
-            n, key_encoder.num_lanes, op.order_lanes if merge else None,
+            n, key_encoder.num_lanes, op.num_order_lanes if merge else 0,
             True, _epilogue_bytes_per_row(lanes, live) * M._pad_size(n),
             d2h)
     if to_host:
@@ -699,7 +699,8 @@ def _host_winners(op: M.MergeOperands, key_names) -> np.ndarray:
     """Row indices of each key's winner by the merge's host route, as
     `merge_runs` takes them: keys that share a truncated prefix are told
     apart by the full key."""
-    truncated = bool(op.truncated.any())
+    op.encode_host()
+    truncated = op.any_truncated
     perm, winner, _ = M.host_sorted_winners(
         op.lanes, op.seq, op.keep, op.order_lanes, not truncated,
         op.packed, op.run_starts)
@@ -721,10 +722,9 @@ def _device_numbers(op: Optional[M.MergeOperands], lanes: _Lanes, live,
         m, up = M._pad_size(n), 0
     else:
         M.PATH_COUNTS["device"] += 1
-        _, lanes_p, seq_hi, seq_lo, invalid = M._padded_operands(
-            op.lanes, op.order_lanes, op.seq)
-        m, num_lanes = lanes_p.shape
-        up = 4 * m * (num_lanes + 3)
+        planes = op.device_planes()
+        num_lanes, m = len(planes) - 3, len(planes[0])
+        up = 4 * m * len(planes)
     with M.prep_span(n):
         if op is None and live is None:
             live = np.ones(n, dtype=bool)       # the pad's rows are not
@@ -734,11 +734,10 @@ def _device_numbers(op: Optional[M.MergeOperands], lanes: _Lanes, live,
         if op is None:
             sel, live = live, None
         else:
-            sort = M._merge_fn_packed(num_lanes, op.keep, op.lanes.shape[1])
-            sel = scan_agg_winners(sort(
-                tuple(jnp.asarray(lanes_p[:, i]) for i in range(num_lanes)),
-                jnp.asarray(seq_hi), jnp.asarray(seq_lo),
-                jnp.asarray(invalid)))
+            sort = M._merge_fn_packed(num_lanes, op.keep, op.num_key_lanes)
+            operands = [jnp.asarray(plane) for plane in planes]
+            sel = scan_agg_winners(sort(tuple(operands[:num_lanes]),
+                                        *operands[num_lanes:]))
         out = np.asarray(fn(*_upload((sel, live, code, words, valids))))
     return out.tolist()
 

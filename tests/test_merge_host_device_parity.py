@@ -185,3 +185,44 @@ def test_padding_never_joins_segments(monkeypatch):
     perm, winner = np.asarray(perm), np.asarray(winner, bool)
     win = perm[winner & (perm < 5)]
     assert win.tolist() == [4]       # one segment, max-seq row
+
+
+@pytest.mark.parametrize("key", ["int64", "string"])
+@pytest.mark.parametrize("pin", ["PAIMON_FORCE_DEVICE_SORT",
+                                 "PAIMON_FORCE_HOST_SORT", None])
+def test_one_route_decision_a_merge(pin, key, monkeypatch):
+    """`merge_runs` decides its route ahead of the encode for a
+    fixed-width key and after it for a key that can be cut: either way
+    one decision, one ROUTE_LOG entry and one PATH_COUNTS tick a merge,
+    with the inputs the router always logged."""
+    import pyarrow as pa
+    from paimon_tpu.ops import merge as M
+    from paimon_tpu.ops.normkey import NormalizedKeyEncoder
+
+    rng = np.random.default_rng(13)
+    runs = []
+    for r in range(3):
+        k = np.sort(rng.integers(0, 500, 800))
+        runs.append(pa.table({
+            "k": pa.array(k) if key == "int64"
+            else pa.array(["%04d" % v for v in k]),
+            M.SEQ_COL: pa.array(np.arange(800 * r, 800 * (r + 1))),
+            M.KIND_COL: pa.array(np.zeros(800, np.int8))}))
+    if pin:
+        monkeypatch.setenv(pin, "1")
+    del M.ROUTE_LOG[:]
+    counts = dict(M.PATH_COUNTS)
+    res = M.merge_runs(runs, ["k"], key_encoder=NormalizedKeyEncoder(
+        [runs[0].schema.field("k").type], nullable=[False]))
+    ticks = {p: M.PATH_COUNTS[p] - counts[p] for p in counts}
+    to_device = pin == "PAIMON_FORCE_DEVICE_SORT"
+    assert M.ROUTE_LOG == [{
+        "rows": 2400, "lanes": 2 if key == "int64" else 4,
+        "winners_only": True, "host_fast": key == "int64",
+        "pinned": pin is not None,
+        "route": "device" if to_device else "host"}]
+    assert sum(ticks.values()) == 1
+    assert ticks["device"] == int(to_device)
+    assert len(res.indices) == len(np.unique(
+        np.concatenate([r.column("k").to_numpy(zero_copy_only=False)
+                        for r in runs])))
