@@ -113,6 +113,13 @@ def _get_busy_timer():
 _BUSY_TIMER = _get_busy_timer()
 
 
+def live_span(rows: int):
+    """`compact.live`: what a merge's result loses before it is written —
+    the rows whose surviving kind is a retract (a filter that copies
+    every column of the merged state) and the record-level expiry."""
+    return span("compact.live", cat="compaction", rows=rows)
+
+
 class MergeTreeCompactManager:
     def __init__(self, file_io: FileIO, table_path: str,
                  schema: TableSchema, options: CoreOptions,
@@ -552,8 +559,11 @@ class MergeTreeCompactManager:
                     if f.file_name not in self._file_cache]
         if len(uncached) > 1:
             from concurrent.futures import ThreadPoolExecutor
-            with ThreadPoolExecutor(
-                    max_workers=min(8, len(uncached))) as pool:
+            # the task's thread only waits here: who waited, not what
+            # ran (the reads are `io.read` / `decode` on the pool)
+            with span("wait", cat="wait", what="compaction read"), \
+                    ThreadPoolExecutor(
+                        max_workers=min(8, len(uncached))) as pool:
                 list(pool.map(carry(self._read_file), uncached))
         runs = []
         for run_files in runs_meta:
@@ -598,15 +608,18 @@ class MergeTreeCompactManager:
                 seq_fields=seq_fields,
                 seq_desc=self.options.sequence_field_descending,
                 encoded=encoded)
-            return self._record_level_expire(res.take())
+            merged = res.take()
+            with live_span(merged.num_rows):
+                return self._record_level_expire(merged)
         from paimon_tpu.ops.agg import merge_runs_agg
         merged = merge_runs_agg(run_tables, self.key_cols, self.schema,
                                 self.options,
                                 key_encoder=self.key_encoder,
                                 seq_fields=seq_fields)
-        if drop_deletes:
-            merged = self._live_view(merged)
-        return self._record_level_expire(merged)
+        with live_span(merged.num_rows):
+            if drop_deletes:
+                merged = self._live_view(merged)
+            return self._record_level_expire(merged)
 
     def _merged_state(self, files: List[DataFileMeta],
                       drop_deletes: bool = True) -> Optional[pa.Table]:

@@ -17,6 +17,8 @@ from paimon_tpu.format import get_format
 from paimon_tpu.format.format import extract_simple_stats
 from paimon_tpu.fs import FileIO
 from paimon_tpu.manifest import DataFileMeta, FileSource, SimpleStats
+from paimon_tpu.metrics import IO_STATS_MS
+from paimon_tpu.obs.trace import span
 from paimon_tpu.options import CoreOptions
 from paimon_tpu.ops.merge import KIND_COL, SEQ_COL
 from paimon_tpu.schema.table_schema import TableSchema
@@ -108,39 +110,44 @@ class KeyValueFileWriter:
                                  self.format_options).write(
             self.file_io, path, chunk)
 
-        # key stats + min/max key (first/last row: chunk is key-sorted)
-        kmins, kmaxs, knulls = extract_simple_stats(chunk, self.key_cols)
-        key_stats = SimpleStats.from_values(
-            [t.copy(False) for t in self.key_types], kmins, kmaxs, knulls)
-        first = [chunk.column(c)[0].as_py() for c in self.key_cols]
-        last = [chunk.column(c)[-1].as_py() for c in self.key_cols]
-
         value_cols = [f.name for f in self.schema.fields]
-        value_types = [f.type for f in self.schema.fields]
-        stats_mode = self.stats_mode_per_level.get(level)
-        if stats_mode == "none":
-            # metadata.stats-mode.per.level 'N:none': skip stats work
-            # for short-lived files (planning treats absent stats as
-            # unknown and never prunes on them)
-            nil = [None] * len(value_cols)
-            value_stats = _safe_stats(value_types, nil, nil,
-                                      [None] * len(value_cols))
-        else:
-            vmins, vmaxs, vnulls = extract_simple_stats(chunk, value_cols)
-            if self.stats_keep_first_n is not None:
-                # metadata.stats-keep-first-n-columns: null out the rest
-                k = self.stats_keep_first_n
-                vmins = list(vmins[:k]) + [None] * (len(value_cols) - k)
-                vmaxs = list(vmaxs[:k]) + [None] * (len(value_cols) - k)
-            value_stats = _safe_stats(value_types, vmins, vmaxs, vnulls)
+        # `file.stats`: the rolled file's statistics, a leaf after the
+        # writer's `encode` / `io.upload`
+        with span("file.stats", cat="io", group="io", metric=IO_STATS_MS,
+                  columns=len(self.key_cols) + len(value_cols),
+                  rows=chunk.num_rows):
+            # key stats + min/max key (first/last row: chunk is key-sorted)
+            kmins, kmaxs, knulls = extract_simple_stats(chunk, self.key_cols)
+            key_stats = SimpleStats.from_values(
+                [t.copy(False) for t in self.key_types], kmins, kmaxs, knulls)
+            first = [chunk.column(c)[0].as_py() for c in self.key_cols]
+            last = [chunk.column(c)[-1].as_py() for c in self.key_cols]
 
-        seq = chunk.column(SEQ_COL)
-        import pyarrow.compute as pc
-        seq_min = pc.min(seq).as_py()
-        seq_max = pc.max(seq).as_py()
-        kinds = np.asarray(chunk.column(KIND_COL).combine_chunks()
-                           .cast(pa.int8()))
-        delete_rows = int(((kinds == 1) | (kinds == 3)).sum())
+            value_types = [f.type for f in self.schema.fields]
+            stats_mode = self.stats_mode_per_level.get(level)
+            if stats_mode == "none":
+                # metadata.stats-mode.per.level 'N:none': skip stats work
+                # for short-lived files (planning treats absent stats as
+                # unknown and never prunes on them)
+                nil = [None] * len(value_cols)
+                value_stats = _safe_stats(value_types, nil, nil,
+                                          [None] * len(value_cols))
+            else:
+                vmins, vmaxs, vnulls = extract_simple_stats(chunk, value_cols)
+                if self.stats_keep_first_n is not None:
+                    # metadata.stats-keep-first-n-columns: null out the rest
+                    k = self.stats_keep_first_n
+                    vmins = list(vmins[:k]) + [None] * (len(value_cols) - k)
+                    vmaxs = list(vmaxs[:k]) + [None] * (len(value_cols) - k)
+                value_stats = _safe_stats(value_types, vmins, vmaxs, vnulls)
+
+            seq = chunk.column(SEQ_COL)
+            import pyarrow.compute as pc
+            seq_min = pc.min(seq).as_py()
+            seq_max = pc.max(seq).as_py()
+            kinds = np.asarray(chunk.column(KIND_COL).combine_chunks()
+                               .cast(pa.int8()))
+            delete_rows = int(((kinds == 1) | (kinds == 3)).sum())
 
         embedded_index, extra_files = None, []
         if self.index_spec:

@@ -114,20 +114,34 @@ def group_by_partition_bucket(table: pa.Table, buckets: np.ndarray,
 def build_kv_table(raw: pa.Table, schema: TableSchema,
                    seq: np.ndarray, kinds: np.ndarray) -> pa.Table:
     """Flatten rows into the KV file layout:
-    _KEY_<pk...>, _SEQUENCE_NUMBER, _VALUE_KIND, <all value columns>."""
-    cols = []
-    names = []
-    for k in schema.trimmed_primary_keys():
-        cols.append(raw.column(k))
-        names.append(KEY_PREFIX + k)
-    cols.append(pa.array(seq, pa.int64()))
-    names.append(SEQ_COL)
-    cols.append(pa.array(kinds, pa.int8()))
-    names.append(KIND_COL)
-    for f in schema.fields:
-        cols.append(raw.column(f.name))
-        names.append(f.name)
-    return pa.table(dict(zip(names, cols)))
+    _KEY_<pk...>, _SEQUENCE_NUMBER, _VALUE_KIND, <all value columns>.
+    A leaf span, `write.build`: callers open none around it alone."""
+    from paimon_tpu.metrics import WRITE_BUILD_MS
+    from paimon_tpu.obs.trace import span
+    with span("write.build", cat="write", group="write",
+              metric=WRITE_BUILD_MS, rows=raw.num_rows):
+        cols = []
+        names = []
+        for k in schema.trimmed_primary_keys():
+            cols.append(raw.column(k))
+            names.append(KEY_PREFIX + k)
+        cols.append(pa.array(seq, pa.int64()))
+        names.append(SEQ_COL)
+        cols.append(pa.array(kinds, pa.int8()))
+        names.append(KIND_COL)
+        for f in schema.fields:
+            cols.append(raw.column(f.name))
+            names.append(f.name)
+        return pa.table(dict(zip(names, cols)))
+
+
+def _buffer_span(rows: int):
+    """`write.buffer`: the caller thread's own work on a bucket's
+    buffer — a batch appended with its reserved sequence numbers, the
+    buffer detached as one flush payload (the kinds and the sequence
+    numbers concatenated)."""
+    from paimon_tpu.obs.trace import span
+    return span("write.buffer", cat="write", rows=rows)
 
 
 class _BucketWriter:
@@ -175,12 +189,13 @@ class _BucketWriter:
         return self.buffered_bytes + self._spill_bytes
 
     def write(self, table: pa.Table, kinds: np.ndarray):
-        self.buffers.append(table)
-        self.kind_buffers.append(kinds)
-        # sequence numbers are reserved HERE, on the single-threaded
-        # caller, never inside a pooled flush task
-        seqs = self._assign_seq(table.num_rows)
-        self.seq_buffers.append(seqs)
+        with _buffer_span(table.num_rows):
+            self.buffers.append(table)
+            self.kind_buffers.append(kinds)
+            # sequence numbers are reserved HERE, on the single-threaded
+            # caller, never inside a pooled flush task
+            seqs = self._assign_seq(table.num_rows)
+            self.seq_buffers.append(seqs)
         if self.parent.delta_listener is not None:
             # serving-plane hot delta tier (service/delta.py): the
             # batch becomes point-lookup-visible the moment it is
@@ -238,11 +253,12 @@ class _BucketWriter:
         sort/encode happens in the pooled task that receives it."""
         if not self.buffers:
             return None
-        raw = pa.concat_tables(self.buffers, promote_options="none")
-        kinds = np.concatenate(self.kind_buffers)
-        seq = np.concatenate(self.seq_buffers)
-        self.buffers, self.kind_buffers, self.seq_buffers = [], [], []
-        self.buffered_bytes = 0
+        with _buffer_span(sum(len(k) for k in self.kind_buffers)):
+            raw = pa.concat_tables(self.buffers, promote_options="none")
+            kinds = np.concatenate(self.kind_buffers)
+            seq = np.concatenate(self.seq_buffers)
+            self.buffers, self.kind_buffers, self.seq_buffers = [], [], []
+            self.buffered_bytes = 0
         return raw, kinds, seq
 
     def _sorted_chunk(self, snap) -> Tuple[Optional[pa.Table],
@@ -826,7 +842,8 @@ class KeyValueFileStoreWrite:
         def prep(table=table, kinds=row_kinds.copy(),
                  pre=precomputed_buckets):
             from paimon_tpu.metrics import (
-                WRITE_ROUTE_MS, WRITE_ROUTE_NOCOPY_ROWS, global_registry,
+                WRITE_ROUTE_MS, WRITE_ROUTE_NOCOPY_ROWS, WRITE_ROUTE_ROWS,
+                global_registry,
             )
             from paimon_tpu.obs.trace import metrics_enabled, span
             with span("write.route", cat="write", group="write",
@@ -845,9 +862,10 @@ class KeyValueFileStoreWrite:
                     copied = table.num_rows
                 sp.set(groups=len(groups), copied_rows=copied)
                 if metrics_enabled():
-                    global_registry().write_metrics().counter(
-                        WRITE_ROUTE_NOCOPY_ROWS).inc(
-                            table.num_rows - copied)
+                    group = global_registry().write_metrics()
+                    group.counter(WRITE_ROUTE_ROWS).inc(table.num_rows)
+                    group.counter(WRITE_ROUTE_NOCOPY_ROWS).inc(
+                        table.num_rows - copied)
                 return out
 
         pool = self._prep_executor()

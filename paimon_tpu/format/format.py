@@ -92,12 +92,15 @@ class _ParquetReader(FormatReader):
                   path=path) as sp:
             data = file_io.read_bytes(path)  # store faults propagate
             sp.set(bytes=len(data))
-        cache = global_footer_cache()
-        md = cache.get(path)
-        with _decode_errors(path):
-            pf = pq.ParquetFile(io.BytesIO(data), metadata=md)
-        if md is None:
-            cache.put(path, pf.metadata)
+        # `io.open`: the file's bytes into a seekable buffer (a copy)
+        # and the footer, parsed or taken from the cache
+        with span("io.open", cat="io", path=path, bytes=len(data)):
+            cache = global_footer_cache()
+            md = cache.get(path)
+            with _decode_errors(path):
+                pf = pq.ParquetFile(io.BytesIO(data), metadata=md)
+            if md is None:
+                cache.put(path, pf.metadata)
         return pf
 
     def read(self, file_io, path, projection=None, batch_size=1 << 20):

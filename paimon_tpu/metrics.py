@@ -34,10 +34,14 @@ __all__ = ["Counter", "Gauge", "Histogram", "MetricGroup",
            "COMMIT_DURATION_MS", "COMPACTION_DURATION_MS",
            "COMPACTION_TABLE_MS", "COMPACTION_CONCURRENT_TASKS_PEAK",
            "COMPACTION_MESH_STEPS", "COMPACTION_MESH_PADDED_ROWS",
-           "WRITE_ROUTE_MS", "WRITE_ROUTE_NOCOPY_ROWS",
+           "WRITE_ROUTE_MS", "WRITE_ROUTE_NOCOPY_ROWS", "WRITE_ROUTE_ROWS",
+           "WRITE_BUILD_MS", "IO_STATS_MS",
            "MERGE_PREP_MS", "MERGE_DEVICE_MS", "MERGE_AGG_MS",
            "MERGE_SELECT_MS", "MERGE_GATHER_MS", "MERGE_GATHER_BYTES",
            "MERGE_RETURN_BYTES", "MERGE_PREP_PLANAR_ROWS", "SCAN_AGG_MS",
+           "MERGE_WINNERS_MS", "MERGE_HOST_MS", "MERGE_MASK_MS",
+           "MERGE_DEVICE_TRIPS", "MERGE_DEVICE_INFLIGHT_SUM",
+           "MERGE_DEVICE_ROWS",
            "SCAN_AGG_BELOW_ROWS",
            "SCAN_ROWS_IN",
            "STREAM_EVENTS_INGESTED", "STREAM_CHECKPOINTS",
@@ -140,6 +144,8 @@ IO_READ_MS = "read_ms"                      # io: store -> bytes
 IO_DECODE_MS = "decode_ms"                  # io: bytes -> Arrow
 IO_ENCODE_MS = "encode_ms"                  # io: Arrow -> bytes
 IO_UPLOAD_MS = "upload_ms"                  # io: bytes -> store
+IO_STATS_MS = "stats_ms"                    # io: a rolled file's column
+                                            # statistics, first / last key
 COMPACTION_WINDOW_MS = "window_ms"          # compaction: device window
 COMPACTION_FALLBACK_MS = "fallback_ms"      # compaction: 1-chip rescue
 COMMIT_CAS_MS = "cas_ms"                    # commit: one CAS publish
@@ -162,6 +168,8 @@ WRITE_ROUTE_MS = "route_ms"                 # write: bucket hash, group-by,
 WRITE_ROUTE_NOCOPY_ROWS = "route_nocopy_rows"   # counter: rows of batches
                                             # that were one group, handed on
                                             # without a take
+WRITE_ROUTE_ROWS = "route_rows"             # counter: rows the route handled
+WRITE_BUILD_MS = "build_ms"                 # write: a flush's KV-shaped table
 # merge metric group: the stages of one sorted-run merge, whoever
 # called it (scan split, flush sort, compaction window) — producers
 # in ops/merge.py, ops/agg.py and compact/manager.py
@@ -175,6 +183,17 @@ MERGE_RETURN_BYTES = "return_bytes"         # counter: bytes a merge handed back
 MERGE_PREP_PLANAR_ROWS = "prep_planar_rows"  # counter: rows whose device
                                             # operands went straight from
                                             # the Arrow chunks to planes
+MERGE_WINNERS_MS = "winners_ms"             # (perm, winner) words -> row
+                                            # indices, on every route
+MERGE_HOST_MS = "host_ms"                   # a merge sorted on the host
+MERGE_MASK_MS = "mask_ms"                   # aggregation epilogue's own numpy
+MERGE_DEVICE_TRIPS = "device_trips"         # counter: round trips to the chip
+MERGE_DEVICE_INFLIGHT_SUM = "device_inflight_sum"   # counter: round trips
+                                            # open as each one opened, itself
+                                            # included; / device_trips = how
+                                            # many shared the link
+MERGE_DEVICE_ROWS = "device_rows"           # counter: real rows of the merge
+                                            # round trips (`merge.device`)
 
 # streaming-daemon counter/gauge/histogram names (stream metric group;
 # producer is service/stream_daemon.py, consumers tests/soak_harness.py

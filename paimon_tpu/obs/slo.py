@@ -78,12 +78,12 @@ class SloConfig:
 class SloEvaluator:
     """Per-replica burn-rate evaluator fed one (status, duration)
     pair per served request.  `clock` is injectable so storm tests can
-    march time instead of sleeping."""
+    march time instead of sleeping (None: `time.monotonic`)."""
 
     def __init__(self, config: Optional[SloConfig] = None,
-                 table: str = "", clock=time.monotonic):
+                 table: str = "", clock=None):
         self.config = config or SloConfig()
-        self._clock = clock
+        self._clock = clock or time.monotonic
         self._lock = threading.Lock()
         # (t, ok, over_latency) per request, oldest first
         self._events: deque = deque(maxlen=MAX_EVENTS)

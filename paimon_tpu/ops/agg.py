@@ -28,12 +28,12 @@ import numpy as np
 import pyarrow as pa
 import pyarrow.compute as pc
 
-from paimon_tpu.metrics import MERGE_AGG_MS, MERGE_SELECT_MS
+from paimon_tpu.metrics import MERGE_AGG_MS, MERGE_MASK_MS, MERGE_SELECT_MS
 from paimon_tpu.obs.trace import span
 from paimon_tpu.options import CoreOptions, MergeEngine
 from paimon_tpu.ops.merge import (
-    KIND_COL, SEQ_COL, device_sorted_winners, gather, gather_values,
-    prep_span,
+    KIND_COL, SEQ_COL, device_sorted_winners, device_trip, gather,
+    gather_values, prep_span, winners_span,
 )
 from paimon_tpu.ops.normkey import NormalizedKeyEncoder
 from paimon_tpu.schema.table_schema import TableSchema
@@ -98,49 +98,50 @@ def _segment_ids_from_sort(lanes: np.ndarray, seq: np.ndarray,
     perm, winner, _ = device_sorted_winners(
         lanes, seq, "last", order_lanes, packed=packed,
         run_starts=run_starts if order_lanes is None else None)
-    real = perm < n
-    order = perm[real].astype(np.int64)
-    win_sorted = winner[real]
-    seg_end = win_sorted.copy()
-    if len(seg_end):
-        seg_end[-1] = True
-    seg_id = np.concatenate([[0], np.cumsum(seg_end[:-1])]) \
-        if len(seg_end) else np.zeros(0, np.int64)
-    seg_id = seg_id.astype(np.int64)
+    with winners_span(n, "agg"):
+        real = perm < n
+        order = perm[real].astype(np.int64)
+        win_sorted = winner[real]
+        seg_end = win_sorted.copy()
+        if len(seg_end):
+            seg_end[-1] = True
+        seg_id = np.concatenate([[0], np.cumsum(seg_end[:-1])]) \
+            if len(seg_end) else np.zeros(0, np.int64)
+        seg_id = seg_id.astype(np.int64)
 
-    if truncated is not None and truncated.any() and full_key is not None:
-        aff_ids = np.unique(seg_id[truncated[order]])
-        m = len(order)
-        if len(aff_ids) and m:
-            # seg_id is sorted, so each affected segment is one contiguous
-            # span located in O(log n); only those spans pay host work
-            starts = np.searchsorted(seg_id, aff_ids, side="left")
-            ends = np.searchsorted(seg_id, aff_ids, side="right")
-            new_order = order.copy()
-            boundaries = np.empty(m, dtype=bool)   # True = segment start
-            boundaries[0] = True
-            boundaries[1:] = seg_id[1:] != seg_id[:-1]
-            for s, e in zip(starts, ends):
-                span = order[s:e].tolist()
-                fk = {r: full_key(r) for r in span}
-                # within a key: user sequence first (when present), then
-                # internal sequence — same order the device sort used
-                resorted = sorted(
-                    span,
-                    key=lambda r: (fk[r],
-                                   tuple(order_lanes[r])
-                                   if order_lanes is not None else (),
-                                   int(seq[r])))
-                new_order[s:e] = resorted
-                prev_key = None
-                for k, r in enumerate(resorted):
-                    boundaries[s + k] = (fk[r] != prev_key)
-                    prev_key = fk[r]
-            order = new_order
-            seg_id = np.cumsum(boundaries) - 1
-            win_sorted = np.empty(m, dtype=bool)
-            win_sorted[:-1] = seg_id[:-1] != seg_id[1:]
-            win_sorted[-1] = True
+        if truncated is not None and truncated.any() and full_key is not None:
+            aff_ids = np.unique(seg_id[truncated[order]])
+            m = len(order)
+            if len(aff_ids) and m:
+                # seg_id is sorted, so each affected segment is one contiguous
+                # span located in O(log n); only those spans pay host work
+                starts = np.searchsorted(seg_id, aff_ids, side="left")
+                ends = np.searchsorted(seg_id, aff_ids, side="right")
+                new_order = order.copy()
+                boundaries = np.empty(m, dtype=bool)   # True = segment start
+                boundaries[0] = True
+                boundaries[1:] = seg_id[1:] != seg_id[:-1]
+                for s, e in zip(starts, ends):
+                    span = order[s:e].tolist()
+                    fk = {r: full_key(r) for r in span}
+                    # within a key: user sequence first (when present), then
+                    # internal sequence — same order the device sort used
+                    resorted = sorted(
+                        span,
+                        key=lambda r: (fk[r],
+                                       tuple(order_lanes[r])
+                                       if order_lanes is not None else (),
+                                       int(seq[r])))
+                    new_order[s:e] = resorted
+                    prev_key = None
+                    for k, r in enumerate(resorted):
+                        boundaries[s + k] = (fk[r] != prev_key)
+                        prev_key = fk[r]
+                order = new_order
+                seg_id = np.cumsum(boundaries) - 1
+                win_sorted = np.empty(m, dtype=bool)
+                win_sorted[:-1] = seg_id[:-1] != seg_id[1:]
+                win_sorted[-1] = True
     return order, seg_id, win_sorted
 
 
@@ -253,7 +254,7 @@ def _padded_seg(fn_jit, ufunc):
         n = len(vals)
         if vals.dtype == np.float64:
             return _host_segment_reduce(ufunc, vals, seg_ids, num_seg)
-        with span("agg.device", cat="merge", rows=n, segments=num_seg):
+        with device_trip("agg.device", rows=n, segments=num_seg):
             is_end, ends = _segment_ends(seg_ids, num_seg)
             m = 1 << max(10, int(n - 1).bit_length())
             padded = np.zeros(m, dtype=vals.dtype)
@@ -282,17 +283,18 @@ def _last_index_where(mask: np.ndarray, seg_id: np.ndarray,
                       num_seg: int) -> np.ndarray:
     """Per segment, the position (into sorted order) of the last True;
     -1 if none. Vectorized with segment_max over masked positions."""
-    pos = np.arange(len(mask), dtype=np.int64)
-    masked = np.where(mask, pos, -1)
-    out = np.asarray(_seg_max(masked, seg_id, num_seg))
-    return out
+    with _mask_span(len(mask)):
+        pos = np.arange(len(mask), dtype=np.int64)
+        masked = np.where(mask, pos, -1)
+    return np.asarray(_seg_max(masked, seg_id, num_seg))
 
 
 def _first_index_where(mask: np.ndarray, seg_id: np.ndarray,
                        num_seg: int) -> np.ndarray:
     n = len(mask)
-    pos = np.arange(n, dtype=np.int64)
-    masked = np.where(mask, pos, n + 1)
+    with _mask_span(n):
+        pos = np.arange(n, dtype=np.int64)
+        masked = np.where(mask, pos, n + 1)
     out = np.asarray(_seg_min(masked, seg_id, num_seg))
     return np.where(out > n, -1, out)
 
@@ -316,6 +318,18 @@ def _select_span(rows: int, groups: int, columns: int):
     return span("agg.select", cat="merge", group="merge",
                 metric=MERGE_SELECT_MS, rows=rows, groups=groups,
                 columns=columns)
+
+
+def _mask_span(rows: int, column: str = ""):
+    """`agg.mask`: the epilogue's own numpy over a window of `rows` —
+    the winners' positions, the retract mask, a column's validity and
+    contribution mask, its values with nulls filled, the signed or
+    masked operand of its reduction, its result as an Arrow array, the
+    output table's assembly — between the `merge.gather` leaves and the
+    `agg.device` / `agg.host` spans, never around one.  `column`: the
+    column it works for, empty for the window's."""
+    return span("agg.mask", cat="merge", group="merge",
+                metric=MERGE_MASK_MS, rows=rows, column=column)
 
 
 def _masked_numeric(result: np.ndarray, any_valid: np.ndarray,
@@ -399,21 +413,24 @@ def aggregate_sorted_segments(table: pa.Table, order: np.ndarray,
                                           options)
 
 
-def _sorted_validity(column: pa.ChunkedArray,
-                     order: np.ndarray) -> np.ndarray:
+def _sorted_validity(column: pa.ChunkedArray, order: np.ndarray,
+                     name: str) -> np.ndarray:
     """The column's validity in the merge's order, a bool a row.  A
     column without nulls is not read."""
-    if column.null_count == 0:
-        return np.ones(len(order), dtype=bool)
-    return gather_values(np.asarray(pc.is_valid(column)), order)
+    with _mask_span(len(order), name):
+        if column.null_count == 0:
+            return np.ones(len(order), dtype=bool)
+        valid = np.asarray(pc.is_valid(column))
+    return gather_values(valid, order)
 
 
 def _sorted_values(column: pa.ChunkedArray, order: np.ndarray,
-                   fill=0) -> np.ndarray:
+                   name: str, fill=0) -> np.ndarray:
     """A fixed-width column's values in the merge's order, nulls as
     `fill`."""
-    return gather_values(
-        np.asarray(column.combine_chunks().fill_null(fill)), order)
+    with _mask_span(len(order), name):
+        values = np.asarray(column.combine_chunks().fill_null(fill))
+    return gather_values(values, order)
 
 
 def _aggregate_sorted_segments(table, order, seg_id, win_sorted, key_cols,
@@ -426,14 +443,17 @@ def _aggregate_sorted_segments(table, order, seg_id, win_sorted, key_cols,
     validity, a summed column) is gathered by `order` column by column,
     when it is reached; a collection aggregate gathers its one column.
     Every one of these is a `merge.gather`."""
-    num_seg = int(seg_id[-1]) + 1 if len(seg_id) else 0
-    win_pos = np.flatnonzero(win_sorted)           # last row of each segment
-
-    kinds_sorted = gather_values(
-        np.asarray(table.column(KIND_COL).combine_chunks().cast(pa.int8())),
-        order)
-    retract = (kinds_sorted == RowKind.DELETE) | \
-              (kinds_sorted == RowKind.UPDATE_BEFORE)
+    n = len(order)
+    with _mask_span(n):
+        num_seg = int(seg_id[-1]) + 1 if len(seg_id) else 0
+        win_pos = np.flatnonzero(win_sorted)       # last row of each segment
+        kinds = np.asarray(table.column(KIND_COL).combine_chunks()
+                           .cast(pa.int8()))
+    kinds_sorted = gather_values(kinds, order)
+    with _mask_span(n):
+        retract = (kinds_sorted == RowKind.DELETE) | \
+                  (kinds_sorted == RowKind.UPDATE_BEFORE)
+        add_mask = ~retract
 
     aggs = field_aggregators(schema, options)
     remove_on_delete = options.get(
@@ -445,8 +465,10 @@ def _aggregate_sorted_segments(table, order, seg_id, win_sorted, key_cols,
         """`out_cols[name]`, for each of `columns`: the column's value
         at sorted position `idx` of each segment, null where `idx` < 0
         (a null index takes a null)."""
-        missing = idx < 0
-        rows = pa.array(order[idx], mask=missing if missing.any() else None)
+        with _mask_span(n, columns[0]):
+            missing = idx < 0
+            rows = pa.array(order[idx],
+                            mask=missing if missing.any() else None)
         taken = gather(table.select(columns), rows)
         out_cols.update(zip(columns, taken.columns))
 
@@ -458,8 +480,6 @@ def _aggregate_sorted_segments(table, order, seg_id, win_sorted, key_cols,
     names = list(dict.fromkeys(list(key_cols) + [SEQ_COL, KIND_COL]
                                + [f.name for f in schema.fields]))
     take_winners([name for name in names if name not in aggs], win_pos)
-
-    add_mask = ~retract
 
     # sequence groups (partial-update): each group's member columns take
     # their values from the row with the LARGEST group-sequence value
@@ -481,7 +501,7 @@ def _aggregate_sorted_segments(table, order, seg_id, win_sorted, key_cols,
                 group_of[colname] = g
         views = [[_sorted_sequence_field(table, s, order)
                   for s in seq_fields] for seq_fields, _ in groups]
-        with _select_span(len(order), len(groups),
+        with _select_span(n, len(groups),
                           sum(len(cols) for _, cols in groups)):
             group_idx = [_seq_group_winner_index(fields, seg_id, num_seg,
                                                  add_mask)
@@ -500,54 +520,64 @@ def _aggregate_sorted_segments(table, order, seg_id, win_sorted, key_cols,
         func = aggs[name]
         column = table.column(name)
         ctype = column.type
-        valid = _sorted_validity(column, order)
-        contrib_mask = valid & add_mask
+        valid = _sorted_validity(column, order, name)
+        with _mask_span(n, name):
+            contrib_mask = valid & add_mask
         if func in _NUMERIC_DEVICE_AGGS and ctype in _JAX_NUMERIC:
             np_dtype = _JAX_NUMERIC[ctype]
             if func == "count":
-                dev = _seg_sum(contrib_mask.astype(np.int64), seg_id,
-                               num_seg)
-                result = np.asarray(dev)
-                out_cols[name] = pa.array(result, pa.int64())
+                with _mask_span(n, name):
+                    counted = contrib_mask.astype(np.int64)
+                result = np.asarray(_seg_sum(counted, seg_id, num_seg))
+                with _mask_span(n, name):
+                    out_cols[name] = pa.array(result, pa.int64())
                 continue
-            vals = _sorted_values(column, order).astype(np_dtype,
-                                                       copy=False)
+            vals = _sorted_values(column, order, name)
             if func == "sum":
                 ignore_retract = options.options.get_or(
                     f"fields.{name}.ignore-retract", "false") == "true"
-                if ignore_retract:
-                    # reference FieldIgnoreRetractAgg: retracts are
-                    # no-ops instead of subtracting, and do not count
-                    # as a contribution (all-retract segment -> null)
-                    signed = np.where(retract, 0, vals)
-                    contributed = valid & ~retract
-                else:
-                    signed = np.where(retract, -vals, vals)
-                    contributed = valid
-                signed = np.where(valid, signed, 0)
-                dev = _seg_sum(signed, seg_id, num_seg)
-                result = np.asarray(dev)
-                any_valid = np.asarray(_seg_max(
-                    contributed.astype(np.int32), seg_id, num_seg)) > 0
-                out_cols[name] = _masked_numeric(result, any_valid, ctype)
+                with _mask_span(n, name):
+                    vals = vals.astype(np_dtype, copy=False)
+                    if ignore_retract:
+                        # reference FieldIgnoreRetractAgg: retracts are
+                        # no-ops instead of subtracting, and do not count
+                        # as a contribution (all-retract segment -> null)
+                        signed = np.where(retract, 0, vals)
+                        contributed = valid & ~retract
+                    else:
+                        signed = np.where(retract, -vals, vals)
+                        contributed = valid
+                    signed = np.where(valid, signed, 0)
+                    contributed = contributed.astype(np.int32)
+                result = np.asarray(_seg_sum(signed, seg_id, num_seg))
+                any_valid = np.asarray(
+                    _seg_max(contributed, seg_id, num_seg))
+                with _mask_span(n, name):
+                    out_cols[name] = _masked_numeric(result, any_valid > 0,
+                                                     ctype)
                 continue
             if func in ("max", "min", "product"):
-                ident = {"max": _np_min_ident(np_dtype),
-                         "min": _np_max_ident(np_dtype),
-                         "product": np_dtype(1)}[func]
-                masked = np.where(contrib_mask, vals, ident)
+                with _mask_span(n, name):
+                    vals = vals.astype(np_dtype, copy=False)
+                    ident = {"max": _np_min_ident(np_dtype),
+                             "min": _np_max_ident(np_dtype),
+                             "product": np_dtype(1)}[func]
+                    masked = np.where(contrib_mask, vals, ident)
+                    contributed = contrib_mask.astype(np.int32)
                 dev = {"max": _seg_max, "min": _seg_min,
                        "product": _seg_prod}[func](masked, seg_id,
                                                    num_seg)
                 result = np.asarray(dev)
-                any_valid = np.asarray(_seg_max(
-                    contrib_mask.astype(np.int32), seg_id, num_seg)) > 0
-                out_cols[name] = _masked_numeric(result, any_valid, ctype)
+                any_valid = np.asarray(
+                    _seg_max(contributed, seg_id, num_seg))
+                with _mask_span(n, name):
+                    out_cols[name] = _masked_numeric(result, any_valid > 0,
+                                                     ctype)
                 continue
         # order-based aggregates: pick an index per segment, host gather
         if func in _INDEX_SELECTIONS:
             pick, non_null = _INDEX_SELECTIONS[func]
-            with _select_span(len(order), 0, 1):
+            with _select_span(n, 0, 1):
                 idx = pick(contrib_mask if non_null else add_mask,
                            seg_id, num_seg)
             take_winners([name], idx)
@@ -578,30 +608,34 @@ def _aggregate_sorted_segments(table, order, seg_id, win_sorted, key_cols,
                                             contrib_mask, seg_id,
                                             num_seg, options, name, f)
         elif func in ("bool_and", "bool_or"):
-            vals = _sorted_values(column, order, fill=False)
-            if func == "bool_or":
-                masked = vals & contrib_mask
-            else:
-                masked = vals | ~contrib_mask
+            vals = _sorted_values(column, order, name, fill=False)
+            with _mask_span(n, name):
+                if func == "bool_or":
+                    masked = vals & contrib_mask
+                else:
+                    masked = vals | ~contrib_mask
+                masked = masked.astype(np.int32)
             dev = (_seg_max if func == "bool_or" else _seg_min)(
-                masked.astype(np.int32), seg_id, num_seg)
-            out_cols[name] = pa.array(np.asarray(dev).astype(bool),
-                                      pa.bool_())
+                masked, seg_id, num_seg)
+            with _mask_span(n, name):
+                out_cols[name] = pa.array(np.asarray(dev).astype(bool),
+                                          pa.bool_())
         else:
             raise ValueError(f"Unknown aggregate function {func!r} "
                              f"for field {name}")
 
-    out = pa.table({name: out_cols[name] for name in names})
-    # delete handling: drop segments whose winner is a retract
-    winner_kinds = np.asarray(out.column(KIND_COL).combine_chunks()
-                              .cast(pa.int8()))
-    if options.merge_engine == MergeEngine.PARTIAL_UPDATE \
-            and not remove_on_delete:
-        return out  # deletes ignored (retracts folded per column)
-    drop = (winner_kinds == RowKind.DELETE)
-    if drop.any():
-        out = out.filter(pa.array(~drop))
-    return out
+    with _mask_span(n):
+        out = pa.table({name: out_cols[name] for name in names})
+        if options.merge_engine == MergeEngine.PARTIAL_UPDATE \
+                and not remove_on_delete:
+            return out  # deletes ignored (retracts folded per column)
+        # delete handling: drop segments whose winner is a retract
+        winner_kinds = np.asarray(out.column(KIND_COL).combine_chunks()
+                                  .cast(pa.int8()))
+        drop = (winner_kinds == RowKind.DELETE)
+        if drop.any():
+            out = out.filter(pa.array(~drop))
+        return out
 
 
 def _sequence_values(fname: str, arr: pa.Array) -> np.ndarray:
@@ -629,9 +663,10 @@ def _sorted_sequence_field(table: pa.Table, fname: str, order: np.ndarray):
     """(values, validity) of one sequence field in the merge's order,
     as `_seq_group_winner_index` reads them."""
     column = table.column(fname)
-    return (gather_values(_sequence_values(fname, column.combine_chunks()),
-                          order),
-            _sorted_validity(column, order))
+    with _mask_span(len(order), fname):
+        values = _sequence_values(fname, column.combine_chunks())
+    return (gather_values(values, order),
+            _sorted_validity(column, order, fname))
 
 
 def _seq_group_winner_index(fields, seg_id: np.ndarray, num_seg: int,
@@ -648,27 +683,28 @@ def _seq_group_winner_index(fields, seg_id: np.ndarray, num_seg: int,
     the field among the rows still in the running, which then keeps the
     rows that equal it.  Nothing is sorted or ranked; each field is
     compared on its native values."""
-    running = add_mask.copy()
-    for _, valid in fields:
-        running &= valid
+    n = len(add_mask)
+    with _mask_span(n):
+        running = add_mask.copy()
+        for _, valid in fields:
+            running &= valid
     for vals, _ in fields:
-        if vals.dtype == object:
-            # unscaled decimals are Python integers, wider than any
-            # word the device holds: their maxima stay on the host
-            best = _host_segment_reduce(
-                np.maximum, np.where(running, vals, vals.min() - 1),
-                seg_id, num_seg)
-        else:
-            best = _seg_max(
-                np.where(running, vals, _np_min_ident(vals.dtype.type)),
-                seg_id, num_seg)
-        best = best[seg_id]
-        at_best = vals == best
-        if vals.dtype.kind == "f":
-            # a NaN is the largest value and equals itself, as a sort
-            # would have it; `maximum` propagates it into `best`
-            at_best |= np.isnan(vals) & np.isnan(best)
-        running &= at_best
+        # unscaled decimals (dtype object) are Python integers, wider
+        # than any word the device holds: their maxima stay on the host
+        on_host = vals.dtype == object
+        with _mask_span(n):
+            masked = np.where(running, vals, vals.min() - 1 if on_host
+                              else _np_min_ident(vals.dtype.type))
+        best = _host_segment_reduce(np.maximum, masked, seg_id, num_seg) \
+            if on_host else _seg_max(masked, seg_id, num_seg)
+        with _mask_span(n):
+            best = best[seg_id]
+            at_best = vals == best
+            if vals.dtype.kind == "f":
+                # a NaN is the largest value and equals itself, as a sort
+                # would have it; `maximum` propagates it into `best`
+                at_best |= np.isnan(vals) & np.isnan(best)
+            running &= at_best
     return _last_index_where(running, seg_id, num_seg)
 
 

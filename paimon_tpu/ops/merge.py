@@ -43,6 +43,7 @@ from __future__ import annotations
 
 import os
 import threading
+from contextlib import contextmanager
 from dataclasses import dataclass
 from functools import lru_cache
 from typing import List, Optional, Sequence, Tuple
@@ -53,8 +54,10 @@ import numpy as np
 import pyarrow as pa
 
 from paimon_tpu.metrics import (
-    MERGE_DEVICE_MS, MERGE_GATHER_BYTES, MERGE_GATHER_MS, MERGE_PREP_MS,
-    MERGE_PREP_PLANAR_ROWS, MERGE_RETURN_BYTES, global_registry,
+    MERGE_DEVICE_INFLIGHT_SUM, MERGE_DEVICE_MS, MERGE_DEVICE_ROWS,
+    MERGE_DEVICE_TRIPS, MERGE_GATHER_BYTES, MERGE_GATHER_MS, MERGE_HOST_MS,
+    MERGE_PREP_MS, MERGE_PREP_PLANAR_ROWS, MERGE_RETURN_BYTES,
+    MERGE_WINNERS_MS, global_registry,
 )
 from paimon_tpu.obs.trace import metrics_enabled, span
 from paimon_tpu.ops.normkey import (
@@ -185,22 +188,71 @@ def _padded_operands(lanes, order_lanes: Optional[np.ndarray],
     return planes
 
 
+# round trips to the chip open now, process-wide: every `merge.device`
+# and `agg.device` span, whichever thread opened it
+_TRIPS_OPEN = 0
+_TRIPS_LOCK = threading.Lock()
+
+
+@contextmanager
+def device_trip(name: str, merge_rows: Optional[int] = None, **span_args):
+    """One round trip to the chip as the span `name`, counted among
+    those open: `merge` / `device_trips` takes one and `device_inflight_sum`
+    the number open as this one opens, itself included, which the span
+    carries as `inflight`; their ratio is how many round trips shared the
+    chip and its link.  `merge_rows`: the real rows of a merge's round
+    trip, for `merge` / `device_rows`.  The count is taken before the
+    span opens and given back after it closes, whatever it raised: the
+    span times the calls as they are, and nothing waits for the device."""
+    global _TRIPS_OPEN
+    with _TRIPS_LOCK:
+        _TRIPS_OPEN += 1
+        inflight = _TRIPS_OPEN
+    try:
+        if metrics_enabled():
+            group = global_registry().group("merge")
+            group.counter(MERGE_DEVICE_TRIPS).inc()
+            group.counter(MERGE_DEVICE_INFLIGHT_SUM).inc(inflight)
+            if merge_rows is not None:
+                group.counter(MERGE_DEVICE_ROWS).inc(merge_rows)
+        with span(name, cat="merge", inflight=inflight, **span_args) as sp:
+            yield sp
+    finally:
+        with _TRIPS_LOCK:
+            _TRIPS_OPEN -= 1
+
+
 def device_span(route: str, rows: int, padded_rows: int,
                 h2d_bytes: int, d2h_bytes: int, **attrs):
     """`merge.device`: one round trip to the chip (or, route `mesh`, to
     the chips of a mesh step), from the first operand's upload to the
     host holding the result.  It times the calls as they are — dispatch
     is asynchronous, the download blocks — and adds none.  Bytes are
-    the operands' and the results' sizes."""
-    return span("merge.device", cat="merge", group="merge",
-                metric=MERGE_DEVICE_MS, rows=rows,
-                padded_rows=padded_rows, h2d_bytes=h2d_bytes,
-                d2h_bytes=d2h_bytes, route=route, **attrs)
+    the operands' and the results' sizes.  Counted as `device_trip`
+    says, its `rows` in `merge` / `device_rows`."""
+    return device_trip("merge.device", rows, group="merge",
+                       metric=MERGE_DEVICE_MS, rows=rows,
+                       padded_rows=padded_rows, h2d_bytes=h2d_bytes,
+                       d2h_bytes=d2h_bytes, route=route, **attrs)
 
 
 def _host_span(route: str, rows: int):
-    """`merge.host`: a merge (or its epilogue) sorted on the host."""
-    return span("merge.host", cat="merge", rows=rows, route=route)
+    """`merge.host`: a merge sorted on the host, as far as the sorted
+    order; its winners are `merge.winners`."""
+    return span("merge.host", cat="merge", group="merge",
+                metric=MERGE_HOST_MS, rows=rows, route=route)
+
+
+def winners_span(rows: int, route: str):
+    """`merge.winners`: a merge's return side — the (perm, winner) words
+    turned into row indices: the unpack of the device's packed words, the
+    host routes' winner epilogue, `flatnonzero` and the index cast, the
+    repair of truncated keys, the delete check.  `route`: `device`,
+    `host`, `ovc`, `mesh`, `agg` (the segment ids of an aggregation) or
+    `sort` (`sort_table`'s order, which has no winners).
+    The winners' count is set at its end (ring only)."""
+    return span("merge.winners", cat="merge", group="merge",
+                metric=MERGE_WINNERS_MS, rows=rows, route=route)
 
 
 def _eq_next(lane_list, invalid, ovc_off, perm):
@@ -491,11 +543,15 @@ def _host_sorted_winners(lanes: np.ndarray, seq: np.ndarray, keep: str,
                          num_key_lanes: int,
                          need_prev: bool = True,
                          packed: Optional[np.ndarray] = None
-                         ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+                         ) -> Tuple[np.ndarray, ...]:
     """CPU-backend fallback with EXACTLY the kernel's semantics: when no
     accelerator is attached, np.lexsort beats a single-threaded XLA
     host sort ~2x and skips the device round-trip + power-of-two
-    padding entirely.  Accelerator runs never take this path."""
+    padding entirely.  Accelerator runs never take this path.
+
+    Returns the fused fast path's (perm, winner, prev), or the sorted
+    order and its neighbours' key equality (perm, eq) for the caller's
+    `_winner_epilogue` (`_host_winners`)."""
     n, num_lanes = lanes.shape
     if num_lanes == 2 and num_key_lanes == 2 and not need_prev \
             and n > 0:
@@ -516,8 +572,7 @@ def _host_sorted_winners(lanes: np.ndarray, seq: np.ndarray, keep: str,
             if p2 is not None:
                 perm = p1[p2].astype(np.int32, copy=False)
                 k_sorted = packed[perm]
-                eq = k_sorted[1:] == k_sorted[:-1]
-                return _winner_epilogue(perm, eq, keep)
+                return perm, k_sorted[1:] == k_sorted[:-1]
     lanes = np.asarray(lanes)        # materialize if lazily concatenated
     useq = seq.astype(np.int64, copy=False).view(np.uint64)
     keys = ((useq & np.uint64(0xFFFFFFFF)).astype(np.uint32),
@@ -525,8 +580,7 @@ def _host_sorted_winners(lanes: np.ndarray, seq: np.ndarray, keep: str,
             *(lanes[:, i] for i in range(num_lanes - 1, -1, -1)))
     perm = np.lexsort(keys).astype(np.int32)
     s_lanes = lanes[:, :num_key_lanes][perm]
-    eq = np.all(s_lanes[:-1] == s_lanes[1:], axis=1)
-    return _winner_epilogue(perm, eq, keep)
+    return perm, np.all(s_lanes[:-1] == s_lanes[1:], axis=1)
 
 
 def count_returned(nbytes: int) -> None:
@@ -588,19 +642,31 @@ def host_sorted_winners(lanes: np.ndarray, seq: np.ndarray, keep: str,
         # sorted-run inputs: offset-value coded merge replaces the
         # sort (single-int compares, segment boundaries for free)
         with _host_span("ovc", n):
-            res = ovc_sorted_winners(lanes, seq, keep, run_starts,
+            res = ovc_sorted_winners(lanes, seq, run_starts,
                                      num_key_lanes, packed=packed)
         if res is not None:
             PATH_COUNTS["ovc"] += 1
-            return res
+            return _host_winners(res, keep, n, "ovc")
     PATH_COUNTS["host"] += 1
     with _host_span("host", n):
         full = lanes if no_user_order \
             else np.concatenate([lanes, order_lanes], axis=1)
-        return _host_sorted_winners(full, seq, keep, num_key_lanes,
-                                    need_prev=not winners_only,
-                                    packed=packed if no_user_order
-                                    else None)
+        res = _host_sorted_winners(full, seq, keep, num_key_lanes,
+                                   need_prev=not winners_only,
+                                   packed=packed if no_user_order
+                                   else None)
+    return _host_winners(res, keep, n, "host")
+
+
+def _host_winners(res: Tuple[np.ndarray, ...], keep: str, n: int,
+                  route: str) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """A host sort's result as (perm, winner, prev): the fused fast
+    path's as it is, a sorted order with its neighbours' key equality
+    through `_winner_epilogue`, after `merge.host` has closed."""
+    if len(res) == 3:
+        return res
+    with winners_span(n, route):
+        return _winner_epilogue(*res, keep)
 
 
 def device_sorted_winners(lanes: np.ndarray, seq: np.ndarray,
@@ -696,8 +762,9 @@ def _device_winners(planes: List[np.ndarray], n: int, num_key_lanes: int,
             perm, winner, prev = (np.asarray(a) for a in out)
     count_returned(4 * m if winners_only else 9 * m)
     if winners_only:
-        perm = (packed & np.uint32(0x7FFFFFFF)).astype(np.int32)
-        winner = (packed >> np.uint32(31)).astype(bool)
+        with winners_span(n, "device"):
+            perm = (packed & np.uint32(0x7FFFFFFF)).astype(np.int32)
+            winner = (packed >> np.uint32(31)).astype(bool)
         prev = np.broadcast_to(np.int64(-1), m)
     return perm, winner, prev
 
@@ -758,18 +825,20 @@ def sort_table(table: pa.Table, key_names: Sequence[str],
         seq = np.asarray(
             table.column(SEQ_COL).combine_chunks().cast(pa.int64()))
     perm, _, _ = device_sorted_winners(lanes, seq, "last")
-    order = perm[perm < n].astype(np.int64)
-    if truncated.any():
-        # prefix ties may misorder full keys; host re-sort of affected rows
-        key_cols = [table.column(k) for k in key_names]
+    with winners_span(n, "sort"):
+        order = perm[perm < n].astype(np.int64)
+        if truncated.any():
+            # prefix ties may misorder full keys; host re-sort of
+            # affected rows
+            key_cols = [table.column(k) for k in key_names]
 
-        def full_key(i):
-            return tuple(c[int(i)].as_py() for c in key_cols)
+            def full_key(i):
+                return tuple(c[int(i)].as_py() for c in key_cols)
 
-        order = np.array(
-            sorted(order.tolist(),
-                   key=lambda i: (full_key(i), int(seq[i]))),
-            dtype=np.int64)
+            order = np.array(
+                sorted(order.tolist(),
+                       key=lambda i: (full_key(i), int(seq[i]))),
+                dtype=np.int64)
     return order
 
 
@@ -819,6 +888,7 @@ class MergeOperands:
     packed: Optional[np.ndarray] = None
     seq: Optional[np.ndarray] = None
     order_lanes: Optional[np.ndarray] = None
+    route: Optional[str] = None     # `sorted_winners`' decision
 
     @property
     def n(self) -> int:
@@ -958,8 +1028,10 @@ def sorted_winners(op: MergeOperands, winners_only: bool
     """`device_sorted_winners` of a merge's operands, the route decided
     ahead of the encode: one decision, then the one form it reads."""
     n = op.n
-    if route_to_host(n, op.num_key_lanes, op.num_order_lanes,
-                     winners_only):
+    to_host = route_to_host(n, op.num_key_lanes, op.num_order_lanes,
+                            winners_only)
+    op.route = "host" if to_host else "device"
+    if to_host:
         op.encode_host()
         res = host_sorted_winners(op.lanes, op.seq, op.keep,
                                   op.order_lanes, winners_only, op.packed,
@@ -1004,33 +1076,35 @@ def merge_runs(runs: Sequence[pa.Table], key_names: Sequence[str],
     perm, winner, prev = sorted_winners(
         op, winners_only=not with_prev and not truncated)
 
-    win_pos = np.flatnonzero(winner)
-    indices = perm[win_pos].astype(np.int64)
-    prev_idx = prev[win_pos].astype(np.int64) if with_prev else None
+    with winners_span(n, op.route) as sp:
+        win_pos = np.flatnonzero(winner)
+        indices = perm[win_pos].astype(np.int64)
+        prev_idx = prev[win_pos].astype(np.int64) if with_prev else None
 
-    if truncated:
-        indices, prev_idx = _refine_truncated(
-            table, key_names, perm, winner, op.truncated, op.seq, keep,
-            with_prev, prev)
+        if truncated:
+            indices, prev_idx = _refine_truncated(
+                table, key_names, perm, winner, op.truncated, op.seq, keep,
+                with_prev, prev)
 
-    if drop_deletes and KIND_COL in table.column_names:
-        # cheap min/max scan beats materializing the kinds array when
-        # the batch is uniformly +I or uniformly +U (the common
-        # compaction window has only +I): RowKind is +I=0 < -U=1 <
-        # +U=2 < -D=3, and only lo==hi in {0,2} proves no -U/-D hides
-        # in between
-        import pyarrow.compute as pc
-        mm = pc.min_max(table.column(KIND_COL))
-        lo, hi = mm["min"].as_py(), mm["max"].as_py()
-        if not (lo == hi and lo in (RowKind.INSERT,
-                                    RowKind.UPDATE_AFTER)):
-            kinds = np.asarray(table.column(KIND_COL).combine_chunks()
-                               .cast(pa.int8()))
-            keep_mask = (kinds[indices] == RowKind.INSERT) | \
-                        (kinds[indices] == RowKind.UPDATE_AFTER)
-            indices = indices[keep_mask]
-            if prev_idx is not None:
-                prev_idx = prev_idx[keep_mask]
+        if drop_deletes and KIND_COL in table.column_names:
+            # cheap min/max scan beats materializing the kinds array when
+            # the batch is uniformly +I or uniformly +U (the common
+            # compaction window has only +I): RowKind is +I=0 < -U=1 <
+            # +U=2 < -D=3, and only lo==hi in {0,2} proves no -U/-D hides
+            # in between
+            import pyarrow.compute as pc
+            mm = pc.min_max(table.column(KIND_COL))
+            lo, hi = mm["min"].as_py(), mm["max"].as_py()
+            if not (lo == hi and lo in (RowKind.INSERT,
+                                        RowKind.UPDATE_AFTER)):
+                kinds = np.asarray(table.column(KIND_COL).combine_chunks()
+                                   .cast(pa.int8()))
+                keep_mask = (kinds[indices] == RowKind.INSERT) | \
+                            (kinds[indices] == RowKind.UPDATE_AFTER)
+                indices = indices[keep_mask]
+                if prev_idx is not None:
+                    prev_idx = prev_idx[keep_mask]
+        sp.set(winners=len(indices))
 
     return MergeResult(table, indices, prev_idx)
 

@@ -75,16 +75,16 @@ def run_ovc_offsets(lanes, run_starts: np.ndarray) -> np.ndarray:
     return out
 
 
-def ovc_sorted_winners(lanes, seq: np.ndarray, keep: str,
+def ovc_sorted_winners(lanes, seq: np.ndarray,
                        run_starts: np.ndarray, num_key_lanes: int,
                        packed: Optional[np.ndarray] = None
-                       ) -> Optional[Tuple[np.ndarray, np.ndarray,
-                                           np.ndarray]]:
-    """(perm, winner, prev) — same contract as the unpadded host paths
-    of ops/merge.device_sorted_winners — via the native OVC merge, or
-    None when ineligible (native runtime unavailable, empty input, or a
-    run that is not actually (key, seq)-sorted; the caller falls back
-    to the sort paths)."""
+                       ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+    """(perm, eq) — the merged order over the unpadded rows and whether
+    sorted rows i and i+1 share a key, which ops/merge.py's
+    `_winner_epilogue` turns into the host paths' (perm, winner, prev) —
+    via the native OVC merge, or None when ineligible (native runtime
+    unavailable, empty input, or a run that is not actually (key,
+    seq)-sorted; the caller falls back to the sort paths)."""
     from paimon_tpu import native
 
     n = len(seq)
@@ -111,5 +111,4 @@ def ovc_sorted_winners(lanes, seq: np.ndarray, keep: str,
     # a KEY iff the first difference sits past the key lanes
     eq = (out_codes[1:] >> np.uint64(32)) \
         <= np.uint64(num_lanes - num_key_lanes)
-    from paimon_tpu.ops.merge import _winner_epilogue
-    return _winner_epilogue(perm, eq, keep)
+    return perm, eq

@@ -704,10 +704,12 @@ def _host_winners(op: M.MergeOperands, key_names) -> np.ndarray:
     perm, winner, _ = M.host_sorted_winners(
         op.lanes, op.seq, op.keep, op.order_lanes, not truncated,
         op.packed, op.run_starts)
-    if truncated:
-        return M._refine_truncated(op.table, key_names, perm, winner,
-                                   op.truncated, op.seq, op.keep, False)[0]
-    return perm[np.flatnonzero(winner)]
+    with M.winners_span(op.n, "host"):
+        if truncated:
+            return M._refine_truncated(
+                op.table, key_names, perm, winner, op.truncated, op.seq,
+                op.keep, False)[0]
+        return perm[np.flatnonzero(winner)]
 
 
 def _device_numbers(op: Optional[M.MergeOperands], lanes: _Lanes, live,

@@ -332,37 +332,44 @@ class _EngineContext:
         """Fold one window given the mesh kernel's sorted order."""
         import pyarrow as pa
 
-        from paimon_tpu.ops.merge import KIND_COL, gather
+        from paimon_tpu.compact.manager import live_span
+        from paimon_tpu.ops.merge import KIND_COL, gather, winners_span
         from paimon_tpu.types import RowKind
 
         n = wtable.num_rows
         if self.engine in (MergeEngine.DEDUPLICATE, MergeEngine.FIRST_ROW):
-            win_pos = np.flatnonzero(winner_row)
-            indices = perm_row[win_pos].astype(np.int64)
-            kinds = np.asarray(wtable.column(KIND_COL).combine_chunks()
-                               .cast(pa.int8()))
-            keep_mask = (kinds[indices] == RowKind.INSERT) | \
-                        (kinds[indices] == RowKind.UPDATE_AFTER)
-            merged = gather(wtable, indices[keep_mask])
-            return self.expire_filter(merged)
+            with winners_span(n, "mesh") as sp:
+                win_pos = np.flatnonzero(winner_row)
+                indices = perm_row[win_pos].astype(np.int64)
+                kinds = np.asarray(wtable.column(KIND_COL).combine_chunks()
+                                   .cast(pa.int8()))
+                keep_mask = (kinds[indices] == RowKind.INSERT) | \
+                            (kinds[indices] == RowKind.UPDATE_AFTER)
+                indices = indices[keep_mask]
+                sp.set(winners=len(indices))
+            merged = gather(wtable, indices)
+            with live_span(merged.num_rows):
+                return self.expire_filter(merged)
         # aggregation / partial-update: kernel order + segment ends feed
         # the shared single-chip aggregation epilogue
         from paimon_tpu.ops.agg import aggregate_sorted_segments
 
-        real = perm_row < n
-        order = perm_row[real].astype(np.int64)
-        win_sorted = np.asarray(winner_row[real], dtype=bool)
-        if len(win_sorted):
-            win_sorted[-1] = True
-            seg_end = win_sorted
-            seg_id = np.concatenate(
-                [[0], np.cumsum(seg_end[:-1])]).astype(np.int64)
-        else:
-            seg_id = np.zeros(0, np.int64)
+        with winners_span(n, "mesh"):
+            real = perm_row < n
+            order = perm_row[real].astype(np.int64)
+            win_sorted = np.asarray(winner_row[real], dtype=bool)
+            if len(win_sorted):
+                win_sorted[-1] = True
+                seg_end = win_sorted
+                seg_id = np.concatenate(
+                    [[0], np.cumsum(seg_end[:-1])]).astype(np.int64)
+            else:
+                seg_id = np.zeros(0, np.int64)
         merged = aggregate_sorted_segments(
             wtable, order, seg_id, win_sorted, self.key_cols,
             self.schema, self.options)
-        return self.expire_filter(self.live_filter(merged))
+        with live_span(merged.num_rows):
+            return self.expire_filter(self.live_filter(merged))
 
 
 def _stack_windows(device_rows, n_pad: int, num_lanes: int):

@@ -230,6 +230,13 @@ def _read_split_traced(read, split, table_path):
         return read.read_split(split)
 
 
+def _split_wait():
+    """`wait` (`what` "scan split"): the consuming thread waiting for
+    its workers — who waited, never what ran, as `wait_future`'s."""
+    from paimon_tpu.obs.trace import span
+    return span("wait", cat="wait", what="scan split")
+
+
 def _iter_pipelined(read, splits, options, par, *, ordered, stats):
     import concurrent.futures as cf
 
@@ -307,10 +314,11 @@ def _iter_pipelined(read, splits, options, par, *, ordered, stats):
                 # finished results accumulate unboundedly
                 idx, s, b, fut = inflight.popleft()
             else:
-                cf.wait([e[3] for e in inflight],
-                        timeout=None if dl is None
-                        else dl.remaining_s(),
-                        return_when=cf.FIRST_COMPLETED)
+                with _split_wait():
+                    cf.wait([e[3] for e in inflight],
+                            timeout=None if dl is None
+                            else dl.remaining_s(),
+                            return_when=cf.FIRST_COMPLETED)
                 pos = next((i for i, e in enumerate(inflight)
                             if e[3].done()), None)
                 if pos is None:
@@ -323,13 +331,15 @@ def _iter_pipelined(read, splits, options, par, *, ordered, stats):
                 idx, s, b, fut = inflight[pos]
                 del inflight[pos]
             if dl is None:
-                # lint-ok: deadline-wait no-deadline branch of an
-                # already-deadline-aware wait: the else-branch below
-                # bounds with remaining_s() and abandons hung splits
-                table = fut.result()  # raises the worker's exception
+                with _split_wait():
+                    # lint-ok: deadline-wait no-deadline branch of an
+                    # already-deadline-aware wait: the else-branch below
+                    # bounds with remaining_s() and abandons hung splits
+                    table = fut.result()  # raises the worker's exception
             else:
                 try:
-                    table = fut.result(timeout=dl.remaining_s())
+                    with _split_wait():
+                        table = fut.result(timeout=dl.remaining_s())
                 except cf.TimeoutError:
                     # the split read is HUNG past the deadline:
                     # abandon it (no join — the worker drains in the

@@ -149,7 +149,10 @@ class ServiceManager:
 
 class KvQueryServer:
     def __init__(self, table, host: str = "127.0.0.1", port: int = 0,
-                 replica_id: int = 0, delta=None):
+                 replica_id: int = 0, delta=None, slo_clock=None):
+        """`slo_clock`: the SLO evaluator's clock (seconds, monotonic;
+        `time.monotonic` when None), for a test that marches its
+        windows instead of sleeping through them."""
         opts = table.options
         if opts.get(CoreOptions.SERVICE_CACHE_SHARED):
             table = self._join_shared_cache(table)
@@ -201,7 +204,7 @@ class KvQueryServer:
         _trace.set_replica_id(f"r{self.replica_id}")
         from paimon_tpu.obs.slo import SloConfig, SloEvaluator
         self.slo = SloEvaluator(SloConfig.from_options(opts),
-                                table=table.name)
+                                table=table.name, clock=slo_clock)
         from paimon_tpu.metrics import (
             SERVICE_CHANGELOG_MS, SERVICE_CONNECTIONS,
             SERVICE_LOOKUP_CPU_MS, SERVICE_LOOKUP_KEYS,

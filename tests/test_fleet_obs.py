@@ -535,30 +535,35 @@ def test_sigtermed_daemon_leaves_spool_and_flight_dump(tmp_path):
 
 # -- leg 3: SLO burn-rate plane ----------------------------------------------
 
-def _prom_value(text, name):
-    """Last sample value of `name` in a Prometheus exposition."""
+def _prom_value(text, name, table):
+    """The sample of `name` for `table` in a Prometheus exposition: the
+    registry is the process's, and other tests' servers leave their own
+    `slo` series in it."""
     vals = [float(line.rsplit(" ", 1)[1])
             for line in text.splitlines()
-            if line.startswith(name) and not line.startswith("#")
-            and (line[len(name)] in ("{", " "))]
-    assert vals, f"{name} not in exposition"
-    return vals[-1]
+            if line.startswith(name + '{table="' + table + '"}')]
+    assert len(vals) == 1, f"{name} of {table!r}: {vals}"
+    return vals[0]
 
 
 def test_slo_storm_flips_alert_and_recovers(tmp_path):
     """An injected 504 storm burns the availability budget above the
     threshold in BOTH windows -> alert on, visible at /slo, the router
     aggregate, and the `slo` Prometheus group; after the bad events
-    age out of the fast window, a healthy loadgen run shows it clear."""
+    age out of the fast window, a healthy loadgen run shows it clear.
+    The evaluator's windows run on a clock the test marches: on the
+    wall clock a loaded machine spread the storm over more than the
+    1-s window (the failures at PRs 30, 34 and 35)."""
     from benchmarks.loadgen import run_loadgen
     from paimon_tpu.obs.export import render_prometheus
 
-    t = _serving_table(str(tmp_path / "t"), rows=64)
+    t = _serving_table(str(tmp_path / "slo_storm"), rows=64)
     t = FileStoreTable.load(t.path, dynamic_options={
         "service.slo.fast-window-s": "1.0",
         "service.slo.slow-window-s": "5.0",
         "service.slo.burn-threshold": "2.0"})
-    server = KvQueryServer(t).start()
+    now = [100.0]
+    server = KvQueryServer(t, slo_clock=lambda: now[0]).start()
     router = ReplicaRouter(servers=[server])
     router.server.start()
     try:
@@ -601,14 +606,14 @@ def test_slo_storm_flips_alert_and_recovers(tmp_path):
         # ... and through the `slo` Prometheus group (the /slo render
         # above refreshed the gauges)
         text = render_prometheus()
-        assert _prom_value(text, "paimon_slo_alert") == 1.0
+        assert _prom_value(text, "paimon_slo_alert", t.name) == 1.0
         assert _prom_value(
-            text, "paimon_slo_availability_burn_fast") >= 2.0
+            text, "paimon_slo_availability_burn_fast", t.name) >= 2.0
 
         # recovery: let the storm age past the fast window, then
         # serve a healthy loadgen run — the fast leg cools and the
         # multi-window AND clears the alert
-        time.sleep(1.1)
+        now[0] += 1.1
         res = run_loadgen(server.address, rows=64, seconds=1.0,
                           procs=1, threads=4)
         assert res["qps"] > 0
@@ -619,7 +624,7 @@ def test_slo_storm_flips_alert_and_recovers(tmp_path):
         assert healed["objectives"]["availability"]["burn_fast"] < 2.0
         assert healed["good_events"] > stormed["good_events"]
         text = render_prometheus()
-        assert _prom_value(text, "paimon_slo_alert") == 0.0
+        assert _prom_value(text, "paimon_slo_alert", t.name) == 0.0
     finally:
         router.server.stop()
         server.stop()

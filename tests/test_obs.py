@@ -816,8 +816,7 @@ def test_stage_histograms_fill_and_bytes_only_on_the_device_route(
         total, samples = _delta(before, after, group, metric)
         assert samples > 0 and total > 0, (group, metric)
     # stages no metric reads are spans only: no histogram, no counter
-    for group, metric in (("merge", "host_ms"),
-                          ("merge", "h2d_bytes"), ("compaction", "wait_ms"),
+    for group, metric in (("merge", "h2d_bytes"), ("compaction", "wait_ms"),
                           ("compaction", "cut_ms"),
                           ("scan", "assemble_ms")):
         assert (group, metric) not in after, (group, metric)
@@ -838,6 +837,9 @@ def test_stage_histograms_fill_and_bytes_only_on_the_device_route(
     else:
         assert not device and device_ms[1] == 0
         assert "merge.host" in names
+    # `merge.host` names a sink since PR 36: a sample a host-route merge
+    hosts = [s for s in spans if s.name == "merge.host"]
+    assert _delta(before, after, "merge", "host_ms")[1] == len(hosts)
 
 
 def _small_partial_update_table(path, rows=3_000, commits=3):
@@ -1038,3 +1040,218 @@ def test_task_and_commit_durations_keep_one_sample_each(tmp_path):
         wb.new_commit().commit(w.prepare_commit())
     assert _delta(after, _registry_totals(), "commit",
                   "duration_ms")[1] == 1
+
+
+# -- leaves under the envelopes, round trips in flight (ISSUE 36) -----------
+
+def _flush_one_commit(path):
+    table = FileStoreTable.create(path, _schema({"bucket": "1"}))
+    wb = table.new_batch_write_builder()
+    with wb.new_write() as w:
+        w.write_arrow(_data(3_000, 1))
+        wb.new_commit().commit(w.prepare_commit())
+
+
+# case: (the operation, the leaf, the envelope above it, the root,
+#        attrs the leaf carries)
+_LEAF_CASES = {
+    "scan:merge.winners": ("scan", "merge.winners", "scan.merge",
+                           "scan.to_arrow", {"route": "host"}),
+    "scan:wait": ("scan", "wait", "scan.to_arrow", "scan.to_arrow",
+                  {"what": "scan split"}),
+    "flush:write.build": ("flush", "write.build", "write.sort",
+                          "write.prepare", {"rows": 3_000}),
+    "compaction:file.stats": ("compaction", "file.stats", "compact.task",
+                              "compact.table", {"columns": 4}),
+    "agg_compaction:agg.mask": ("agg_compaction", "agg.mask", "agg.reduce",
+                                "compact.table", {"column": "v"}),
+    "mesh_compaction:merge.winners": ("mesh_compaction", "merge.winners",
+                                      "compact.task", "compact.table",
+                                      {"route": "mesh"}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LEAF_CASES))
+def test_new_leaves_lie_under_their_envelopes_and_have_no_child(
+        tmp_path, monkeypatch, case):
+    """Each leaf of ISSUE 36 is recorded by the operation that runs its
+    code, has no span inside it, lies under the envelope whose own time
+    it takes, and walks back to the operation's root."""
+    operation, leaf, envelope, root, attrs = _LEAF_CASES[case]
+    monkeypatch.delenv("PAIMON_FORCE_DEVICE_SORT", raising=False)
+    path = str(tmp_path / "t")
+    if operation == "flush":
+        obs.enable_tracing(max_spans=50_000)
+        _flush_one_commit(path)
+    elif operation == "agg_compaction":
+        table = _small_agg_table(path, streamed=False)
+        obs.enable_tracing(max_spans=50_000)
+        assert table.compact(full=True) is not None
+    else:
+        table = _build_traced_table(path, rows=4_000)
+        obs.enable_tracing(max_spans=50_000)
+        if operation == "scan":
+            table.to_arrow()
+        else:
+            if operation == "mesh_compaction":
+                table = table.copy({"tpu.mesh.compact": "true"})
+            assert table.compact(full=True) is not None
+    spans = obs.take_spans()
+    by_id = {s.span_id: s for s in spans}
+    parents = {s.parent_id for s in spans}
+    found = [s for s in spans if s.name == leaf
+             and all(s.attrs.get(k) == v for k, v in attrs.items())]
+    assert found, (leaf, attrs, sorted({s.name for s in spans}))
+    roots = _roots(spans)
+    for s in found:
+        assert s.span_id not in parents, f"{leaf} has a child"
+        above, up = [], s
+        while up.parent_id is not None:
+            up = by_id[up.parent_id]
+            above.append(up.name)
+        assert envelope in above, (leaf, above)
+        assert roots[s.span_id].name == root
+    if leaf == "merge.winners":
+        assert all(s.attrs["rows"] > 0 for s in found)
+        assert any(s.attrs.get("winners", 0) > 0 for s in found)
+
+
+@pytest.mark.parametrize("leaf,roots", [
+    ("io.open", {"scan.to_arrow"}),
+    ("write.buffer", {"write.batch", "write.prepare"}),
+    ("compact.live", {"compact.table"})])
+def test_leaves_given_after_the_first_self_times(tmp_path, leaf, roots):
+    """`io.open` (between `io.read` and `decode`), `write.buffer` (the
+    caller thread's appends and the detached payload) and `compact.live`
+    (the merged state's retract filter) name no sink: spans only, leaves,
+    under their operation's root."""
+    from paimon_tpu.obs import trace as T
+    assert T.span(leaf, cat="io") is T._NOOP        # no listener, no cost
+    path = str(tmp_path / "t")
+    if leaf == "write.buffer":
+        obs.enable_tracing(max_spans=50_000)
+        _flush_one_commit(path)
+    else:
+        table = _small_agg_table(path, streamed=False)
+        obs.enable_tracing(max_spans=50_000)
+        if leaf == "io.open":
+            table.to_arrow()
+        else:
+            assert table.compact(full=True) is not None
+    spans = obs.take_spans()
+    found = [s for s in spans if s.name == leaf]
+    assert found, sorted({s.name for s in spans})
+    parents = {s.parent_id for s in spans}
+    tops = _roots(spans)
+    assert not {s.span_id for s in found} & parents
+    assert {tops[s.span_id].name for s in found} <= roots
+    assert all(s.attrs.get("rows", s.attrs.get("bytes", 0)) > 0
+               for s in found)
+
+
+def test_new_sinks_and_counters_move_with_tracing_off(tmp_path,
+                                                      monkeypatch):
+    """No ring, no profiler: the five new histograms take their samples
+    and the four new counters count; with the metrics off all stand
+    still."""
+    from paimon_tpu.ops import merge as M
+    monkeypatch.delenv("PAIMON_FORCE_HOST_SORT", raising=False)
+    assert not obs.tracing_enabled() and not obs.profiler_listening()
+    sinks = (("merge", "winners_ms"), ("io", "stats_ms"),
+             ("write", "build_ms"), ("merge", "mask_ms"),
+             ("merge", "host_ms"))
+    counters = (("merge", "device_trips"), ("merge", "device_inflight_sum"),
+                ("merge", "device_rows"), ("write", "route_rows"))
+
+    def run(tag):
+        # host route (the cpu backend's own): a flush, an aggregation
+        # compaction, a merging scan; then the scan's merges on the
+        # device programs
+        monkeypatch.delenv("PAIMON_FORCE_DEVICE_SORT", raising=False)
+        agg = _small_agg_table(str(tmp_path / f"a{tag}"), streamed=False)
+        assert agg.compact(full=True) is not None
+        dedup = _build_traced_table(str(tmp_path / f"d{tag}"), rows=2_000)
+        dedup.to_arrow()
+        monkeypatch.setenv("PAIMON_FORCE_DEVICE_SORT", "1")
+        dedup.to_arrow()
+
+    before = _registry_totals()
+    run("on")
+    after = _registry_totals()
+    for group, metric in sinks:
+        total, samples = _delta(before, after, group, metric)
+        assert samples > 0 and total > 0, (group, metric)
+    moved = {c: _delta(before, after, *c)[0] for c in counters}
+    assert all(v > 0 for v in moved.values()), moved
+    # one merge a bucket, each alone or beside the other workers', and
+    # the aggregation's sum and its validity maximum (`agg.device`):
+    # round trips too, which count no merge rows
+    trips = moved[("merge", "device_trips")]
+    assert trips == 8 + 2
+    assert trips <= moved[("merge", "device_inflight_sum")] <= 8 * trips
+    assert moved[("merge", "device_rows")] == 4_000
+    # every row a `write.route` handled: three commits and two
+    assert moved[("write", "route_rows")] == 3 * 6_000 + 2 * 2_000
+    assert M._TRIPS_OPEN == 0
+    obs.set_metrics_enabled(False)
+    run("off")
+    still = _registry_totals()
+    for key in sinks + counters:
+        assert _delta(after, still, *key) == (0, 0), key
+    assert M._TRIPS_OPEN == 0
+
+
+def test_round_trips_in_flight_are_counted_and_given_back(monkeypatch):
+    """Four merges at once on the device programs: four trips, each
+    counting those open as it opened (itself included), and the count of
+    open round trips back at zero — after an exception inside the span
+    too."""
+    import threading
+
+    from paimon_tpu.ops import merge as M
+    monkeypatch.delenv("PAIMON_FORCE_HOST_SORT", raising=False)
+    monkeypatch.setenv("PAIMON_FORCE_DEVICE_SORT", "1")
+    rng = np.random.default_rng(3)
+    n = 5_000
+    lanes = rng.integers(0, 1 << 10, (n, 2)).astype(np.uint32)
+    seq = np.arange(n, dtype=np.int64)
+    M.device_sorted_winners(lanes, seq, winners_only=True)   # compiled
+    barrier = threading.Barrier(4)
+    results, errors = [], []
+
+    def merge():
+        try:
+            barrier.wait(timeout=60)
+            results.append(M.device_sorted_winners(lanes, seq,
+                                                   winners_only=True))
+        except Exception as e:              # noqa: BLE001
+            errors.append(e)
+
+    obs.enable_tracing()
+    before = _registry_totals()
+    threads = [threading.Thread(target=merge) for _ in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    after = _registry_totals()
+    assert not errors and len(results) == 4
+    assert _delta(before, after, "merge", "device_trips")[0] == 4
+    assert 4 <= _delta(before, after, "merge",
+                       "device_inflight_sum")[0] <= 16
+    assert _delta(before, after, "merge", "device_rows")[0] == 4 * n
+    trips = [s for s in obs.take_spans() if s.name == "merge.device"]
+    assert sorted(s.attrs["inflight"] for s in trips)[0] == 1
+    assert sum(s.attrs["inflight"] for s in trips) == \
+        _delta(before, after, "merge", "device_inflight_sum")[0]
+    assert M._TRIPS_OPEN == 0
+    with pytest.raises(RuntimeError):
+        with M.device_span("packed", 1, 1024, 0, 0) as sp:
+            assert sp.attrs["inflight"] == 1 and M._TRIPS_OPEN == 1
+            with M.device_trip("agg.device", rows=1) as inner:
+                assert inner.attrs["inflight"] == 2
+            raise RuntimeError("inside the round trip")
+    assert M._TRIPS_OPEN == 0
+    (failed,) = [s for s in obs.take_spans() if s.name == "merge.device"
+                 and s.attrs.get("error")]
+    assert failed.attrs["error"] == "RuntimeError"
