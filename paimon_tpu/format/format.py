@@ -165,21 +165,33 @@ class _ParquetWriter(FormatWriter):
             "parquet.enable.dictionary", "true").lower() != "false"
 
     def write(self, file_io, path, table):
-        from paimon_tpu.metrics import IO_ENCODE_MS, IO_UPLOAD_MS
-        from paimon_tpu.obs.trace import span
-        buf = io.BytesIO()
+        from paimon_tpu.format.parquet_stitch import encode_table
+        from paimon_tpu.metrics import (
+            IO_ENCODE_MS, IO_ENCODE_ROWS, IO_ENCODE_SPLIT_ROWS,
+            IO_UPLOAD_MS, global_registry,
+        )
+        from paimon_tpu.obs.trace import metrics_enabled, span
         rg = self.row_group_rows
         if self.block_bytes and table.num_rows:
             per_row = max(1, table.nbytes // table.num_rows)
             rg = max(1024, self.block_bytes // per_row)
+        # `encode` is the wall time of this thread, whoever encodes: a
+        # file of several pieces has them on the process's encode pool
+        # (their `encode.piece` spans) and on this thread, and is the
+        # same bytes as the one call's
         with span("encode", cat="io", group="io", metric=IO_ENCODE_MS,
-                  path=path, rows=table.num_rows):
-            pq.write_table(table, buf, compression=self.compression,
-                           compression_level=self.level,
-                           row_group_size=rg,
-                           use_dictionary=self.use_dictionary,
-                           write_statistics=True)
-        data = buf.getvalue()
+                  path=path, rows=table.num_rows) as sp:
+            data, pieces = encode_table(table, rg, dict(
+                compression=self.compression,
+                compression_level=self.level,
+                use_dictionary=self.use_dictionary,
+                write_statistics=True))
+            sp.set(pieces=pieces)
+        if metrics_enabled():
+            group = global_registry().group("io")
+            group.counter(IO_ENCODE_ROWS).inc(table.num_rows)
+            if pieces > 1:
+                group.counter(IO_ENCODE_SPLIT_ROWS).inc(table.num_rows)
         with span("io.upload", cat="io", group="io",
                   metric=IO_UPLOAD_MS, path=path, bytes=len(data)):
             file_io.write_bytes(path, data, overwrite=False)

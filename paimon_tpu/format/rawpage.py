@@ -40,6 +40,7 @@ import numpy as np
 import pyarrow as pa
 import pyarrow.parquet as pq
 
+from paimon_tpu.format.thrift import varint as _varint, zigzag as _zigzag
 from paimon_tpu.fs import FileIO
 
 __all__ = ["DeviceDecodeUnsupported", "read_parquet_device",
@@ -93,22 +94,6 @@ def _parsing():
 # thrift compact protocol (page headers only — footers come from the
 # cached pyarrow FileMetaData)
 # ---------------------------------------------------------------------------
-
-
-def _varint(buf: bytes, pos: int) -> Tuple[int, int]:
-    out = shift = 0
-    while True:
-        b = buf[pos]
-        pos += 1
-        out |= (b & 0x7F) << shift
-        if not b & 0x80:
-            return out, pos
-        shift += 7
-
-
-def _zigzag(buf: bytes, pos: int) -> Tuple[int, int]:
-    v, pos = _varint(buf, pos)
-    return (v >> 1) ^ -(v & 1), pos
 
 
 def _skip(buf: bytes, pos: int, ftype: int) -> int:

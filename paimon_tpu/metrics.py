@@ -29,6 +29,7 @@ __all__ = ["Counter", "Gauge", "Histogram", "MetricGroup",
            "SCAN_SPLIT_MS", "SCAN_MERGE_MS",
            "WRITE_SORT_MS", "WRITE_FLUSH_TASK_MS",
            "IO_READ_MS", "IO_DECODE_MS", "IO_ENCODE_MS", "IO_UPLOAD_MS",
+           "IO_ENCODE_ROWS", "IO_ENCODE_SPLIT_ROWS",
            "COMPACTION_WINDOW_MS", "COMPACTION_FALLBACK_MS",
            "COMMIT_CAS_MS", "COMMIT_MANIFEST_ENCODE_MS",
            "COMMIT_DURATION_MS", "COMPACTION_DURATION_MS",
@@ -144,6 +145,10 @@ IO_READ_MS = "read_ms"                      # io: store -> bytes
 IO_DECODE_MS = "decode_ms"                  # io: bytes -> Arrow
 IO_ENCODE_MS = "encode_ms"                  # io: Arrow -> bytes
 IO_UPLOAD_MS = "upload_ms"                  # io: bytes -> store
+IO_ENCODE_ROWS = "encode_rows"              # counter: rows of the Parquet
+                                            # files `write` encoded
+IO_ENCODE_SPLIT_ROWS = "encode_split_rows"  # counter: those whose file was
+                                            # encoded in several pieces
 IO_STATS_MS = "stats_ms"                    # io: a rolled file's column
                                             # statistics, first / last key
 COMPACTION_WINDOW_MS = "window_ms"          # compaction: device window
