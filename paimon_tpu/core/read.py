@@ -135,13 +135,17 @@ def evolve_table(table: pa.Table, file_schema_id: int, schema: TableSchema,
     return pa.table(cols)
 
 
-def _count_rows_in(rows: int) -> None:
+def _count_rows_in(rows: int, raw: bool = False) -> None:
     """`scan` / `rows_in`: the rows a split's files held, before any
-    merge, filter or aggregate."""
-    from paimon_tpu.metrics import SCAN_ROWS_IN, global_registry
+    merge, filter or aggregate; `raw`: read with no merge (`_read_raw`),
+    counted in `scan` / `raw_rows` too."""
+    from paimon_tpu.metrics import SCAN_RAW_ROWS, SCAN_ROWS_IN, global_registry
     from paimon_tpu.obs.trace import metrics_enabled
     if metrics_enabled():
-        global_registry().scan_metrics().counter(SCAN_ROWS_IN).inc(rows)
+        group = global_registry().scan_metrics()
+        group.counter(SCAN_ROWS_IN).inc(rows)
+        if raw:
+            group.counter(SCAN_RAW_ROWS).inc(rows)
 
 
 def assemble_tables(tables: Sequence[pa.Table]) -> pa.Table:
@@ -354,7 +358,7 @@ class MergeFileSplitRead:
         if not tables:
             return self._empty_table(bool(split.for_streaming))
         merged = pa.concat_tables(tables, promote_options="none")
-        _count_rows_in(merged.num_rows)
+        _count_rows_in(merged.num_rows, raw=True)
         if split.for_streaming and split.is_delta:
             # changelog consumers observe every row with its kind
             # (reference streaming read preserves RowKind; -U/-D survive)

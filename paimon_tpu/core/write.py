@@ -13,6 +13,7 @@ in one shot and written columnar.
 
 from __future__ import annotations
 
+import contextlib
 import os
 from collections import deque
 from dataclasses import dataclass, field as dc_field
@@ -842,14 +843,26 @@ class KeyValueFileStoreWrite:
         def prep(table=table, kinds=row_kinds.copy(),
                  pre=precomputed_buckets):
             from paimon_tpu.metrics import (
+                WRITE_HASH_MS, WRITE_HASH_ROWS, WRITE_HASH_VECTOR_ROWS,
                 WRITE_ROUTE_MS, WRITE_ROUTE_NOCOPY_ROWS, WRITE_ROUTE_ROWS,
                 global_registry,
             )
             from paimon_tpu.obs.trace import metrics_enabled, span
             with span("write.route", cat="write", group="write",
                       metric=WRITE_ROUTE_MS, rows=table.num_rows) as sp:
-                buckets = pre if pre is not None \
-                    else self.bucket_assigner.assign(table)
+                buckets = pre
+                if buckets is None:
+                    hashed, vector = self.bucket_assigner.hashed_rows(
+                        table.num_rows)
+                    # one bucket hashes nothing: no span, the route a leaf
+                    with span("write.hash", cat="write", group="write",
+                              metric=WRITE_HASH_MS, rows=table.num_rows) \
+                            if hashed else contextlib.nullcontext():
+                        buckets = self.bucket_assigner.assign(table)
+                    if metrics_enabled():
+                        group = global_registry().write_metrics()
+                        group.counter(WRITE_HASH_ROWS).inc(hashed)
+                        group.counter(WRITE_HASH_VECTOR_ROWS).inc(vector)
                 groups = lpt_order(group_by_partition_bucket(
                     table, buckets, self.partition_keys))
                 if len(groups) == 1:

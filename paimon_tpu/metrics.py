@@ -42,9 +42,11 @@ __all__ = ["Counter", "Gauge", "Histogram", "MetricGroup",
            "MERGE_RETURN_BYTES", "MERGE_PREP_PLANAR_ROWS", "SCAN_AGG_MS",
            "MERGE_WINNERS_MS", "MERGE_HOST_MS", "MERGE_MASK_MS",
            "MERGE_DEVICE_TRIPS", "MERGE_DEVICE_INFLIGHT_SUM",
-           "MERGE_DEVICE_ROWS",
+           "MERGE_DEVICE_ROWS", "MERGE_TIEBREAK_MS", "MERGE_TIEBREAK_ROWS",
+           "MERGE_TIEBREAK_RESORTED_ROWS",
+           "WRITE_HASH_MS", "WRITE_HASH_ROWS", "WRITE_HASH_VECTOR_ROWS",
            "SCAN_AGG_BELOW_ROWS",
-           "SCAN_ROWS_IN",
+           "SCAN_ROWS_IN", "SCAN_RAW_ROWS",
            "STREAM_EVENTS_INGESTED", "STREAM_CHECKPOINTS",
            "STREAM_CHECKPOINT_MS", "STREAM_LOOP_RESTARTS",
            "STREAM_FRESHNESS_MS", "STREAM_CHANGELOG_ROWS",
@@ -139,6 +141,9 @@ SCAN_AGG_BELOW_ROWS = "agg_below_rows"      # counter: rows aggregated below
 #                                             the merge (ops/scan_agg.py)
 SCAN_ROWS_IN = "rows_in"                    # counter: rows the split reads
 #                                             of pk tables decoded
+SCAN_RAW_ROWS = "raw_rows"                  # counter: those of them read
+#                                             with no merge (a split of
+#                                             one sorted run, `_read_raw`)
 WRITE_SORT_MS = "sort_ms"                   # write: buffer sort/dedup
 WRITE_FLUSH_TASK_MS = "flush_task_ms"       # write: whole flush task
 IO_READ_MS = "read_ms"                      # io: store -> bytes
@@ -175,6 +180,11 @@ WRITE_ROUTE_NOCOPY_ROWS = "route_nocopy_rows"   # counter: rows of batches
                                             # without a take
 WRITE_ROUTE_ROWS = "route_rows"             # counter: rows the route handled
 WRITE_BUILD_MS = "build_ms"                 # write: a flush's KV-shaped table
+WRITE_HASH_MS = "hash_ms"                   # write: the route's bucket hash
+WRITE_HASH_ROWS = "hash_rows"               # counter: rows the route hashed
+WRITE_HASH_VECTOR_ROWS = "hash_vector_rows"  # counter: those hashed by the
+                                            # vectorised path, not a row
+                                            # at a time
 # merge metric group: the stages of one sorted-run merge, whoever
 # called it (scan split, flush sort, compaction window) — producers
 # in ops/merge.py, ops/agg.py and compact/manager.py
@@ -199,6 +209,13 @@ MERGE_DEVICE_INFLIGHT_SUM = "device_inflight_sum"   # counter: round trips
                                             # many shared the link
 MERGE_DEVICE_ROWS = "device_rows"           # counter: real rows of the merge
                                             # round trips (`merge.device`)
+MERGE_TIEBREAK_MS = "tiebreak_ms"           # keys cut to their lane prefix
+                                            # put in exact order
+MERGE_TIEBREAK_ROWS = "tiebreak_rows"       # counter: rows compared by
+                                            # their full key bytes
+MERGE_TIEBREAK_RESORTED_ROWS = "tiebreak_resorted_rows"  # counter: rows of
+                                            # prefix groups holding several
+                                            # keys, sorted again
 
 # streaming-daemon counter/gauge/histogram names (stream metric group;
 # producer is service/stream_daemon.py, consumers tests/soak_harness.py

@@ -33,7 +33,7 @@ from paimon_tpu.obs.trace import span
 from paimon_tpu.options import CoreOptions, MergeEngine
 from paimon_tpu.ops.merge import (
     KIND_COL, SEQ_COL, device_sorted_winners, device_trip, gather,
-    gather_values, prep_span, winners_span,
+    gather_values, prep_span, tiebreak_cut_keys, winners_span,
 )
 from paimon_tpu.ops.normkey import NormalizedKeyEncoder
 from paimon_tpu.schema.table_schema import TableSchema
@@ -85,20 +85,30 @@ def sequence_groups(schema: TableSchema,
 
 def _segment_ids_from_sort(lanes: np.ndarray, seq: np.ndarray,
                            truncated: Optional[np.ndarray] = None,
-                           full_key=None, order_lanes=None,
+                           cut_keys=None, order_lanes=None,
                            packed: Optional[np.ndarray] = None,
                            run_starts: Optional[np.ndarray] = None):
     """Shared device sort -> (order over real rows, segment ids).
 
-    If some rows' string keys exceeded the lane prefix (`truncated`),
-    device segments may over-group prefix-equal keys; the affected spans
-    are repaired on the host by re-sorting on the full key (`full_key`:
-    row index -> comparable tuple) and splitting sub-segments."""
+    If some rows' string keys were cut to the lane prefix (`truncated`),
+    the sort's segments may join prefix-equal keys; `cut_keys` (the
+    table, its key column names and the encoder) then puts the order in
+    exact key order and cuts the segments by full-key equality
+    (ops/merge.py `tiebreak_cut_keys`)."""
     n = lanes.shape[0]
     perm, winner, _ = device_sorted_winners(
         lanes, seq, "last", order_lanes, packed=packed,
         run_starts=run_starts if order_lanes is None else None)
     with winners_span(n, "agg"):
+        if truncated is not None and truncated.any() \
+                and cut_keys is not None:
+            order, same = tiebreak_cut_keys(*cut_keys, lanes, truncated,
+                                            perm, seq, order_lanes)
+            seg_id = np.zeros(len(order), dtype=np.int64)
+            np.cumsum(~same, out=seg_id[1:])
+            win_sorted = np.ones(len(order), dtype=bool)
+            win_sorted[:-1] = ~same
+            return order, seg_id, win_sorted
         real = perm < n
         order = perm[real].astype(np.int64)
         win_sorted = winner[real]
@@ -108,40 +118,6 @@ def _segment_ids_from_sort(lanes: np.ndarray, seq: np.ndarray,
         seg_id = np.concatenate([[0], np.cumsum(seg_end[:-1])]) \
             if len(seg_end) else np.zeros(0, np.int64)
         seg_id = seg_id.astype(np.int64)
-
-        if truncated is not None and truncated.any() and full_key is not None:
-            aff_ids = np.unique(seg_id[truncated[order]])
-            m = len(order)
-            if len(aff_ids) and m:
-                # seg_id is sorted, so each affected segment is one contiguous
-                # span located in O(log n); only those spans pay host work
-                starts = np.searchsorted(seg_id, aff_ids, side="left")
-                ends = np.searchsorted(seg_id, aff_ids, side="right")
-                new_order = order.copy()
-                boundaries = np.empty(m, dtype=bool)   # True = segment start
-                boundaries[0] = True
-                boundaries[1:] = seg_id[1:] != seg_id[:-1]
-                for s, e in zip(starts, ends):
-                    span = order[s:e].tolist()
-                    fk = {r: full_key(r) for r in span}
-                    # within a key: user sequence first (when present), then
-                    # internal sequence — same order the device sort used
-                    resorted = sorted(
-                        span,
-                        key=lambda r: (fk[r],
-                                       tuple(order_lanes[r])
-                                       if order_lanes is not None else (),
-                                       int(seq[r])))
-                    new_order[s:e] = resorted
-                    prev_key = None
-                    for k, r in enumerate(resorted):
-                        boundaries[s + k] = (fk[r] != prev_key)
-                        prev_key = fk[r]
-                order = new_order
-                seg_id = np.cumsum(boundaries) - 1
-                win_sorted = np.empty(m, dtype=bool)
-                win_sorted[:-1] = seg_id[:-1] != seg_id[1:]
-                win_sorted[-1] = True
     return order, seg_id, win_sorted
 
 
@@ -371,13 +347,6 @@ def merge_runs_agg(runs: Sequence[pa.Table], key_cols: Sequence[str],
                                                                key_cols)
         seq = np.asarray(
             table.column(SEQ_COL).combine_chunks().cast(pa.int64()))
-        full_key = None
-        if truncated.any():
-            kcols = [table.column(k) for k in key_cols]
-
-            def full_key(i: int):
-                return tuple(c[int(i)].as_py() for c in kcols)
-
         from paimon_tpu.ops.merge import user_seq_order_lanes
         order_lanes = user_seq_order_lanes(
             table, seq_fields, options.sequence_field_descending) \
@@ -385,8 +354,8 @@ def merge_runs_agg(runs: Sequence[pa.Table], key_cols: Sequence[str],
         run_starts = np.concatenate(
             [[0], np.cumsum([r.num_rows for r in runs])]).astype(np.int64)
     order, seg_id, win_sorted = _segment_ids_from_sort(
-        lanes, seq, truncated, full_key, order_lanes, packed=packed,
-        run_starts=run_starts)
+        lanes, seq, truncated, (table, key_cols, key_encoder), order_lanes,
+        packed=packed, run_starts=run_starts)
     return aggregate_sorted_segments(table, order, seg_id, win_sorted,
                                      key_cols, schema, options)
 

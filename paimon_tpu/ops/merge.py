@@ -52,11 +52,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
+import pyarrow.compute as pc
 
 from paimon_tpu.metrics import (
     MERGE_DEVICE_INFLIGHT_SUM, MERGE_DEVICE_MS, MERGE_DEVICE_ROWS,
     MERGE_DEVICE_TRIPS, MERGE_GATHER_BYTES, MERGE_GATHER_MS, MERGE_HOST_MS,
     MERGE_PREP_MS, MERGE_PREP_PLANAR_ROWS, MERGE_RETURN_BYTES,
+    MERGE_TIEBREAK_MS, MERGE_TIEBREAK_RESORTED_ROWS, MERGE_TIEBREAK_ROWS,
     MERGE_WINNERS_MS, global_registry,
 )
 from paimon_tpu.obs.trace import metrics_enabled, span
@@ -71,7 +73,7 @@ from paimon_tpu.types import RowKind
 
 __all__ = ["merge_runs", "MergeResult", "MergeOperands", "merge_operands",
            "device_sorted_winners", "sorted_winners", "route_to_host",
-           "host_sorted_winners",
+           "host_sorted_winners", "tiebreak_cut_keys",
            "take_link_reading", "user_seq_order_lanes", "SEQ_COL",
            "KIND_COL"]
 
@@ -826,20 +828,137 @@ def sort_table(table: pa.Table, key_names: Sequence[str],
             table.column(SEQ_COL).combine_chunks().cast(pa.int64()))
     perm, _, _ = device_sorted_winners(lanes, seq, "last")
     with winners_span(n, "sort"):
-        order = perm[perm < n].astype(np.int64)
         if truncated.any():
-            # prefix ties may misorder full keys; host re-sort of
-            # affected rows
-            key_cols = [table.column(k) for k in key_names]
+            return tiebreak_cut_keys(table, key_names, key_encoder, lanes,
+                                     truncated, perm, seq)[0]
+        return perm[perm < n].astype(np.int64)
 
-            def full_key(i):
-                return tuple(c[int(i)].as_py() for c in key_cols)
 
-            order = np.array(
-                sorted(order.tolist(),
-                       key=lambda i: (full_key(i), int(seq[i]))),
-                dtype=np.int64)
-    return order
+def _adjacent_equal(lanes: np.ndarray, order: np.ndarray, lo: int,
+                    hi: int) -> np.ndarray:
+    """bool[n-1]: rows order[i] and order[i+1] agree in lanes lo..hi-1
+    (all true where the range is empty)."""
+    eq = np.ones(max(len(order) - 1, 0), dtype=bool)
+    for j in range(lo, hi):
+        col = lanes[:, j][order]
+        eq &= col[1:] == col[:-1]
+    return eq
+
+
+def _pairs_same_bytes(columns: Sequence[pa.ChunkedArray], a: np.ndarray,
+                      b: np.ndarray) -> np.ndarray:
+    """bool[k]: rows a[i] and b[i] hold the same bytes in each of the
+    string / binary `columns` (a null equals a null alone) — one Arrow
+    take of each side and one compare a column."""
+    same = np.ones(len(a), dtype=bool)
+    if not len(a):
+        return same
+    ia, ib = pa.array(a), pa.array(b)
+    for col in columns:
+        x, y = col.take(ia), col.take(ib)
+        eq = pc.equal(x, y)
+        if eq.null_count:
+            eq = pc.or_(pc.fill_null(eq, False),
+                        pc.and_(pc.is_null(x), pc.is_null(y)))
+        same &= eq.to_numpy()
+    return same
+
+
+def tiebreak_cut_keys(table: pa.Table, key_names: Sequence[str],
+                      key_encoder: NormalizedKeyEncoder, lanes, truncated,
+                      perm: np.ndarray, seq: np.ndarray,
+                      order_lanes: Optional[np.ndarray] = None
+                      ) -> Tuple[np.ndarray, np.ndarray]:
+    """`merge.tiebreak`: a sort by key lanes turned into the exact order
+    of keys whose lanes hold a cut prefix (the encoder's `truncated`).
+
+    `perm` is the (lanes, order lanes, seq, arrival) order of a host or
+    device route, padding included.  Rows that share the head lanes
+    (`cut_head_lanes`, through the last string column) and of which one
+    is cut are compared by their full bytes, one vectorised pass over
+    the neighbour pairs.  A group of equal head lanes that holds one key
+    is already exact; only groups that hold several are sorted again,
+    by (group, full key, order lanes, seq, arrival) in one Arrow sort.
+    Returns (order, same): int64[n] rows in exact order, and bool[n-1],
+    True where sorted rows i and i+1 share their full key.  Counted in
+    `merge` / `tiebreak_rows` (rows of the compared pairs) and
+    `tiebreak_resorted_rows` (rows of the groups sorted again)."""
+    n = len(seq)
+    with span("merge.tiebreak", cat="merge", group="merge",
+              metric=MERGE_TIEBREAK_MS, rows=n) as sp:
+        order = perm[perm < n].astype(np.int64)
+        lanes = np.asarray(lanes)
+        head, num_lanes = key_encoder.cut_head_lanes, lanes.shape[1]
+        eq_head = _adjacent_equal(lanes, order, 0, head)
+        same = eq_head & _adjacent_equal(lanes, order, head, num_lanes)
+        cut = truncated[order]
+        pairs = np.flatnonzero(eq_head & (cut[1:] | cut[:-1]))
+        byte_cols = [table.column(key_names[i])
+                     for i in key_encoder.bytes_columns]
+        differ = pairs[~_pairs_same_bytes(byte_cols, order[pairs],
+                                          order[pairs + 1])]
+        same[differ] = False
+        compared = np.zeros(n, dtype=bool)
+        compared[pairs] = compared[pairs + 1] = True
+        resorted = 0
+        if len(differ):
+            group = np.zeros(n, dtype=np.int64)
+            np.cumsum(~eq_head, out=group[1:])
+            several = np.zeros(int(group[-1]) + 1, dtype=bool)
+            several[group[differ]] = True
+            rows = np.flatnonzero(several[group])
+            resorted = len(rows)
+            sub = order[rows]
+            order[rows] = sub[_exact_sort(
+                table, key_names, key_encoder, lanes, group[rows], sub,
+                seq, order_lanes)]
+            inner = rows[rows < n - 1]
+            inner = inner[eq_head[inner]]
+            a, b = order[inner], order[inner + 1]
+            same[inner] = _pairs_same_bytes(byte_cols, a, b) & np.all(
+                lanes[a, head:] == lanes[b, head:], axis=1)
+        if metrics_enabled():
+            group_m = global_registry().group("merge")
+            group_m.counter(MERGE_TIEBREAK_ROWS).inc(
+                int(np.count_nonzero(compared)))
+            group_m.counter(MERGE_TIEBREAK_RESORTED_ROWS).inc(resorted)
+        sp.set(compared=int(np.count_nonzero(compared)), resorted=resorted)
+    return order, same
+
+
+def _exact_sort(table: pa.Table, key_names: Sequence[str],
+                key_encoder: NormalizedKeyEncoder, lanes: np.ndarray,
+                group: np.ndarray, rows: np.ndarray, seq: np.ndarray,
+                order_lanes: Optional[np.ndarray]) -> np.ndarray:
+    """Positions of `rows` in (group, key, order lanes, seq, arrival)
+    order, a key compared as the encoder orders it but with each string
+    column's full bytes in place of its prefix lanes."""
+    keys = {"group": group}
+    bytes_cols = set(key_encoder.bytes_columns)
+    lane = 0
+    for i, (nl, nullable) in enumerate(zip(key_encoder.lanes_per_col,
+                                           key_encoder.nullable)):
+        if i in bytes_cols:
+            if nullable:                # the presence lane orders a null
+                keys[f"lane{lane}"] = lanes[rows, lane]
+            col = table.column(key_names[i])
+            if pa.types.is_string(col.type):
+                col = col.cast(pa.binary())
+            elif pa.types.is_large_string(col.type):
+                col = col.cast(pa.large_binary())
+            keys[f"key{i}"] = col.take(pa.array(rows))
+        else:
+            for j in range(lane, lane + nl):
+                keys[f"lane{j}"] = lanes[rows, j]
+        lane += nl
+    for j in range(0 if order_lanes is None else order_lanes.shape[1]):
+        keys[f"order{j}"] = np.asarray(order_lanes)[rows, j]
+    keys["seq"] = seq[rows]
+    keys["arrival"] = rows
+    sort_keys = pa.table(keys)
+    return pc.sort_indices(
+        sort_keys, sort_keys=[(k, "ascending")
+                              for k in sort_keys.column_names]).to_numpy()
 
 
 class _LazyLanes:
@@ -1069,22 +1188,20 @@ def merge_runs(runs: Sequence[pa.Table], key_names: Sequence[str],
     if n == 0:
         return MergeResult(table, np.zeros(0, dtype=np.int64))
     # without changelog derivation the caller consumes only winner
-    # rows, so the packed-key fast path is admissible — unless any key
-    # was prefix-truncated: _refine_truncated needs the full path's
-    # seq-ordered segments with winners at segment boundaries
+    # rows, so the packed-key fast path is admissible — unless a key's
+    # lanes were cut: the tie-break reads the whole sorted order
     truncated = op.any_truncated
     perm, winner, prev = sorted_winners(
         op, winners_only=not with_prev and not truncated)
 
     with winners_span(n, op.route) as sp:
+        if truncated:
+            perm, winner, prev = _winner_epilogue(*tiebreak_cut_keys(
+                table, key_names, op.key_encoder, op.lanes, op.truncated,
+                perm, op.seq, op.order_lanes), keep)
         win_pos = np.flatnonzero(winner)
         indices = perm[win_pos].astype(np.int64)
         prev_idx = prev[win_pos].astype(np.int64) if with_prev else None
-
-        if truncated:
-            indices, prev_idx = _refine_truncated(
-                table, key_names, perm, winner, op.truncated, op.seq, keep,
-                with_prev, prev)
 
         if drop_deletes and KIND_COL in table.column_names:
             # cheap min/max scan beats materializing the kinds array when
@@ -1108,79 +1225,3 @@ def merge_runs(runs: Sequence[pa.Table], key_names: Sequence[str],
 
     return MergeResult(table, indices, prev_idx)
 
-
-def _refine_truncated(table: pa.Table, key_names, perm, winner, truncated,
-                      seq, keep: str, with_prev: bool, prev=None):
-    """Host fallback for prefix-truncated string keys: rows whose prefix
-    collided may belong to different real keys, so device segments can
-    over-group. Only the sorted spans that contain a truncated row are
-    re-grouped by full key on the host; all other winners keep the device
-    result. Rare path (keys longer than the prefix sharing a prefix)."""
-    n = len(seq)
-    winner = np.asarray(winner)
-    sorted_real_mask = perm < n
-    sorted_real = perm[sorted_real_mask]              # sorted positions
-    win_sorted = winner[sorted_real_mask]
-    s_trunc = truncated[sorted_real]
-
-    # segment spans in sorted order: a span ends at each winner/last-of-
-    # segment boundary for keep="last"; reconstruct spans via winner mask
-    # (device winners mark segment boundaries regardless of keep by
-    # construction when keep == "last"; for "first" they mark starts).
-    m = len(sorted_real)
-    if keep == "last":
-        seg_end = win_sorted.copy()
-        seg_end[-1] = True
-        seg_id = np.concatenate([[0], np.cumsum(seg_end[:-1])])
-    else:
-        seg_start = win_sorted.copy()
-        seg_start[0] = True
-        seg_id = np.cumsum(seg_start) - 1
-
-    # spans affected by truncation
-    affected_segs = set(np.unique(seg_id[s_trunc]).tolist())
-    if not affected_segs:
-        win_pos = np.flatnonzero(winner)
-        prev_idx = (np.asarray(prev)[win_pos].astype(np.int64)
-                    if with_prev and prev is not None else None)
-        return (perm[win_pos].astype(np.int64), prev_idx)
-
-    key_cols = [table.column(k) for k in key_names]
-
-    def full_key(i: int):
-        return tuple(c[int(i)].as_py() for c in key_cols)
-
-    idx_out: List[int] = []
-    prev_out: List[int] = []
-    i = 0
-    while i < m:
-        sid = seg_id[i]
-        j = i
-        while j < m and seg_id[j] == sid:
-            j += 1
-        span = sorted_real[i:j]
-        if sid not in affected_segs:
-            for p, w in zip(span, win_sorted[i:j]):
-                if w:
-                    idx_out.append(int(p))
-                    if with_prev:
-                        # predecessor within span
-                        pos = list(span).index(p)
-                        prev_out.append(int(span[pos - 1]) if pos > 0 else -1)
-        else:
-            # re-group by full key; span order is (prefix, seq) so within a
-            # real key rows remain seq-ordered
-            groups: dict = {}
-            for p in span:
-                groups.setdefault(full_key(p), []).append(int(p))
-            for k in sorted(groups):
-                g = groups[k]
-                if keep == "last":
-                    idx_out.append(g[-1])
-                    prev_out.append(g[-2] if len(g) > 1 else -1)
-                else:
-                    idx_out.append(g[0])
-                    prev_out.append(-1)
-        i = j
-    return (np.array(idx_out, dtype=np.int64),
-            np.array(prev_out, dtype=np.int64) if with_prev else None)

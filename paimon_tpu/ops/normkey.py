@@ -11,8 +11,10 @@ Encodings (all big-endian style, most-significant lane first):
 - floats: IEEE total order trick (negative -> flip all bits, else flip
   sign bit)
 - strings/bytes: first `prefix_bytes` bytes as big-endian lanes, zero
-  padded; a `truncated` flag marks rows whose key exceeded the prefix, so
-  callers can resolve rare prefix-equal ties on the host
+  padded; a `truncated` flag marks rows whose lanes do not determine the
+  value — longer than the prefix, or ending in a zero byte the padding
+  cannot tell from its own — so callers can resolve the rare
+  prefix-equal ties (ops/merge.py `tiebreak_cut_keys`)
 - date/time/timestamp: underlying ints
 
 Null ordering: nulls-last via a dedicated leading presence LANE per
@@ -207,6 +209,22 @@ class NormalizedKeyEncoder:
         return all(k in ("int", "float") for k in self._kinds)
 
     @property
+    def bytes_columns(self) -> List[int]:
+        """Positions of the string / binary key columns: the only ones
+        whose lanes can leave a key undetermined (`truncated`)."""
+        return [i for i, k in enumerate(self._kinds) if k == "bytes"]
+
+    @property
+    def cut_head_lanes(self) -> int:
+        """Lanes up to and including the last string / binary column's.
+        Ordered by these alone, rows are in the order of their full keys
+        with ties: a key whose lanes are cut sorts among its equals
+        here, and only the lanes after them can disagree with its full
+        bytes."""
+        cols = self.bytes_columns
+        return sum(self.lanes_per_col[:cols[-1] + 1]) if cols else 0
+
+    @property
     def packs_single_key(self) -> bool:
         """True when this encoder's keys pack into ONE u64 (single
         non-null fixed-width column — the hot pk shape): encode_*_ex
@@ -361,6 +379,14 @@ class NormalizedKeyEncoder:
         ends = offsets[1:]
         lengths = ends - starts
         truncated = lengths > pb
+        # b"ab" and b"ab\0" pad to the same lanes: the longer of two such
+        # keys ends in a zero byte, so marking those tells them apart
+        ends_in_zero = lengths > 0
+        if len(data):
+            ends_in_zero &= data[np.maximum(ends - 1, 0)] == 0
+        else:
+            ends_in_zero[:] = False
+        truncated |= ends_in_zero
         # gather first pb bytes of each value, zero-padded
         take = np.minimum(lengths, pb)
         padded = np.zeros((n, pb), dtype=np.uint8)

@@ -706,9 +706,9 @@ def _host_winners(op: M.MergeOperands, key_names) -> np.ndarray:
         op.packed, op.run_starts)
     with M.winners_span(op.n, "host"):
         if truncated:
-            return M._refine_truncated(
-                op.table, key_names, perm, winner, op.truncated, op.seq,
-                op.keep, False)[0]
+            perm, winner, _ = M._winner_epilogue(*M.tiebreak_cut_keys(
+                op.table, key_names, op.key_encoder, op.lanes,
+                op.truncated, perm, op.seq, op.order_lanes), op.keep)
         return perm[np.flatnonzero(winner)]
 
 

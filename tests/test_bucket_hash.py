@@ -1,8 +1,9 @@
 """The vectorized bucket hash against its scalar reference.
 
 `KeyHasher.hashes` (32-bit words read from the key columns' own values,
-mixed a block of rows at a time) must equal `KeyHasher._hash_rows` (the
-BinaryRow codec + `murmur_hash_bytes`, a row at a time) and
+or, for string and binary keys, gathered from the Arrow offsets and
+data; mixed a block of rows at a time) must equal `KeyHasher._hash_rows`
+(the BinaryRow codec + `murmur_hash_bytes`, a row at a time) and
 `bucket_of` in every row; `_bucket_from_hash` must equal Java's
 `Math.abs(h % n)` computed in Python ints.
 """
@@ -18,8 +19,9 @@ from paimon_tpu.core.bucket import (
     FixedBucketAssigner, KeyHasher, _bucket_from_hash, bucket_of,
 )
 from paimon_tpu.types import (
-    BigIntType, BooleanType, DateType, DoubleType, FloatType, IntType,
-    SmallIntType, TimeType, TinyIntType,
+    BigIntType, BooleanType, CharType, DateType, DoubleType, FloatType,
+    IntType, SmallIntType, TimeType, TinyIntType, VarBinaryType,
+    VarCharType,
 )
 
 BLOCK = 64                     # a small block: the sizes below cross it
@@ -66,6 +68,22 @@ TYPES = {"boolean": BooleanType, "tinyint": TinyIntType,
          "smallint": SmallIntType, "int": IntType, "bigint": BigIntType,
          "float": FloatType, "double": DoubleType, "date": DateType,
          "time": TimeType}
+# string and binary key types, as the hasher is given them
+VAR_TYPES = {"string": lambda: VarCharType(VarCharType.MAX_LENGTH),
+             "char": lambda: CharType(40),
+             "binary": lambda: VarBinaryType(VarBinaryType.MAX_LENGTH)}
+
+
+def _var_column(kind: str, lengths, rng) -> pa.Array:
+    """Strings (ASCII digits, "é" and "€" among them: 1-, 2- and 3-byte
+    UTF-8) or random bytes, zero bytes included, of the given lengths in
+    characters."""
+    if kind == "binary":
+        return pa.array([rng.integers(0, 256, k, dtype=np.uint8).tobytes()
+                         for k in lengths], pa.binary())
+    alphabet = np.array(list("0123456789user\x00é€"))
+    return pa.array(["".join(rng.choice(alphabet, k)) for k in lengths],
+                    pa.string())
 
 
 def _with_nulls(arr: pa.Array, nulls: str, rng) -> pa.Array:
@@ -78,7 +96,8 @@ def _with_nulls(arr: pa.Array, nulls: str, rng) -> pa.Array:
 
 
 def _assert_equal_to_reference(table: pa.Table, kinds):
-    hasher = KeyHasher(table.column_names, [TYPES[k]() for k in kinds])
+    hasher = KeyHasher(table.column_names,
+                       [(TYPES.get(k) or VAR_TYPES[k])() for k in kinds])
     fast = hasher.hashes(table)
     assert fast.dtype == np.uint32 and len(fast) == table.num_rows
     assert np.array_equal(fast, hasher._hash_rows(table))
@@ -157,6 +176,81 @@ def test_a_sliced_table_with_a_non_zero_offset(kind, nulls):
     assert np.array_equal(
         hasher.hashes(part),
         hasher.hashes(whole)[BLOCK // 2 + 3:][:BLOCK + 11])
+
+
+@pytest.mark.parametrize("length", list(range(0, 41)))
+@pytest.mark.parametrize("kind", ["string", "binary"])
+def test_one_string_key_of_every_length_to_40(kind, length):
+    """Inline slots (0-7 bytes), then a variable part of whole 8-byte
+    words: every length lands on its own side of each edge."""
+    rng = np.random.default_rng(length * 3 + len(kind))
+    col = _var_column(kind, [length] * (BLOCK + 9), rng)
+    _assert_equal_to_reference(pa.table({"k": col}), [kind])
+
+
+@pytest.mark.parametrize("nulls", ["none", "some", "all"])
+@pytest.mark.parametrize("kind", sorted(VAR_TYPES))
+def test_one_string_key_of_mixed_lengths(kind, nulls):
+    """Rows of several layouts in one batch (lengths 0-40, multi-byte
+    UTF-8 characters), nulls among them: each layout hashed apart."""
+    rng = np.random.default_rng(len(kind) * 7 + len(nulls))
+    col = _var_column("binary" if kind == "binary" else "string",
+                      rng.integers(0, 41, 3 * BLOCK + 5), rng)
+    _assert_equal_to_reference(pa.table({"k": _with_nulls(col, nulls, rng)}),
+                               [kind])
+
+
+@pytest.mark.parametrize("nulls", [("none", "none"), ("some", "none"),
+                                   ("none", "some"), ("some", "some")])
+@pytest.mark.parametrize("kinds", [
+    ("string", "bigint"), ("bigint", "string"), ("int", "binary"),
+    ("string", "string"), ("string", "binary", "smallint")])
+def test_string_keys_beside_other_columns(kinds, nulls):
+    """A (STRING, BIGINT) bucket key and its kin: a string's variable
+    part lies after every slot, the next string's after it."""
+    rng = np.random.default_rng(zlib.crc32(repr((kinds, nulls)).encode()))
+    n = 2 * BLOCK + 9
+    nulls = nulls + ("none",) * (len(kinds) - len(nulls))
+    cols = {}
+    for i, (kind, nl) in enumerate(zip(kinds, nulls)):
+        col = _var_column(kind, rng.integers(0, 30, n), rng) \
+            if kind in VAR_TYPES else _column(kind, n, rng)
+        cols[f"k{i}"] = _with_nulls(col, nl, rng)
+    _assert_equal_to_reference(pa.table(cols), list(kinds))
+
+
+def test_ycsb_keys_in_chunks_and_slices():
+    """YCSB's 18-23-byte "user" keys, a chunked column and a sliced one:
+    the offsets honoured, the last value read to the end of its data."""
+    rng = np.random.default_rng(23)
+    keys = pa.array([f"user{v}" for v in rng.integers(0, 1 << 63,
+                                                      3 * BLOCK)])
+    chunked = pa.table({"k": pa.chunked_array(
+        [keys.slice(0, 7), keys.slice(7, BLOCK), keys.slice(7 + BLOCK)])})
+    _assert_equal_to_reference(chunked, ["string"])
+    part = pa.table({"k": keys}).slice(BLOCK // 2 + 3, BLOCK + 11)
+    _assert_equal_to_reference(part, ["string"])
+    hasher = KeyHasher(["k"], [VAR_TYPES["string"]()])
+    assert hasher.vectorized(part.num_rows)
+    assert np.array_equal(hasher.hashes(part), hasher.hashes(
+        pa.table({"k": keys}))[BLOCK // 2 + 3:][:BLOCK + 11])
+
+
+@pytest.mark.parametrize("num_buckets", [1, 2, 7, 16])
+def test_the_assigner_buckets_string_keys_as_bucket_of(num_buckets):
+    rng = np.random.default_rng(num_buckets + 100)
+    n = BLOCK + 30
+    table = pa.table({"s": _var_column("string", rng.integers(0, 30, n),
+                                       rng),
+                      "b": _column("bigint", n, rng)})
+    types = [VarCharType(VarCharType.MAX_LENGTH, False), BigIntType(False)]
+    assigner = FixedBucketAssigner(["s", "b"], types, num_buckets)
+    got = assigner.assign(table)
+    s, b = table.column("s").to_pylist(), table.column("b").to_pylist()
+    assert got.tolist() == [bucket_of([s[i], b[i]], types, num_buckets)
+                            for i in range(n)]
+    assert assigner.hashed_rows(n) == ((0, 0) if num_buckets == 1
+                                       else (n, n))
 
 
 def test_the_real_block_size_gives_the_same_hashes(monkeypatch):

@@ -15,10 +15,13 @@ from paimon_tpu import obs
 from paimon_tpu.core import write as write_mod
 from paimon_tpu.core.bucket import bucket_of
 from paimon_tpu.core.write import group_by_partition_bucket
-from paimon_tpu.metrics import WRITE_ROUTE_NOCOPY_ROWS, global_registry
+from paimon_tpu.metrics import (
+    WRITE_HASH_ROWS, WRITE_HASH_VECTOR_ROWS, WRITE_ROUTE_NOCOPY_ROWS,
+    global_registry,
+)
 from paimon_tpu.schema import Schema
 from paimon_tpu.table import FileStoreTable
-from paimon_tpu.types import BigIntType, DoubleType, RowKind
+from paimon_tpu.types import BigIntType, DoubleType, RowKind, VarCharType
 from tests.store_oracle import StoreOracle
 
 
@@ -200,3 +203,44 @@ def test_the_span_and_the_counter_say_whether_the_batch_was_copied(
     assert attrs["copied_rows"] == (n if copied else 0)
     assert attrs["groups"] == (8 if copied else 1)
     assert _nocopy_rows() - before == (0 if copied else n)
+
+
+def _hash_counts():
+    group = global_registry().write_metrics()
+    return (group.counter(WRITE_HASH_ROWS).count,
+            group.counter(WRITE_HASH_VECTOR_ROWS).count)
+
+
+@pytest.mark.parametrize("key_type,num_buckets", [
+    (BigIntType(False), 1), (BigIntType(False), 8),
+    (VarCharType(False, 100), 8)])
+def test_the_hash_span_and_counters_say_what_was_hashed(
+        tmp_path, key_type, num_buckets):
+    """One bucket hashes nothing: no `write.hash` leaf inside the route
+    and nothing counted; more buckets hash every row, a BIGINT or a
+    string key alike on a vectorised path."""
+    from paimon_tpu.obs.trace import metrics_enabled
+    if not metrics_enabled():
+        pytest.skip("metrics are off in this process")
+    table = FileStoreTable.create(
+        str(tmp_path / "t"), Schema.builder()
+        .column("id", key_type).column("v", DoubleType())
+        .primary_key("id").options({"bucket": str(num_buckets),
+                                    "write-only": "true"}).build())
+    n = 300
+    ids = pa.array(np.arange(n), pa.int64())
+    if not isinstance(key_type, BigIntType):
+        ids = ids.cast(pa.string())
+    batch = pa.table({"id": ids, "v": pa.array(np.zeros(n))})
+    obs.enable_tracing(max_spans=10_000)
+    before = _hash_counts()
+    wb = table.new_batch_write_builder()
+    with wb.new_write() as w:
+        w.write_arrow(batch)
+        wb.new_commit().commit(w.prepare_commit())
+    hashes = [s for s in obs.take_spans() if s.name == "write.hash"]
+    hashed = 0 if num_buckets == 1 else n
+    assert [s.attrs["rows"] for s in hashes] == ([n] if hashed else [])
+    assert tuple(a - b for a, b in zip(_hash_counts(), before)) \
+        == (hashed, hashed)
+    assert table.to_arrow().num_rows == n
