@@ -28,7 +28,7 @@ from paimon_tpu.fs import FileIO
 from paimon_tpu.manifest import DataFileMeta, SimpleStats
 from paimon_tpu.options import CoreOptions, MergeEngine
 from paimon_tpu.ops.merge import (
-    KIND_COL, SEQ_COL, gather, merge_runs, sort_table,
+    KIND_COL, SEQ_COL, gather_columns, merge_runs, sort_table,
 )
 from paimon_tpu.schema.table_schema import TableSchema
 from paimon_tpu.types import RowKind
@@ -112,28 +112,210 @@ def group_by_partition_bucket(table: pa.Table, buckets: np.ndarray,
     return out
 
 
+def _offset_width(t: pa.DataType) -> int:
+    """Bytes of a string or binary type's offset; 0 for any other."""
+    if pa.types.is_string(t) or pa.types.is_binary(t):
+        return 4
+    if pa.types.is_large_string(t) or pa.types.is_large_binary(t):
+        return 8
+    return 0
+
+
+def _add_value_ends(ends: np.ndarray, col: pa.ChunkedArray) -> None:
+    """Add a string or binary column's bytes before each row to `ends`
+    (int64[n + 1]; only its differences are read): the offsets where
+    no null holds bytes, else the running sum of the non-null values'
+    lengths (a take copies no null's bytes)."""
+    dtype = np.int64 if _offset_width(col.type) == 8 else np.int32
+    chunks = [chunk for chunk in col.chunks if len(chunk)]
+    row, base = 0, 0
+    for chunk in chunks:
+        n = len(chunk)
+        offsets = np.frombuffer(chunk.buffers()[1], dtype=dtype)[
+            chunk.offset:chunk.offset + n + 1]
+        local = None
+        if chunk.null_count:
+            lengths = np.diff(offsets)
+            bit = chunk.offset % 8
+            valid = np.unpackbits(
+                np.frombuffer(chunk.buffers()[0], dtype=np.uint8)[
+                    chunk.offset // 8:], count=bit + n,
+                bitorder="little")[bit:].astype(dtype)
+            if int(np.dot(lengths, valid)) != \
+                    int(offsets[-1]) - int(offsets[0]):
+                local = np.cumsum(lengths * valid, dtype=np.int64)
+        if local is None:
+            if len(chunks) == 1:
+                np.add(ends, offsets, out=ends)
+                return
+            local = offsets[1:].astype(np.int64) - int(offsets[0])
+        seg = ends[row + 1:row + n + 1]
+        seg += local
+        seg += base
+        base += int(local[-1])
+        row += n
+
+
+def taken_nbytes(table: pa.Table,
+                 selections: Sequence[np.ndarray]) -> Optional[List[int]]:
+    """`table.take(idx).nbytes` of each of `selections`, read off the
+    columns' validity, widths and offsets without the take.  A take
+    writes a validity bitmap where the column holds a null, and always
+    for a string or binary column; a fixed-width value's width a row; a
+    string or binary value's offset and its bytes (a null's none).
+    None where a column is of another type."""
+    rows = np.array([len(idx) for idx in selections], dtype=np.int64)
+    bitmap = (rows + 7) // 8
+    total = np.zeros(len(selections), dtype=np.int64)
+    ends = None
+    for col in table.columns:
+        t = col.type
+        if _offset_width(t):
+            total += bitmap + rows * _offset_width(t)
+            if ends is None:
+                ends = np.zeros(table.num_rows + 1, dtype=np.int64)
+            _add_value_ends(ends, col)
+        elif (pa.types.is_boolean(t) or pa.types.is_integer(t)
+              or pa.types.is_floating(t) or pa.types.is_decimal(t)
+              or pa.types.is_timestamp(t) or pa.types.is_date(t)
+              or pa.types.is_time(t) or pa.types.is_duration(t)
+              or pa.types.is_fixed_size_binary(t)):
+            total += (rows * t.bit_width + 7) // 8
+            if col.null_count:
+                total += bitmap
+        else:
+            return None
+    if ends is not None:
+        row_bytes = np.diff(ends)
+        total += [int(row_bytes[idx].sum()) for idx in selections]
+    return total.tolist()
+
+
+def _kv_head(keys: pa.Table, schema: TableSchema, seq: np.ndarray,
+             kinds: np.ndarray) -> pa.Table:
+    """The KV layout's leading columns: _KEY_<pk...>, _SEQUENCE_NUMBER,
+    _VALUE_KIND; `keys` holds the trimmed primary key columns."""
+    cols = {KEY_PREFIX + k: keys.column(k)
+            for k in schema.trimmed_primary_keys()}
+    cols[SEQ_COL] = pa.array(seq, pa.int64())
+    cols[KIND_COL] = pa.array(kinds, pa.int8())
+    return pa.table(cols)
+
+
+def _joined(*tables: pa.Table) -> pa.Table:
+    """The tables' columns side by side, in turn, as one table."""
+    cols = {}
+    for t in tables:
+        cols.update(zip(t.column_names, t.columns))
+    return pa.table(cols)
+
+
+def _build_span(rows: int):
+    from paimon_tpu.metrics import WRITE_BUILD_MS
+    from paimon_tpu.obs.trace import span
+    return span("write.build", cat="write", group="write",
+                metric=WRITE_BUILD_MS, rows=rows)
+
+
 def build_kv_table(raw: pa.Table, schema: TableSchema,
                    seq: np.ndarray, kinds: np.ndarray) -> pa.Table:
     """Flatten rows into the KV file layout:
     _KEY_<pk...>, _SEQUENCE_NUMBER, _VALUE_KIND, <all value columns>.
     A leaf span, `write.build`: callers open none around it alone."""
-    from paimon_tpu.metrics import WRITE_BUILD_MS
+    with _build_span(raw.num_rows):
+        return _joined(_kv_head(raw, schema, seq, kinds),
+                       raw.select([f.name for f in schema.fields]))
+
+
+def _take_span(rows: int):
+    """`write.take`: rows taken on the route's side of the write — the
+    key columns and kinds of a batch of several groups, or a selection
+    taken whole before its flush."""
+    from paimon_tpu.metrics import WRITE_TAKE_MS
     from paimon_tpu.obs.trace import span
-    with span("write.build", cat="write", group="write",
-              metric=WRITE_BUILD_MS, rows=raw.num_rows):
-        cols = []
-        names = []
-        for k in schema.trimmed_primary_keys():
-            cols.append(raw.column(k))
-            names.append(KEY_PREFIX + k)
-        cols.append(pa.array(seq, pa.int64()))
-        names.append(SEQ_COL)
-        cols.append(pa.array(kinds, pa.int8()))
-        names.append(KIND_COL)
-        for f in schema.fields:
-            cols.append(raw.column(f.name))
-            names.append(f.name)
-        return pa.table(dict(zip(names, cols)))
+    return span("write.take", cat="write", group="write",
+                metric=WRITE_TAKE_MS, rows=rows)
+
+
+class _Rows:
+    """One batch's rows in a bucket's buffer.  Whole (`idx` None): the
+    rows are `table`, as the caller handed them on.  A selection: the
+    route took the key columns (`keys`) at `idx`, and the value columns
+    stay in the caller's `table` until the flush gathers them by the
+    composed index, or the writer takes them early (`_materialise`).
+    `nbytes` is what `table.take(idx)` holds; `pin` is the writer's
+    record of `table` while the selection is buffered."""
+    __slots__ = ("table", "keys", "idx", "nbytes", "pin")
+
+    def __init__(self, table: pa.Table, keys: Optional[pa.Table] = None,
+                 idx: Optional[np.ndarray] = None,
+                 nbytes: Optional[int] = None):
+        self.table, self.keys, self.idx = table, keys, idx
+        self.nbytes = table.nbytes if nbytes is None else nbytes
+        self.pin = None
+
+    @property
+    def num_rows(self) -> int:
+        return self.table.num_rows if self.idx is None else len(self.idx)
+
+
+class _Pin:
+    """A caller's batch that buffered selections read: its bytes, and
+    those selections (by id, in the order they were routed)."""
+    __slots__ = ("table", "nbytes", "rows")
+
+    def __init__(self, table: pa.Table):
+        self.table, self.nbytes, self.rows = table, table.nbytes, {}
+
+
+@dataclass
+class _Payload:
+    """A bucket's buffer detached for one flush: each batch's
+    (table, keys, idx) as `_Rows` holds them, with the kinds and the
+    sequence numbers, and the bytes the buffer accounted."""
+    parts: List[Tuple[pa.Table, Optional[pa.Table], Optional[np.ndarray]]]
+    kinds: np.ndarray
+    seq: np.ndarray
+    nbytes: int
+
+    @property
+    def num_rows(self) -> int:
+        return len(self.kinds)
+
+    def keys(self, key_names: Sequence[str]) -> pa.Table:
+        """The key columns, in arrival order (zero-copy)."""
+        return pa.concat_tables(
+            [t.select(key_names) if idx is None else keys
+             for t, keys, idx in self.parts], promote_options="none")
+
+    def arrival(self, fields: Sequence[str]) -> pa.Table:
+        """The value columns in arrival order: a whole batch as it is,
+        a selection taken once, as the route once took it."""
+        return pa.concat_tables(
+            [t.select(fields) if idx is None
+             else t.select(fields).take(pa.array(idx))
+             for t, _, idx in self.parts], promote_options="none")
+
+    def sources(self, fields: Sequence[str]
+                ) -> Tuple[pa.Table, Optional[np.ndarray], int]:
+        """(values, index, deferred): the value columns of the distinct
+        batches, each once, in one zero-copy table; the position there
+        of each buffered row, None where that is the row's own; and
+        the rows that are selections."""
+        start, tables, rows = {}, [], 0
+        for t, _, _ in self.parts:
+            if id(t) not in start:
+                start[id(t)] = rows
+                tables.append(t.select(fields))
+                rows += t.num_rows
+        values = pa.concat_tables(tables, promote_options="none")
+        deferred = sum(len(idx) for _, _, idx in self.parts
+                       if idx is not None)
+        if rows == self.num_rows and not deferred:
+            return values, None, 0
+        return values, np.concatenate(
+            [start[id(t)] + (np.arange(t.num_rows) if idx is None else idx)
+             for t, _, idx in self.parts]), deferred
 
 
 def _buffer_span(rows: int):
@@ -189,13 +371,16 @@ class _BucketWriter:
         """Flush-cost estimate for LPT scheduling (buffered + spilled)."""
         return self.buffered_bytes + self._spill_bytes
 
-    def write(self, table: pa.Table, kinds: np.ndarray):
-        with _buffer_span(table.num_rows):
-            self.buffers.append(table)
+    def write(self, rows: _Rows, kinds: np.ndarray):
+        if self.parent.delta_listener is not None and rows.idx is not None:
+            # the listener reads the rows themselves
+            self.parent._materialise(rows)
+        with _buffer_span(rows.num_rows):
+            self.buffers.append(rows)
             self.kind_buffers.append(kinds)
             # sequence numbers are reserved HERE, on the single-threaded
             # caller, never inside a pooled flush task
-            seqs = self._assign_seq(table.num_rows)
+            seqs = self._assign_seq(rows.num_rows)
             self.seq_buffers.append(seqs)
         if self.parent.delta_listener is not None:
             # serving-plane hot delta tier (service/delta.py): the
@@ -203,8 +388,8 @@ class _BucketWriter:
             # buffered — AFTER sequence reservation, so delta
             # newest-wins order is exactly flush order
             self.parent.delta_listener(self.partition, self.bucket,
-                                       table, kinds, seqs)
-        self.buffered_bytes += table.nbytes
+                                       rows.table, kinds, seqs)
+        self.buffered_bytes += rows.nbytes
         opts = self.parent.options
         if self.parent.spillable:
             # sorted runs spill at sort-spill-buffer-size cadence,
@@ -247,60 +432,82 @@ class _BucketWriter:
         self.next_seq = start + n
         return np.arange(start, start + n, dtype=np.int64)
 
-    def _snapshot(self):
+    def _snapshot(self) -> Optional[_Payload]:
         """Detach the in-RAM buffer into an immutable flush payload
-        (caller thread): (raw, kinds, seq) or None.  `pa.concat_tables`
-        is zero-copy, so the snapshot is cheap; the expensive
-        sort/encode happens in the pooled task that receives it."""
+        (caller thread), or None.  Nothing is copied: the flush task
+        that receives it composes the rows (`_Payload`), sorts and
+        encodes them; the selections leave the writer's pins here."""
         if not self.buffers:
             return None
         with _buffer_span(sum(len(k) for k in self.kind_buffers)):
-            raw = pa.concat_tables(self.buffers, promote_options="none")
-            kinds = np.concatenate(self.kind_buffers)
-            seq = np.concatenate(self.seq_buffers)
+            for rows in self.buffers:
+                if rows.pin is not None:
+                    self.parent._unpin(rows)
+            snap = _Payload([(r.table, r.keys, r.idx) for r in self.buffers],
+                            np.concatenate(self.kind_buffers),
+                            np.concatenate(self.seq_buffers),
+                            self.buffered_bytes)
             self.buffers, self.kind_buffers, self.seq_buffers = [], [], []
             self.buffered_bytes = 0
-        return raw, kinds, seq
+        return snap
 
-    def _sorted_chunk(self, snap) -> Tuple[Optional[pa.Table],
-                                           List[DataFileMeta]]:
+    def _sorted_chunk(self, snap: Optional[_Payload]
+                      ) -> Tuple[Optional[pa.Table], List[DataFileMeta]]:
         """Sort/merge one flush payload into a key-sorted KV chunk and
         write its changelog-producer=input file (arrival order).
+        The sort reads the key columns, the sequence numbers and the
+        kinds; then one gather takes those by the sort's order and each
+        value column from the caller's batches by the composed index,
+        every value byte copied once.
         Worker-side and retry-safe: nothing on `self` is mutated —
         returns (sorted_kv, changelog_metas) for the caller to publish
         after the whole task succeeded."""
         if snap is None:
             return None, []
-        raw, kinds, seq = snap
-
         schema = self.parent.schema
-        from paimon_tpu.metrics import WRITE_SORT_MS
-        from paimon_tpu.obs.trace import span
+        key_names = schema.trimmed_primary_keys()
+        fields = [f.name for f in schema.fields]
+        from paimon_tpu.metrics import (
+            WRITE_DEFERRED_GATHER_ROWS, WRITE_SORT_MS, global_registry,
+        )
+        from paimon_tpu.obs.trace import metrics_enabled, span
         with span("write.sort", cat="write", group="write",
                   metric=WRITE_SORT_MS, partition=self.partition,
-                  bucket=self.bucket, rows=raw.num_rows):
-            kv = build_kv_table(raw, schema, seq, kinds)
-            key_cols = [KEY_PREFIX + k
-                        for k in schema.trimmed_primary_keys()]
+                  bucket=self.bucket, rows=snap.num_rows):
+            with _build_span(snap.num_rows):
+                head = _kv_head(snap.keys(key_names), schema, snap.seq,
+                                snap.kinds)
+                values, index, deferred = snap.sources(fields)
+            key_cols = [KEY_PREFIX + k for k in key_names]
             engine = self.parent.options.merge_engine
             if engine in (MergeEngine.DEDUPLICATE, MergeEngine.FIRST_ROW):
-                res = merge_runs([kv], key_cols, merge_engine=engine,
-                                 drop_deletes=False,
-                                 key_encoder=self.parent.key_encoder,
-                                 seq_fields=self.parent.options
-                                 .sequence_field or None,
-                                 seq_desc=self.parent.options
-                                 .sequence_field_descending)
-                sorted_kv = res.take()
+                seq_fields = self.parent.options.sequence_field or None
+                sort_in = head
+                if seq_fields:
+                    ordering = values.select(seq_fields)
+                    sort_in = _joined(head, ordering if index is None else
+                                      ordering.take(pa.array(index)))
+                order = merge_runs([sort_in], key_cols, merge_engine=engine,
+                                   drop_deletes=False,
+                                   key_encoder=self.parent.key_encoder,
+                                   seq_fields=seq_fields,
+                                   seq_desc=self.parent.options
+                                   .sequence_field_descending).indices
             else:
-                order = sort_table(kv, key_cols,
+                order = sort_table(head, key_cols,
                                    key_encoder=self.parent.key_encoder)
-                sorted_kv = gather(kv, order)
+            sorted_kv = gather_columns(
+                [(head, order),
+                 (values, order if index is None else index[order])])
+            if deferred and metrics_enabled():
+                global_registry().write_metrics().counter(
+                    WRITE_DEFERRED_GATHER_ROWS).inc(deferred)
 
         changelog: List[DataFileMeta] = []
         if self.parent.changelog_input:
             # changelog-producer=input: raw rows in arrival order
-            cl = build_kv_table(raw, schema, seq, kinds)
+            with _build_span(snap.num_rows):
+                cl = _joined(head, snap.arrival(fields))
             changelog = self.parent.write_changelog(
                 self.partition, self.bucket, cl)
         return sorted_kv, changelog
@@ -322,7 +529,7 @@ class _BucketWriter:
             self.new_files.extend(metas)
             self.changelog_files.extend(changelog)
 
-        self.parent.flush_pool().submit(self._key, snap[0].nbytes, task)
+        self.parent.flush_pool().submit(self._key, snap.nbytes, task)
 
     # -- spillable buffer (reference SortBufferWriteBuffer:59 spill via
     # MergeSorter/BinaryExternalSortBuffer: full buffers become local
@@ -371,7 +578,7 @@ class _BucketWriter:
         if snap is None:
             return
         self._spills_scheduled += 1
-        payload = snap[0].nbytes
+        payload = snap.nbytes
         self._spill_sched_bytes += payload
 
         def spill_task(snap=snap):
@@ -389,7 +596,7 @@ class _BucketWriter:
                 self._fold_spills(max_handles)
 
         pool = self.parent.flush_pool()
-        pool.submit(self._key, snap[0].nbytes, spill_task)
+        pool.submit(self._key, payload, spill_task)
         pool.submit(self._key, 1, fold_task)
 
     def _fold_spills(self, max_handles: int):
@@ -559,7 +766,7 @@ class _BucketWriter:
                     self.new_files.extend(metas)
                     self.changelog_files.extend(changelog)
 
-        est = (snap[0].nbytes if snap is not None else 0) + \
+        est = (snap.nbytes if snap is not None else 0) + \
             self._spill_bytes
         self.parent.flush_pool().submit(self._key, est, task)
 
@@ -731,6 +938,11 @@ class KeyValueFileStoreWrite:
             nullable=[rt.get_field(k).type.nullable
                       for k in table_schema.trimmed_primary_keys()])
         self._writers: Dict[Tuple, _BucketWriter] = {}
+        self._key_names = table_schema.trimmed_primary_keys()
+        # the caller's batches that buffered selections read, oldest
+        # first (caller thread only), and their bytes
+        self._pins: Dict[int, _Pin] = {}
+        self._pinned_bytes = 0
         # serving-plane hook (service/delta.py ServingWriter): called
         # with (partition, bucket, table, kinds, seqs) for every
         # buffered batch, on the single-threaded caller
@@ -814,7 +1026,8 @@ class KeyValueFileStoreWrite:
                     group_by_partition_bucket(
                         table, buckets, self.partition_keys)):
                 sub = table.take(pa.array(idx))
-                self._writer(part, bucket).write(sub, row_kinds[idx])
+                self._writer(part, bucket).write(_Rows(sub),
+                                                 row_kinds[idx])
             return
         if self._dynamic is not None:
             # partition-first grouping: bucket assignment depends on the
@@ -830,7 +1043,7 @@ class KeyValueFileStoreWrite:
                 for (_, bucket), idx2 in lpt_order(
                         group_by_partition_bucket(sub, buckets, [])):
                     self._writer(part, bucket).write(
-                        sub.take(pa.array(idx2)), sub_kinds[idx2])
+                        _Rows(sub.take(pa.array(idx2))), sub_kinds[idx2])
             return
 
         # fixed-bucket hot path: the hash/group-by/take is a PURE
@@ -838,6 +1051,9 @@ class KeyValueFileStoreWrite:
         # previous batch routes — the "incoming batch's hash overlaps
         # bucket flushes" leg of the pipeline.  Routing (and therefore
         # sequence reservation) stays on this thread, in batch order.
+        # A batch of several groups hands each writer a selection: the
+        # key columns and kinds taken, the value columns left in the
+        # batch for the flush's gather (`_Rows`).
         # The kinds are copied here, on the caller: prep may run after
         # write_arrow has returned and the caller reuses its array.
         def prep(table=table, kinds=row_kinds.copy(),
@@ -868,10 +1084,10 @@ class KeyValueFileStoreWrite:
                 if len(groups) == 1:
                     # the group is the batch: no take (an Arrow table
                     # is immutable, the writer may keep the caller's)
-                    out, copied = [(groups[0][0], table, kinds)], 0
+                    out, copied = [(groups[0][0], _Rows(table), kinds)], 0
                 else:
-                    out = [(key, table.take(pa.array(idx)), kinds[idx])
-                           for key, idx in groups]
+                    with _take_span(table.num_rows):
+                        out = self._select(table, kinds, groups)
                     copied = table.num_rows
                 sp.set(groups=len(groups), copied_rows=copied)
                 if metrics_enabled():
@@ -888,8 +1104,8 @@ class KeyValueFileStoreWrite:
         from paimon_tpu.obs.trace import carry
         self._prep.append(pool.submit(carry(prep)))
         # bounded lookahead: at most 4 batches prepped ahead (each holds
-        # its batch, and a copy of it when it fell into several groups),
-        # routed strictly in submission order
+        # its batch, and the key columns of its groups when it fell
+        # into several), routed strictly in submission order
         while len(self._prep) > 4:
             self._route(wait_future(self._prep.popleft(),
                                     "write prep backpressure"))
@@ -897,9 +1113,68 @@ class KeyValueFileStoreWrite:
             self._route(wait_future(self._prep.popleft(),
                                     "write prep drain"))
 
+    def _select(self, table: pa.Table, kinds: np.ndarray, groups):
+        """Each group of a batch as a selection of it: the key columns
+        and kinds taken at the group's rows, the bytes its take would
+        hold.  Taken whole where a column's bytes cannot be read off
+        its buffers (`taken_nbytes`)."""
+        sizes = taken_nbytes(table, [idx for _, idx in groups])
+        if sizes is None:
+            return [(key, _Rows(table.take(pa.array(idx))), kinds[idx])
+                    for key, idx in groups]
+        keys = table.select(self._key_names)
+        return [(key, _Rows(table, keys.take(pa.array(idx)), idx, nbytes),
+                 kinds[idx]) for (key, idx), nbytes in zip(groups, sizes)]
+
     def _route(self, groups):
-        for (part, bucket), sub, kinds in groups:
-            self._writer(part, bucket).write(sub, kinds)
+        newest = 0
+        for (part, bucket), rows, kinds in groups:
+            newest = self._pin(rows) if rows.idx is not None \
+                else rows.nbytes
+            self._writer(part, bucket).write(rows, kinds)
+        if self._pins:
+            self._bound_pins(newest)
+
+    # -- selections: the caller's batches they pin ---------------------------
+
+    def _pin(self, rows: _Rows) -> int:
+        """Record that a buffered selection reads its batch; the
+        batch's bytes."""
+        pin = self._pins.get(id(rows.table))
+        if pin is None:
+            pin = self._pins[id(rows.table)] = _Pin(rows.table)
+            self._pinned_bytes += pin.nbytes
+        pin.rows[id(rows)] = rows
+        rows.pin = pin
+        return pin.nbytes
+
+    def _unpin(self, rows: _Rows):
+        """The selection left its buffer (a flush payload holds it now)
+        or was taken: a batch no buffered selection reads is let go."""
+        pin, rows.pin = rows.pin, None
+        del pin.rows[id(rows)]
+        if not pin.rows:
+            del self._pins[id(pin.table)]
+            self._pinned_bytes -= pin.nbytes
+
+    def _materialise(self, rows: _Rows):
+        """Take a selection's rows out of the caller's batch now, as
+        the route once did; its accounted bytes are the take's."""
+        with _take_span(len(rows.idx)):
+            taken = rows.table.take(pa.array(rows.idx))
+        if rows.pin is not None:
+            self._unpin(rows)
+        rows.table, rows.keys, rows.idx = taken, None, None
+
+    def _bound_pins(self, newest: int):
+        """Hold the batches that buffered selections pin to the bytes
+        the buffers account plus the newest batch: past that, the
+        oldest batch's selections are taken whole."""
+        limit = newest + sum(w.buffered_bytes for w in self._writers.values())
+        while self._pinned_bytes > limit:
+            oldest = next(iter(self._pins.values()))
+            for rows in list(oldest.rows.values()):
+                self._materialise(rows)
 
     def _drain_prep(self):
         while self._prep:
@@ -1016,3 +1291,5 @@ class KeyValueFileStoreWrite:
         for w in self._writers.values():
             w._drop_spills()         # aborted writes must not leak /tmp
         self._writers.clear()
+        self._pins.clear()
+        self._pinned_bytes = 0

@@ -45,6 +45,7 @@ __all__ = ["Counter", "Gauge", "Histogram", "MetricGroup",
            "MERGE_DEVICE_ROWS", "MERGE_TIEBREAK_MS", "MERGE_TIEBREAK_ROWS",
            "MERGE_TIEBREAK_RESORTED_ROWS",
            "WRITE_HASH_MS", "WRITE_HASH_ROWS", "WRITE_HASH_VECTOR_ROWS",
+           "WRITE_TAKE_MS", "WRITE_DEFERRED_GATHER_ROWS",
            "SCAN_AGG_BELOW_ROWS",
            "SCAN_ROWS_IN", "SCAN_RAW_ROWS",
            "STREAM_EVENTS_INGESTED", "STREAM_CHECKPOINTS",
@@ -185,6 +186,12 @@ WRITE_HASH_ROWS = "hash_rows"               # counter: rows the route hashed
 WRITE_HASH_VECTOR_ROWS = "hash_vector_rows"  # counter: those hashed by the
                                             # vectorised path, not a row
                                             # at a time
+WRITE_TAKE_MS = "take_ms"                   # write: the route's takes of the
+                                            # key columns and kinds, and a
+                                            # selection taken before its flush
+WRITE_DEFERRED_GATHER_ROWS = "deferred_gather_rows"  # counter: rows a
+                                            # flush gathered straight from
+                                            # the caller's batch
 # merge metric group: the stages of one sorted-run merge, whoever
 # called it (scan split, flush sort, compaction window) — producers
 # in ops/merge.py, ops/agg.py and compact/manager.py

@@ -125,6 +125,22 @@ def gather(table: pa.Table, indices) -> pa.Table:
                      lambda: table.take(indices))
 
 
+def gather_columns(parts: Sequence[Tuple[pa.Table, np.ndarray]]
+                   ) -> pa.Table:
+    """`merge.gather` of tables laid side by side: each part's rows at
+    its own positions (one length for all), its columns in turn, as one
+    table — rows whose key columns and value columns live in different
+    tables.  The bytes counted are the buffers of the table taken."""
+    def take():
+        cols = {}
+        for table, indices in parts:
+            taken = table.take(pa.array(indices))
+            cols.update(zip(taken.column_names, taken.columns))
+        return pa.table(cols)
+    return _gathered(len(parts[0][1]),
+                     sum(table.num_columns for table, _ in parts), take)
+
+
 def gather_values(values: np.ndarray, indices: np.ndarray) -> np.ndarray:
     """`merge.gather` of one column held as a numpy array (a column's
     values or its validity): the view of it that a reduction over the

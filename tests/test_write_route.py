@@ -2,9 +2,11 @@
 
 `group_by_partition_bucket` against a dict-of-lists reference; the
 fixed-bucket dispatch hands a one-group batch on without a copy (and
-without aliasing the caller's kinds), copies a many-group batch, says
-which in the `write.route` span and the `write` registry group, and
-commits the same rows to the same buckets either way.
+without aliasing the caller's kinds), hands a many-group batch's writers
+its key columns taken and its value columns in place (a `write.take`
+leaf, gathered by the flushes), says which in the `write.route` span and
+the `write` registry group, and commits the same rows to the same
+buckets either way.
 """
 
 import numpy as np
@@ -16,9 +18,10 @@ from paimon_tpu.core import write as write_mod
 from paimon_tpu.core.bucket import bucket_of
 from paimon_tpu.core.write import group_by_partition_bucket
 from paimon_tpu.metrics import (
-    WRITE_HASH_ROWS, WRITE_HASH_VECTOR_ROWS, WRITE_ROUTE_NOCOPY_ROWS,
-    global_registry,
+    WRITE_DEFERRED_GATHER_ROWS, WRITE_HASH_ROWS, WRITE_HASH_VECTOR_ROWS,
+    WRITE_ROUTE_NOCOPY_ROWS, global_registry,
 )
+from paimon_tpu.obs.trace import metrics_enabled
 from paimon_tpu.schema import Schema
 from paimon_tpu.table import FileStoreTable
 from paimon_tpu.types import BigIntType, DoubleType, RowKind, VarCharType
@@ -135,6 +138,11 @@ def _nocopy_rows():
         .counter(WRITE_ROUTE_NOCOPY_ROWS).count
 
 
+def _deferred_rows():
+    return global_registry().write_metrics() \
+        .counter(WRITE_DEFERRED_GATHER_ROWS).count
+
+
 @pytest.mark.parametrize("parallelism", ["1", "4"])
 def test_one_bucket_slice_with_reused_kinds_commits_what_it_was_given(
         tmp_path, parallelism):
@@ -191,18 +199,27 @@ def test_the_span_and_the_counter_say_whether_the_batch_was_copied(
     batch = pa.table({"id": pa.array(np.arange(n), pa.int64()),
                       "v": pa.array(np.zeros(n))})
     obs.enable_tracing(max_spans=10_000)
-    before = _nocopy_rows()
+    before, deferred = _nocopy_rows(), _deferred_rows()
     wb = table.new_batch_write_builder()
     with wb.new_write() as w:
         w.write_arrow(batch)
         wb.new_commit().commit(w.prepare_commit())
-    routes = [s for s in obs.take_spans() if s.name == "write.route"]
+    spans = obs.take_spans()
+    routes = [s for s in spans if s.name == "write.route"]
     assert len(routes) == 1
     attrs = routes[0].attrs
     assert attrs["rows"] == n
     assert attrs["copied_rows"] == (n if copied else 0)
     assert attrs["groups"] == (8 if copied else 1)
     assert _nocopy_rows() - before == (0 if copied else n)
+    # several groups: the key columns and kinds taken in a leaf under
+    # the route, the value columns gathered by the flushes from the batch
+    takes = [s for s in spans if s.name == "write.take"]
+    assert [s.parent_id for s in takes] == \
+        ([routes[0].span_id] if copied else [])
+    assert not any(s.parent_id == t.span_id for t in takes for s in spans)
+    if metrics_enabled():
+        assert _deferred_rows() - deferred == (n if copied else 0)
 
 
 def _hash_counts():
