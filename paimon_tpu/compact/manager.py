@@ -27,7 +27,9 @@ from paimon_tpu.core.read import assemble_runs
 from paimon_tpu.fs import FileIO
 from paimon_tpu.manifest import DataFileMeta, FileSource
 from paimon_tpu.options import CoreOptions, MergeEngine
-from paimon_tpu.metrics import COMPACTION_DURATION_MS, global_registry
+from paimon_tpu.metrics import (
+    COMPACTION_DURATION_MS, LOOKUP_CHANGELOG_MS, global_registry,
+)
 from paimon_tpu.obs.trace import carry, span
 from paimon_tpu.ops.merge import merge_runs, prep_span
 from paimon_tpu.utils.deadline import check_deadline, wait_future
@@ -124,7 +126,12 @@ class MergeTreeCompactManager:
     def __init__(self, file_io: FileIO, table_path: str,
                  schema: TableSchema, options: CoreOptions,
                  partition: Tuple, bucket: int,
-                 files: List[DataFileMeta], schema_manager=None):
+                 files: List[DataFileMeta], schema_manager=None,
+                 levels_index=None):
+        """`levels_index`: the bucket writer's `lookup/levels_index.py`
+        index, kept across commits and brought up to date by every
+        compaction of this manager; without one, the lookup changelog
+        producer builds one for this manager alone."""
         self.file_io = file_io
         self.schema = schema
         self.options = options
@@ -134,6 +141,10 @@ class MergeTreeCompactManager:
         self._schema_cache = {schema.id: schema}
         self._file_cache: dict = {}
         self.levels = Levels(files, options.num_levels)
+        self.levels_index = levels_index
+        self._index_owned = levels_index is not None
+        self._index_synced = False
+        self._output: Optional[pa.Table] = None    # the rewrite's rows
         self.strategy = UniversalCompaction(
             max_size_amp=options.max_size_amplification_percent,
             size_ratio=options.size_ratio,
@@ -172,14 +183,22 @@ class MergeTreeCompactManager:
 
     # -- picking -------------------------------------------------------------
 
-    def pick(self, full: bool = False) -> Optional[CompactUnit]:
+    def pick(self, full: bool = False,
+             force_up_l0: bool = False) -> Optional[CompactUnit]:
+        """`force_up_l0`: upstream's ForceUpLevel0Compaction, the pick
+        of a writer under `changelog-producer=lookup` — universal's
+        pick, else every level-0 run forced up."""
         runs = self.levels.level_sorted_runs()
         if full:
             return pick_full_compaction(
                 self.options.num_levels, runs,
                 force_rewrite_all=self.options.get(
                     CoreOptions.COMPACTION_FORCE_REWRITE_ALL_FILES))
-        return self.strategy.pick(self.options.num_levels, runs)
+        unit = self.strategy.pick(self.options.num_levels, runs)
+        if unit is None and force_up_l0:
+            return self.strategy.force_pick_l0(self.options.num_levels,
+                                               runs)
+        return unit
 
     def should_wait_for_compaction(self) -> bool:
         """Write-stall condition (num-sorted-run.stop-trigger)."""
@@ -188,8 +207,9 @@ class MergeTreeCompactManager:
 
     # -- execution -----------------------------------------------------------
 
-    def compact(self, full: bool = False) -> Optional[CompactResult]:
-        unit = self.pick(full)
+    def compact(self, full: bool = False,
+                force_up_l0: bool = False) -> Optional[CompactResult]:
+        unit = self.pick(full, force_up_l0)
         if unit is None or not unit.files:
             return None
         return self.do_compact(unit)
@@ -214,6 +234,9 @@ class MergeTreeCompactManager:
         finally:
             timer.stop()
             group.counter("tasks").inc()
+        if self._index_owned:
+            self.levels_index.apply(result.before, result.after,
+                                    self._output)
         group.counter("input_files").inc(len(unit.files))
         group.counter("output_files").inc(len(result.after))
         return result
@@ -223,6 +246,7 @@ class MergeTreeCompactManager:
 
         files = unit.files
         producer = self.options.changelog_producer
+        self._output = None
         # upgrade fast path: single file, no rewrite needed. Both
         # compaction changelog producers must force a rewrite instead:
         # lookup for any L0 promotion (its keys were never changelog'd),
@@ -262,6 +286,14 @@ class MergeTreeCompactManager:
                 upgraded = f.upgrade(unit.output_level)
                 return CompactResult([f], [upgraded])
 
+        if producer == ChangelogProducer.LOOKUP and \
+                any(f.level == 0 for f in files):
+            # the unit's upper runs come from the index, not the store
+            index = self.synced_index()
+            for f in files:
+                held = index.table_of(f) if f.level > 0 else None
+                if held is not None:
+                    self._file_cache.setdefault(f.file_name, held)
         drop_delete = (unit.output_level != 0
                        and unit.output_level
                        >= self.levels.non_empty_highest_level())
@@ -278,6 +310,7 @@ class MergeTreeCompactManager:
                                      level=unit.output_level,
                                      file_source=FileSource.COMPACT)
         changelog = self._produce_changelog(unit, merged, drop_delete)
+        self._output = merged
         return CompactResult(list(files), after, changelog)
 
     def _rewrite_streamed(self, files: List[DataFileMeta],
@@ -483,49 +516,95 @@ class MergeTreeCompactManager:
                                       self.key_encoder, value_cols)
         elif producer == ChangelogProducer.LOOKUP:
             # the reference's lookup producer changelogs EVERY commit
-            # (LookupChangelogMergeFunctionWrapper.java:54); batched at
-            # compaction time, completeness demands replaying the L0
-            # deltas in commit order against an evolving state — one
-            # aggregate before/after diff would silently swallow a key
-            # that was inserted AND deleted between two compactions
-            # (its +I was visible to any from-snapshot-full consumer)
+            # (LookupChangelogMergeFunctionWrapper.java:54): the unit's
+            # L0 deltas are replayed in commit order against the state
+            # of the keys they touch, so a key inserted AND deleted
+            # between two compactions still shows its +I and -D
             l0 = sorted((f for f in unit.files if f.level == 0),
                         key=lambda f: (f.max_sequence_number,
                                        f.min_sequence_number))
             if l0:
-                all_files = self.levels.all_files()
-                self._read_runs(l0, flatten=True)   # warm via the pool
-                state = self._merged_state(
-                    [f for f in all_files if f.level > 0])
-                pieces = []
-                for f in l0:
-                    delta = self._read_runs([f], flatten=True)[0]
-                    runs = ([state] if state is not None and
-                            state.num_rows else []) + [delta]
-                    # ENGINE-AWARE replay: the evolving state must merge
-                    # exactly like the table (partial-update/aggregation
-                    # fold, not last-write-wins)
-                    new_state = self._merge_tables(runs,
-                                                   drop_deletes=True)
-                    piece = keyed_changelog_diff(
-                        state, new_state, self.key_cols,
-                        self.key_encoder, value_cols,
-                        restrict_table=delta)
-                    if piece is not None and piece.num_rows:
-                        pieces.append(piece)
-                    state = new_state
-                if pieces:
-                    cl = pa.concat_tables(pieces,
-                                          promote_options="none")
+                cl = self._lookup_changelog(l0, value_cols)
         if cl is None or cl.num_rows == 0:
             return []
-        return write_changelog_file(
-            self.file_io, self.path_factory, self.schema,
-            self.options.changelog_file_format,
-            self.options.changelog_file_compression,
-            self.partition, self.bucket, cl,
-            prefix=self.options.changelog_file_prefix,
-            format_options=self.options.format_options)
+        lookup = producer == ChangelogProducer.LOOKUP
+        with span("changelog.write", cat="lookup",
+                  group="lookup" if lookup else None,
+                  metric=LOOKUP_CHANGELOG_MS, rows=cl.num_rows):
+            return write_changelog_file(
+                self.file_io, self.path_factory, self.schema,
+                self.options.changelog_file_format,
+                self.options.changelog_file_compression,
+                self.partition, self.bucket, cl,
+                prefix=self.options.changelog_file_prefix,
+                format_options=self.options.format_options)
+
+    def _lookup_changelog(self, l0: List[DataFileMeta],
+                          value_cols: List[str]) -> Optional[pa.Table]:
+        """+I / -U,+U / -D of every key the L0 deltas touch, one delta
+        after another.  The state before the first is what the levels
+        above hold for those keys, probed in the levels index
+        (`_before_images`); each delta is merged into it under the
+        table's merge engine.  A key that was there gives -U/+U also
+        when its value did not change, unless
+        `changelog-producer.row-deduplicate` is set; under first-row
+        a key that was there keeps its row and gives nothing, so the
+        changelog is +I alone (reference FirstRowMergeFunctionWrapper,
+        which LookupMergeTreeCompactRewriter takes for first-row)."""
+        from paimon_tpu.ops.diff import keyed_changelog_diff
+
+        deltas = [self._read_file(f) for f in l0]
+        state = self._before_images(self.synced_index(), deltas)
+        keep_unchanged = (
+            self.options.merge_engine != MergeEngine.FIRST_ROW
+            and not self.options.get(
+                CoreOptions.CHANGELOG_ROW_DEDUPLICATE))
+        pieces = []
+        for delta in deltas:
+            runs = ([state] if state is not None and state.num_rows
+                    else []) + [delta]
+            new_state = self._merge_tables(runs, drop_deletes=True)
+            piece = keyed_changelog_diff(
+                state, new_state, self.key_cols, self.key_encoder,
+                value_cols, restrict_table=delta,
+                keep_unchanged=keep_unchanged)
+            if piece is not None and piece.num_rows:
+                pieces.append(piece)
+            state = new_state
+        if not pieces:
+            return None
+        return pa.concat_tables(pieces, promote_options="none")
+
+    def _before_images(self, index, deltas: List[pa.Table]
+                       ) -> Optional[pa.Table]:
+        """The merged state of the levels above 0 for the keys of
+        `deltas`: each run's rows that hold one of the keys, gathered
+        from the index and merged like the table merges (key-sorted,
+        key-unique, live rows only)."""
+        probes = deltas[0] if len(deltas) == 1 else \
+            pa.concat_tables(deltas, promote_options="none")
+        tables = index.gather(probes)
+        if not tables:
+            return None
+        return self._merge_tables(tables, drop_deletes=True)
+
+    def synced_index(self):
+        """The levels index, holding the runs above level 0 this
+        manager was given (built on first use, decoding what it
+        lacks)."""
+        from paimon_tpu.lookup.levels_index import LevelsIndex
+        if self.levels_index is None:
+            self.levels_index = LevelsIndex(self.key_encoder,
+                                            self.key_cols)
+        if not self._index_synced:
+            self.levels_index.sync(self.levels.all_files(), self._decode)
+            self._index_synced = True
+        return self.levels_index
+
+    def _decode(self, files: List[DataFileMeta]) -> List[pa.Table]:
+        """`files` decoded side by side (`_read_runs`' pool), in order."""
+        self._read_runs(files, flatten=True)
+        return [self._read_file(f) for f in files]
 
     # -- merged-state helpers ------------------------------------------------
 

@@ -139,6 +139,10 @@ class UniversalCompaction:
 
     def force_pick_l0(self, num_levels: int,
                       runs: List[LevelSortedRun]) -> Optional[CompactUnit]:
+        """Every level-0 run forced up, with the runs below it that the
+        size ratio admits: the second half of upstream's
+        ForceUpLevel0Compaction (reference mergetree/compact/
+        ForceUpLevel0Compaction.java), whose first half is `pick`."""
         count = 0
         for r in runs:
             if r.level > 0:

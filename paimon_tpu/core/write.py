@@ -362,6 +362,9 @@ class _BucketWriter:
         self._spill_sched_bytes = 0           # scheduled-not-yet-written
         # spill payload bytes: the disk-budget check must see queued
         # spills too, or async workers let /tmp overshoot the cap
+        # changelog-producer=lookup: the runs above level 0, kept across
+        # commits (lookup/levels_index.py), built on first use
+        self.lookup_index = None
 
     @property
     def _key(self) -> Tuple:
@@ -956,6 +959,11 @@ class KeyValueFileStoreWrite:
         self._restore_max_seq = restore_max_seq
         self.changelog_input = (
             options.changelog_producer == "input")
+        # changelog-producer=lookup: every commit compacts its level-0
+        # files (ForceUpLevel0Compaction) and writes their changelog;
+        # lookup-wait=false leaves that to the next commit
+        self.lookup = options.changelog_producer == "lookup"
+        self.lookup_wait = options.get(CoreOptions.LOOKUP_WAIT)
         self.spillable = options.get(CoreOptions.WRITE_BUFFER_SPILLABLE)
         self._changelog_counter = 0
         self._local_merger: Optional[LocalMerger] = None
@@ -1226,12 +1234,29 @@ class KeyValueFileStoreWrite:
         if auto_compact and self._bucket_files_map is not None:
             # ONE manifest read for the whole commit, not one per bucket
             existing_map = self._bucket_files_map()
+        existing_map = existing_map or {}
+        deferred = auto_compact and self.lookup and not self.lookup_wait
+        todo = []
         for w in self._writers.values():
             msg = w.take_commit_message()
+            if msg is None and deferred and any(
+                    f.level == 0 for f in existing_map.get(w._key, [])):
+                # the level-0 files an earlier commit left for this one
+                msg = CommitMessage(w.partition, w.bucket,
+                                    self.total_buckets)
             if msg is not None:
                 if auto_compact:
-                    self._maybe_compact(msg, existing_map or {})
+                    todo.append((w, msg))
                 out.append(msg)
+        if self.lookup:
+            # every touched bucket compacts in this commit: side by side
+            self._per_bucket(
+                lambda w, msg: self._maybe_compact(w, msg, existing_map),
+                todo, "inline compaction")
+        else:
+            for w, msg in todo:
+                self._maybe_compact(w, msg, existing_map)
+        out = [m for m in out if not m.is_empty()]
         if self._dynamic is not None:
             entries = self._dynamic.index_entries()
             if entries:
@@ -1249,27 +1274,65 @@ class KeyValueFileStoreWrite:
             self._stager.drain()
         return out
 
-    def _maybe_compact(self, msg: CommitMessage, existing_map: Dict):
+    @staticmethod
+    def _per_bucket(fn, jobs, what: str):
+        """`fn(*job)` for each job, the buckets side by side on a pool
+        (threads `paimon-compact_N`, at most min(8, cores)) when there
+        are several; the first error raises here."""
+        workers = min(8, os.cpu_count() or 1, len(jobs))
+        if workers <= 1:
+            for job in jobs:
+                fn(*job)
+            return
+        from paimon_tpu.obs.trace import carry
+        from paimon_tpu.parallel.executors import new_thread_pool
+        pool = new_thread_pool(workers, "paimon-compact")
+        try:
+            for f in [pool.submit(carry(fn), *job) for job in jobs]:
+                wait_future(f, what)
+        finally:
+            pool.shutdown(wait=True, cancel_futures=True)
+
+    def _maybe_compact(self, w: _BucketWriter, msg: CommitMessage,
+                       existing_map: Dict):
         """Inline compaction at prepare-commit when the bucket's sorted
         runs exceed the trigger (reference MergeTreeWriter: compaction
         fires at flush unless write-only). The picked unit may include
         the message's own new L0 files: commit() publishes APPEND before
-        COMPACT, so the conflict check still sees them."""
+        COMPACT, so the conflict check still sees them.
+
+        Under `changelog-producer=lookup` the pick forces every level-0
+        run up (reference ForceUpLevel0Compaction) and the message
+        carries the compaction's changelog, looked up in the writer's
+        levels index; with `lookup-wait=false` this commit's own L0
+        files wait for the next commit."""
         existing = existing_map.get((msg.partition, msg.bucket), [])
-        files = existing + msg.new_files
-        if len(files) < 2:
+        if self.lookup and not self.lookup_wait:
+            files = list(existing)
+        else:
+            files = existing + msg.new_files
+        if not files or (len(files) < 2 and not self.lookup):
             return
         from paimon_tpu.compact.manager import MergeTreeCompactManager
         mgr = MergeTreeCompactManager(
             self.file_io, self.table_path, self.schema, self.options,
             msg.partition, msg.bucket, files,
-            schema_manager=self._schema_manager)
-        result = mgr.compact(full=False)
+            schema_manager=self._schema_manager,
+            levels_index=self._levels_index(w) if self.lookup else None)
+        result = mgr.compact(full=False, force_up_l0=self.lookup)
         if result is None or result.is_empty():
             return
         msg.compact_before = result.before
         msg.compact_after = result.after
         msg.compact_changelog = result.changelog
+
+    def _levels_index(self, w: _BucketWriter):
+        if w.lookup_index is None:
+            from paimon_tpu.lookup.levels_index import LevelsIndex
+            w.lookup_index = LevelsIndex(
+                self.key_encoder,
+                [KEY_PREFIX + k for k in self._key_names])
+        return w.lookup_index
 
     def close(self):
         if self._prep_pool is not None:

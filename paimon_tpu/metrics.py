@@ -70,6 +70,10 @@ __all__ = ["Counter", "Gauge", "Histogram", "MetricGroup",
            "LOOKUP_FILES_PRUNED", "LOOKUP_SNAPSHOT_REFRESHES",
            "LOOKUP_DELTA_HITS", "LOOKUP_NATIVE_PROBES",
            "LOOKUP_NATIVE_FALLBACKS",
+           "LOOKUP_INDEX_MS", "LOOKUP_PROBE_MS", "LOOKUP_GATHER_MS",
+           "LOOKUP_CHANGELOG_MS", "LOOKUP_PROBE_KEYS", "LOOKUP_PROBE_HITS",
+           "LOOKUP_INDEX_ROWS", "LOOKUP_INDEX_DEVICE_BYTES",
+           "LOOKUP_LEVEL_ROWS_DECODED",
            "CACHE_DISK_HITS", "CACHE_DISK_MISSES",
            "CACHE_DISK_PROMOTIONS", "CACHE_DISK_DEMOTIONS",
            "CACHE_DISK_EVICTIONS", "CACHE_DISK_BYTES",
@@ -298,6 +302,22 @@ LOOKUP_DELTA_HITS = "delta_hits"              # keys answered by delta
 # a nonzero steady-state value is the "serving the slow path" alarm)
 LOOKUP_NATIVE_PROBES = "native_probes"
 LOOKUP_NATIVE_FALLBACKS = "native_fallbacks"
+# the lookup changelog producer's levels index (lookup/levels_index.py,
+# compact/manager.py): build and update of the resident index, the
+# device probe, the before-images' gather, the changelog file's encode
+# and upload; probe_keys / probe_hits count probed keys and those a run
+# above level 0 holds; index_rows / index_device_bytes count the rows
+# and lane bytes put on the device; level_rows_decoded counts rows of
+# level files decoded for the index (0 once it is built)
+LOOKUP_INDEX_MS = "index_ms"
+LOOKUP_PROBE_MS = "probe_ms"
+LOOKUP_GATHER_MS = "gather_ms"
+LOOKUP_CHANGELOG_MS = "changelog_ms"
+LOOKUP_PROBE_KEYS = "probe_keys"
+LOOKUP_PROBE_HITS = "probe_hits"
+LOOKUP_INDEX_ROWS = "index_rows"
+LOOKUP_INDEX_DEVICE_BYTES = "index_device_bytes"
+LOOKUP_LEVEL_ROWS_DECODED = "level_rows_decoded"
 
 # tiered host-SSD storage counter/gauge/histogram names (cache_disk
 # metric group; producers in fs/caching.py DiskCacheTier + the

@@ -36,6 +36,19 @@ def joint_key_ranks(tables: Sequence[pa.Table], key_cols: Sequence[str],
     """Rank the keys of several tables in ONE order-preserving space:
     equal keys (across tables) share a rank; rank order == key order.
     Truncated string keys are disambiguated by full-key sub-ranks."""
+    if encoder.packs_single_key:
+        # the key packs into one order-preserving u64: a 1-D unique
+        # instead of a row-wise one, the same ranks
+        packed = [encoder.encode_table_ex(t, key_cols)[2]
+                  if t.num_rows else np.zeros(0, np.uint64)
+                  for t in tables]
+        _, inv = np.unique(np.concatenate(packed) if packed else
+                           np.zeros(0, np.uint64), return_inverse=True)
+        out, pos = [], 0
+        for u in packed:
+            out.append(inv[pos:pos + len(u)].astype(np.int64))
+            pos += len(u)
+        return out
     lanes_list, trunc_list = [], []
     for t in tables:
         lanes, trunc = encoder.encode_table(t, key_cols)
@@ -78,13 +91,16 @@ def keyed_changelog_diff(before: Optional[pa.Table], after: pa.Table,
                          key_cols: Sequence[str],
                          encoder: NormalizedKeyEncoder,
                          value_cols: Sequence[str],
-                         restrict_table: Optional[pa.Table] = None
-                         ) -> pa.Table:
+                         restrict_table: Optional[pa.Table] = None,
+                         keep_unchanged: bool = False) -> pa.Table:
     """Diff two key-unique KV tables (same KV layout) into changelog rows
     with _VALUE_KIND set to +I / -U / +U / -D.
 
     `restrict_table`: optional KV table; only keys occurring in it are
     diffed (the lookup producer's "keys touched by L0").
+    `keep_unchanged`: a key in both tables gives -U/+U also where its
+    values are equal (the lookup producer without
+    `changelog-producer.row-deduplicate`).
     Output ordered with each -U immediately before its +U."""
     if before is None:
         before = after.slice(0, 0)
@@ -118,7 +134,7 @@ def keyed_changelog_diff(before: Optional[pa.Table], after: pa.Table,
     # matched keys: emit -U/+U only when the value actually changed
     a_m = after.filter(pa.array(in_before))
     b_m = before.take(pa.array(matched_before_pos))
-    if a_m.num_rows:
+    if a_m.num_rows and not keep_unchanged:
         differs = np.zeros(a_m.num_rows, dtype=bool)
         for c in value_cols:
             ca = a_m.column(c).combine_chunks()
