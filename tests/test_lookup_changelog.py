@@ -212,28 +212,128 @@ def test_no_level_file_decoded_once_built(tmp_path):
                                | set(range(0, 2400, 3)))
 
 
-@pytest.mark.parametrize("lanes", [2, 4])
-def test_probe_kernel_matches_searchsorted(lanes):
+_ONES = 0xFFFFFFFF
+
+
+def _in_order(keys):
+    return keys[np.lexsort(keys.T[::-1])]
+
+
+def _probe_cases(case):
+    """(run, probes) pairs of `uint32[n, L]` lanes, the run in key
+    order, for one case of the probe test."""
+    if case in ("2", "4"):
+        lanes = int(case)
+        rng = np.random.default_rng(lanes)
+        keys = np.unique(rng.integers(0, 40, (5000, lanes)).astype(
+            np.uint32), axis=0)
+        queries = rng.integers(0, 40, (777, lanes)).astype(np.uint32)
+        queries[:50] = keys[rng.integers(0, len(keys), 50)]
+        return [(keys, queries)]
+    rng = np.random.default_rng(zlib.crc32(case.encode()))
+    if case == "ragged_n":
+        # every second value held: hits, misses between them, probes
+        # below the first row and above the last; n < cap throughout
+        out = []
+        for n in (1, 127, 129, 1000, 1023, 3001):
+            keys = np.stack([np.full(n, 7), 2 * np.arange(n) + 2],
+                            axis=1).astype(np.uint32)
+            v = np.arange(2 * n + 4)
+            queries = np.concatenate([
+                np.stack([np.full(len(v), 7), v], axis=1),
+                [[6, _ONES], [8, 0], [0, 0], [_ONES, _ONES]]]).astype(
+                    np.uint32)
+            out.append((keys, queries[rng.permutation(len(queries))]))
+        return out
+    if case == "fences":
+        # probes equal to a block's first row or last, one below or
+        # above either, below the whole run and above it
+        keys = np.unique(rng.integers(1, 1 << 20, (3000, 2)).astype(
+            np.uint32), axis=0)
+        edges = np.concatenate([keys[::128], keys[127::128]])
+        less, more = edges.copy(), edges.copy()
+        less[:, 1] -= 1
+        more[:, 1] += 1
+        queries = np.concatenate([
+            edges, less, more, keys[:1] - 1, keys[-1:] + 1,
+            [[0, 0], [_ONES, _ONES]]]).astype(np.uint32)
+        return [(keys, queries[rng.permutation(len(queries))])]
+    if case == "all_ones":
+        # the padding's lanes as probes, against runs without such a
+        # key, with it as the last row, and filling the capacity
+        out = []
+        for n, last in ((700, False), (701, True), (1024, True)):
+            keys = np.unique(rng.integers(0, 1 << 16, (n * 2, 2)).astype(
+                np.uint32), axis=0)[:n]
+            if last:
+                keys[-1] = _ONES
+            queries = np.array([[_ONES, _ONES], [_ONES, 0], [0, _ONES],
+                                keys[0], keys[-1], keys[n // 2]],
+                               dtype=np.uint32)
+            out.append((keys, queries))
+        return out
+    if case == "cut_runs":
+        # runs of equal lanes, as keys cut to their prefix give them:
+        # some span three blocks or more, some start or end on a fence
+        sizes = [5, 400, 1, 122, 128, 256, 3, 640, 2, 129, 1]
+        values = np.sort(rng.choice(1 << 20, len(sizes), replace=False))
+        keys = np.repeat(np.stack([values // 7, values], axis=1),
+                         sizes, axis=0).astype(np.uint32)
+        near = np.stack([values // 7, values], axis=1)
+        queries = np.concatenate([near, near - [0, 1], near + [0, 1],
+                                  [[0, 0], [_ONES, _ONES]]]).astype(
+                                      np.uint32)
+        return [(keys, queries)]
+    assert case == "one_probe"
+    keys = np.unique(rng.integers(0, 1 << 12, (2000, 4)).astype(
+        np.uint32), axis=0)
+    return [(keys, q[None]) for q in
+            (keys[0], keys[len(keys) // 3], keys[-1], keys[5] + 1,
+             np.zeros(4, np.uint32), np.full(4, _ONES, np.uint32))]
+
+
+@pytest.mark.parametrize("case", [
+    pytest.param("2", id="2"), pytest.param("4", id="4"), "ragged_n",
+    "fences", "all_ones", "cut_runs", "one_probe"])
+def test_probe_kernel_matches_searchsorted(case):
+    """Rows and ends as a binary search over the run's key bytes gives
+    them, at the edges of the blocks the probe reads too."""
     from paimon_tpu.ops.lookup_probe import device_lanes, probe
-    rng = np.random.default_rng(lanes)
-    keys = np.unique(rng.integers(0, 40, (5000, lanes)).astype(np.uint32),
-                     axis=0)
-    queries = rng.integers(0, 40, (777, lanes)).astype(np.uint32)
-    queries[:50] = keys[rng.integers(0, len(keys), 50)]
+    for keys, queries in _probe_cases(case):
+        keys = _in_order(keys)
+        lanes = keys.shape[1]
 
-    def as_bytes(a):
-        return np.ascontiguousarray(a.astype(">u4")).view(
-            np.dtype((np.void, 4 * lanes))).ravel()
+        def as_bytes(a):
+            return np.ascontiguousarray(a.astype(">u4")).view(
+                np.dtype((np.void, 4 * lanes))).ravel()
 
-    kb, qb = as_bytes(keys), as_bytes(queries)
-    lo = np.searchsorted(kb, qb, "left")
-    hi = np.searchsorted(kb, qb, "right")
-    rows, ends = probe(device_lanes(keys), len(keys), queries, upper=True)
-    np.testing.assert_array_equal(rows, np.where(hi > lo, lo, -1))
-    np.testing.assert_array_equal(ends, hi)
-    only, none = probe(device_lanes(keys), len(keys), queries)
-    assert none is None
-    np.testing.assert_array_equal(only, rows)
+        kb, qb = as_bytes(keys), as_bytes(queries)
+        lo = np.searchsorted(kb, qb, "left")
+        hi = np.searchsorted(kb, qb, "right")
+        index = device_lanes(keys)
+        rows, ends = probe(index, len(keys), queries, upper=True)
+        np.testing.assert_array_equal(rows, np.where(hi > lo, lo, -1))
+        np.testing.assert_array_equal(ends, hi)
+        only, none = probe(index, len(keys), queries)
+        assert none is None
+        np.testing.assert_array_equal(only, rows)
+
+
+@pytest.mark.parametrize("upper", [False, True])
+def test_probe_program_keeps_its_name(upper):
+    """Every program of the probe is named `jit_lookup_probe`: the
+    benchmark's roofline of the probe finds its device time by it."""
+    import jax
+    from paimon_tpu.ops.lookup_probe import (
+        _lane_major, device_lanes, lookup_probe,
+    )
+    keys = np.arange(600, dtype=np.uint32).reshape(300, 2)
+    lowered = lookup_probe.lower(device_lanes(keys), np.int32(300),
+                                 jax.device_put(_lane_major(keys, 1024)),
+                                 upper=upper)
+    assert "@jit_lookup_probe" in lowered.as_text()
+    assert lowered.compile().as_text().startswith(
+        "HloModule jit_lookup_probe")
 
 
 @pytest.mark.parametrize("key", ["packed", "nullable_int"])
